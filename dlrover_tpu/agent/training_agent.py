@@ -109,6 +109,17 @@ class ElasticLaunchConfig:
     bundle_dir: str = ""  # default: the run's telemetry dir
     run_id: str = field(default_factory=lambda: uuid.uuid4().hex[:8])
 
+    def __post_init__(self):
+        if self.accelerator == "tpu" and self.nproc_per_node > 1:
+            # Nothing gives a worker its own chips: every process would
+            # see all of them, and a chip belongs to one process.
+            raise ValueError(
+                f"--accelerator tpu with --nproc_per_node "
+                f"{self.nproc_per_node}: one worker process drives all "
+                f"local chips (its mesh spans them); use "
+                f"--nproc_per_node 1"
+            )
+
     def auto_configure_from_env(self):
         """Fill node counts from the scheduler-provided env (reference
         ``training.py:144``): under a managed job the operator exports

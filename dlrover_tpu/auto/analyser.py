@@ -40,17 +40,16 @@ class DeviceContext:
         platform = d0.platform
         ctx = cls(platform=platform, n_devices=len(devices))
         if platform == "tpu":
-            kind = getattr(d0, "device_kind", "").lower()
-            for gen, (hbm, tflops, ici) in cls._TPU_SPECS.items():
-                if gen in kind:
-                    ctx.hbm_bytes = hbm << 30
-                    ctx.bf16_flops = tflops * 1e12
-                    ctx.ici_bandwidth = ici * 1e9
-                    break
-            else:
-                ctx.hbm_bytes = 16 << 30
-                ctx.bf16_flops = 2e14
-                ctx.ici_bandwidth = 5e10
+            from dlrover_tpu.telemetry.costmodel import attached_generation
+
+            # Keyed by what the device reports ("TPU v5 lite" is a v5e);
+            # a kind nobody identified raises rather than get a guess.
+            hbm, tflops, ici = cls._TPU_SPECS[
+                attached_generation(d0.device_kind)
+            ]
+            ctx.hbm_bytes = hbm << 30
+            ctx.bf16_flops = tflops * 1e12
+            ctx.ici_bandwidth = ici * 1e9
             try:
                 stats = d0.memory_stats()
                 ctx.hbm_bytes = stats.get("bytes_limit", ctx.hbm_bytes)

@@ -814,8 +814,8 @@ def brain_strategy(
 
 def _device_generation(device) -> str:
     """Map a ``DeviceContext`` back to its generation row in the
-    costmodel tables via the peak-FLOPs spec it detected; "tpu" (the
-    attached-chip default row) when nothing matches."""
+    costmodel tables via the peak-FLOPs spec it detected; a device with
+    no row (the CPU test meshes) is planned for as a v5e."""
     try:
         from dlrover_tpu.auto.analyser import DeviceContext as _DC
 
@@ -824,4 +824,4 @@ def _device_generation(device) -> str:
                 return gen
     except Exception:  # noqa: BLE001 — table lookup only
         pass
-    return "tpu"
+    return "v5e"

@@ -4,7 +4,7 @@ Subcommands:
 
 ``report``     render the telemetry warehouse as a fleet report
                (markdown to stdout; ``--json`` for machine-readable)
-``backfill``   ingest the repo's flat perf history (PERF_LEDGER.jsonl +
+``backfill``   ingest the repo's flat perf history (perf_history.jsonl +
                BENCH_r0*.json) into a warehouse db
 ``plan``       what-if capacity planner: price a proposed fleet
                (replicas, standbys, chip generation) against recorded
@@ -51,7 +51,7 @@ def parse_args(argv=None):
     )
 
     bf = sub.add_parser(
-        "backfill", help="ingest PERF_LEDGER.jsonl + BENCH_r0*.json"
+        "backfill", help="ingest perf_history.jsonl + BENCH_r0*.json"
     )
     _add_db_arg(bf)
     bf.add_argument(
@@ -67,7 +67,7 @@ def parse_args(argv=None):
                     help="proposed max live replicas")
     pl.add_argument("--standbys", type=int, required=True,
                     help="proposed warm-standby pool size")
-    pl.add_argument("--chip-gen", default="tpu",
+    pl.add_argument("--chip-gen", default="v5e",
                     help="chip generation to price on (tpu/v5e/v5p/v6e)")
     pl.add_argument("--job", default="",
                     help="restrict traffic history to one job uid")

@@ -33,7 +33,7 @@ _DEFAULT_SLOTS = 8
 
 def replica_capacity(
     warehouse: Optional[Any] = None,
-    chip_gen: str = "tpu",
+    chip_gen: str = "v5e",
     n_params: int = _DEFAULT_N_PARAMS,
     repo: Optional[str] = None,
 ) -> Dict[str, Any]:
@@ -72,7 +72,7 @@ def plan_capacity(
     *,
     replicas: int,
     standbys: int,
-    chip_gen: str = "tpu",
+    chip_gen: str = "v5e",
     job_uid: str = "",
     n_params: int = _DEFAULT_N_PARAMS,
     lead_s: float = 30.0,
@@ -165,7 +165,7 @@ def plan_capacity(
     }
     plan["config_draft"] = draft_config_diff(
         current={"max_replicas": 1, "standby_target": 0,
-                 "chip_gen": "tpu"},
+                 "chip_gen": "v5e"},
         proposed=proposed,
         reason=f"capacity plan verdict: {verdict}",
     )

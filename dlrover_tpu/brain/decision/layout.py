@@ -276,7 +276,7 @@ def score_layout(
 def plan_layout(
     profile: LayoutProfile,
     n_devices: int,
-    backend: str = "tpu",
+    backend: str = "v5e",
     top_k: int = 3,
     mfu: Optional[float] = None,
     repo: Optional[str] = None,

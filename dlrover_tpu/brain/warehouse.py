@@ -375,7 +375,7 @@ class TelemetryWarehouse:
     def add_perf_entry(
         self, job_uid: str, entry: dict, run: str = "", attempt: int = 0
     ):
-        """One perf-ledger entry (``PERF_LEDGER.jsonl`` shape)."""
+        """One perf-ledger entry (``perf_history.jsonl`` shape)."""
         self._add(
             job_uid, "perf", t=entry.get("ts"), run=run, attempt=attempt,
             trigger=str(entry.get("source", "")),
@@ -917,7 +917,7 @@ class TelemetryWarehouse:
     def ingest_perf_ledger(
         self, path: str, job_uid: str = "perf-ledger"
     ) -> int:
-        """Ingest ``PERF_LEDGER.jsonl`` (torn-line tolerant); one run per
+        """Ingest ``perf_history.jsonl`` (torn-line tolerant); one run per
         ledger round so rounds are individually queryable."""
         if not os.path.exists(path):
             return 0
@@ -990,12 +990,12 @@ class TelemetryWarehouse:
         return cfg
 
     def backfill(self, root: Optional[str] = None) -> Dict[str, int]:
-        """Ingest the repo's flat perf history (``PERF_LEDGER.jsonl`` +
+        """Ingest the repo's flat perf history (``perf_history.jsonl`` +
         ``BENCH_r0*.json``) so rounds 1..N are queryable."""
         root = root or _repo_root()
         counts = {"ledger": 0, "bench": 0}
         counts["ledger"] = self.ingest_perf_ledger(
-            os.path.join(root, "PERF_LEDGER.jsonl")
+            os.path.join(root, "perf_history.jsonl")
         )
         for path in sorted(glob.glob(os.path.join(root, "BENCH_r0*.json"))):
             counts["bench"] += self.ingest_bench_file(path)

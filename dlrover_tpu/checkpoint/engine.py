@@ -7,7 +7,7 @@ load = shm-first, storage fallback) + the FSDP flat-ckpt reshard-on-restore
 
 TPU mapping: the "state dict" is any pytree of ``jax.Array``s (TrainState).
 ``save_to_memory`` pulls this process's *addressable shards* to host
-(HBM→host over PCIe/tunnel) and memcpys them into the agent's shm block with
+(HBM→host over PCIe) and memcpys them into the agent's shm block with
 their global layout (shape + index).  Restore pastes shards from any saved
 mesh layout into arrays sharded for the *current* mesh — elastic restarts
 with a different world size reshard transparently.
@@ -317,8 +317,7 @@ def host_tree_to_state(
     # Batch ALL host→device uploads into one device_put call at the end:
     # jax pipelines the transfers (the restore twin of the batched
     # device_get on the save path — per-leaf puts each pay dispatch
-    # latency, which dominates through a tunnel and serializes DMA
-    # streams on co-located hosts).
+    # latency and serialize the DMA streams).
     puts: List[Tuple[int, np.ndarray, Any]] = []
     for i, (path, leaf) in enumerate(flat):
         key = jax.tree_util.keystr(path)

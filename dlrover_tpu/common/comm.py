@@ -978,6 +978,26 @@ class ServeControlResult:
 
 
 @comm_message
+class ServeVerify:  # dlr: no-trace — an offline check, spans no request
+    """Gateway -> worker: score a finished completion (``tokens`` =
+    prompt + completion) against the plain, non-paged forward of the
+    worker's own weights.  Only the process that holds the chip can run
+    that reference, so the check lives in the replica."""
+
+    tokens: List[int] = field(default_factory=list)
+    prompt_len: int = 0
+
+
+@comm_message
+class ServeVerifyResult:
+    """Per generated position: the reference row's maximum logit, and how
+    far below it the token the engine chose sits (0.0 = the argmax)."""
+
+    row_max: List[float] = field(default_factory=list)
+    margin: List[float] = field(default_factory=list)
+
+
+@comm_message
 class ServeProgress:
     """Worker -> gateway: newly generated tokens per request id (the
     gateway's commit journal feed), finished completions (plain dicts

@@ -2,7 +2,7 @@
 
 Reference parity: ``dlrover/python/common/constants.py`` (NodeType,
 NodeStatus, NodeEventType, NodeEnv, ...).  Re-designed for TPU jobs: the
-accelerator taxonomy is TPU-first and the per-node env contract carries the
+accelerator classification is TPU-first and the per-node env contract carries the
 JAX distributed-initialization triple (coordinator, num_processes,
 process_id) instead of torch-elastic's MASTER_ADDR/RANK pair.
 """

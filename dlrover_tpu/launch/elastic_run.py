@@ -126,6 +126,7 @@ def _config_from_args(args) -> ElasticLaunchConfig:
 
 
 def run(args) -> WorkerState:
+    config = _config_from_args(args)  # refuses bad layouts before any start
     master = None
     explicit = args.master_addr is not None
     master_addr = (
@@ -191,7 +192,6 @@ def run(args) -> WorkerState:
             args.training_script,
         ]
     entrypoint += list(args.training_script_args or [])
-    config = _config_from_args(args)
     config.manage_world_bootstrap = not args.no_world_bootstrap
     # Namespace the job's IPC (flash-checkpoint factory queue, shm locks)
     # by run id: two jobs co-hosted on one machine must never unlink each

@@ -95,6 +95,10 @@ def main(argv: Optional[List[str]] = None) -> int:
     from dlrover_tpu.telemetry import events as tevents
 
     tevents.emit("process_start", entrypoint=os.path.basename(script))
+    from dlrover_tpu.common.platform import configure_compile_cache
+
+    # A restarted incarnation must find what its predecessor compiled.
+    configure_compile_cache()
     spec = bootstrap()
     tevents.emit(
         "world_init",

@@ -94,11 +94,13 @@ class LocalJobMaster:
             job_uid = os.environ.get("DLROVER_JOB_UID", "") or "local"
             versions = {"python": platform.python_version()}
             try:
-                import jax
+                # The metadata, not the module: the master shares a
+                # process with an agent that must stay off JAX.
+                from importlib import metadata
 
-                versions["jax"] = jax.__version__
-            except Exception:  # noqa: BLE001 — jax-less master is fine
-                pass
+                versions["jax"] = metadata.version("jax")
+            except metadata.PackageNotFoundError:
+                pass  # a jax-less master is fine
             wh.register_run(
                 job_uid,
                 run=os.environ.get("DLROVER_JOB_UID", ""),

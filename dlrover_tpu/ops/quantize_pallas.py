@@ -28,13 +28,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlrover_tpu.common.platform import pallas_interpret
 from dlrover_tpu.optimizers.quantized import LOG_RANGE
 
 ROWS_PER_TILE = 32  # int8 TPU tile is (32, 128)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _as_blocks(x: jnp.ndarray, block_size: int) -> Tuple[jnp.ndarray, int]:
@@ -106,7 +103,7 @@ def quantize_blockwise_pallas(
             jax.ShapeDtypeStruct((rows, block_size), jnp.int8),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(blocks)
     return (
         codes[:n_blocks].reshape(-1),
@@ -145,7 +142,7 @@ def dequantize_blockwise_pallas(
             (ROWS_PER_TILE, block_size), lambda i: (i, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((rows, block_size), jnp.float32),
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(blocks, scales)
     n = 1
     for s in shape:
@@ -159,7 +156,7 @@ def dequantize_blockwise_pallas(
 
 
 def _fused_adam_kernel(
-    count_ref,  # SMEM (1,) int32
+    bc_ref,  # SMEM (2,) f32: the bias corrections 1 - b1^t, 1 - b2^t
     g_ref, mc_ref, ms_ref, vc_ref, vs_ref,
     upd_ref, mc_out_ref, ms_out_ref, vc_out_ref, vs_out_ref,
     *, b1, b2, eps,
@@ -169,10 +166,7 @@ def _fused_adam_kernel(
     v = _decode(vc_ref[...], vs_ref[...], "log")
     m = b1 * m + (1 - b1) * g
     v = b2 * v + (1 - b2) * g * g
-    count = count_ref[0].astype(jnp.float32)
-    bc1 = 1.0 - b1**count
-    bc2 = 1.0 - b2**count
-    upd_ref[...] = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
+    upd_ref[...] = (m / bc_ref[0]) / (jnp.sqrt(v / bc_ref[1]) + eps)
     m_absmax = jnp.max(jnp.abs(m), axis=1, keepdims=True)
     v_absmax = jnp.max(jnp.abs(v), axis=1, keepdims=True)
     ms_out_ref[...] = m_absmax
@@ -212,6 +206,10 @@ def fused_adam8bit_update(
     def pad_scales(s):
         return jnp.pad(s, (0, rows - s.shape[0])).reshape(rows, 1)
 
+    # The two scalar powers stay outside the kernel: Mosaic has no
+    # lowering for math.powf.
+    t = count.astype(jnp.float32)
+    bias_corrections = jnp.stack([1.0 - b1**t, 1.0 - b2**t]).reshape(2)
     grid = (rows // ROWS_PER_TILE,)
     val_spec = pl.BlockSpec((ROWS_PER_TILE, block_size), lambda i: (i, 0))
     scale_spec = pl.BlockSpec((ROWS_PER_TILE, 1), lambda i: (i, 0))
@@ -230,9 +228,9 @@ def fused_adam8bit_update(
             jax.ShapeDtypeStruct((rows, block_size), jnp.int8),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=pallas_interpret(),
     )(
-        count.reshape(1).astype(jnp.int32),
+        bias_corrections,
         g_blocks,
         pad_codes(mu_codes),
         pad_scales(mu_scales),

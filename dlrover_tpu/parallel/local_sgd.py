@@ -111,7 +111,6 @@ def _int8_mean_over_dcn(
     leaf's intra-slice ``param_specs`` sharding: HSDP shards are codec'd
     locally — the sync never materializes a full-model f32 copy).
     """
-    from dlrover_tpu.parallel.mesh import compat_shard_map
 
     from dlrover_tpu.optimizers.quantized import (
         dequantize_blockwise,
@@ -167,7 +166,7 @@ def _int8_mean_over_dcn(
             )
             return out[:n].reshape((1,) + rest_local)
 
-        return compat_shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=PartitionSpec(dcn_axis, *spec),

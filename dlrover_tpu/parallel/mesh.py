@@ -156,25 +156,6 @@ def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across jax versions: newer jax exposes it at top
-    level with ``check_vma``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 # -- ambient mesh ----------------------------------------------------------
 #
 # Ring/Ulysses attention live *inside* a jitted model but need the concrete
@@ -204,19 +185,12 @@ def current_mesh() -> Optional[Mesh]:
     # Fall back to the ambient `with mesh:` context if one is active.
     try:
         ambient = jax.sharding.get_mesh()
-    except AttributeError:
-        # jax 0.4.x has no jax.sharding.get_mesh; the ambient context
-        # lives in the thread-resources env there.
-        from jax._src import mesh as _mesh_lib
-
-        ambient = _mesh_lib.thread_resources.env.physical_mesh
-        return None if ambient.empty else ambient
     except ValueError:
         # Inside jit/eval_shape tracing get_mesh() raises; a meshless
         # trace (e.g. a shape probe before the step is built) degrades
         # to single-shard semantics, which is shape-identical.
         return None
-    return ambient if getattr(ambient, "devices", None) is not None else None
+    return None if ambient.empty else ambient
 
 
 def axis_size(mesh: Optional[Mesh], name: str) -> int:

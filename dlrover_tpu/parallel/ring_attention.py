@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common.log import logger
-from dlrover_tpu.parallel.mesh import axis_size, compat_shard_map, current_mesh
+from dlrover_tpu.parallel.mesh import axis_size, current_mesh
 from dlrover_tpu.ops.flash_attention import mha_reference
 
 _NEG_INF = -1e30
@@ -211,7 +211,7 @@ def ring_attention(
     spec = P(tuple(data_axes), axis_name, head_axis, None)
     if segment_ids is not None:
         seg_spec = P(tuple(data_axes), axis_name)
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             functools.partial(_ring_shard, axis_name=axis_name, sp=sp),
             mesh=mesh,
             in_specs=(spec, spec, spec, seg_spec),
@@ -219,7 +219,7 @@ def ring_attention(
             check_vma=False,
         )
         return fn(q, k, v, segment_ids)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_shard, axis_name=axis_name, sp=sp),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from dlrover_tpu.common.log import logger
-from dlrover_tpu.parallel.mesh import axis_size, compat_shard_map, current_mesh
+from dlrover_tpu.parallel.mesh import axis_size, current_mesh
 from dlrover_tpu.ops.flash_attention import flash_attention_gqa, mha_reference
 
 
@@ -90,7 +90,7 @@ def ulysses_attention(
     )
     if segment_ids is not None:
         seg_spec = P(tuple(data_axes), axis_name)
-        fn = compat_shard_map(
+        fn = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(spec, spec, spec, seg_spec),
@@ -98,7 +98,7 @@ def ulysses_attention(
             check_vma=False,
         )
         return fn(q, k, v, segment_ids)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -21,17 +21,22 @@ The serving tier around the model's KV-cache decode path:
 ``rl/serving.py`` stays as the minimal slot-pool reference engine.
 """
 
-from dlrover_tpu.serving.paged_cache import BlockPool  # noqa: F401
-from dlrover_tpu.serving.engine import PagedServingEngine  # noqa: F401
-from dlrover_tpu.serving.fleet import (  # noqa: F401
-    BROWNOUT_RUNGS,
-    BrownoutController,
-    FleetAutoscaler,
-    ReplicaSet,
-    spawn_with_retry,
-)
-from dlrover_tpu.serving.gateway import (  # noqa: F401
-    InferenceGateway,
-    LocalReplica,
-    ProcessReplica,
-)
+# Names resolve on first use: the gateway lives in a process that must
+# never import JAX (a decode worker owns the chip), the engine needs it.
+from dlrover_tpu.common.lazy import lazy_exports
+
+_LAZY = {
+    "BlockPool": "dlrover_tpu.serving.paged_cache",
+    "PagedServingEngine": "dlrover_tpu.serving.engine",
+    "BROWNOUT_RUNGS": "dlrover_tpu.serving.fleet",
+    "BrownoutController": "dlrover_tpu.serving.fleet",
+    "FleetAutoscaler": "dlrover_tpu.serving.fleet",
+    "ReplicaSet": "dlrover_tpu.serving.fleet",
+    "spawn_with_retry": "dlrover_tpu.serving.fleet",
+    "InferenceGateway": "dlrover_tpu.serving.gateway",
+    "LocalReplica": "dlrover_tpu.serving.gateway",
+    "ProcessReplica": "dlrover_tpu.serving.gateway",
+}
+
+__all__ = sorted(_LAZY)
+__getattr__ = lazy_exports(__name__, _LAZY)

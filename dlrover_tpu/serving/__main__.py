@@ -5,7 +5,7 @@ the deterministic tiny model from the CLI args (no parameter shipping
 — see ``worker.build_tiny_model``), starts a
 :class:`~dlrover_tpu.serving.worker.ServingWorkerServer` on an
 ephemeral port and writes a JSON ready file ``{"name", "port", "pid",
-"uid"}`` once serving — the same handshake idiom as the kv shard
+"uid", "device"}`` once serving — the same handshake idiom as the kv shard
 entrypoint (``kv_service/__main__.py``).  Used by the gateway's
 ``ProcessReplica`` and the SIGKILL chaos drill, which need the decode
 worker to be a genuinely separate OS process (killable with SIGKILL).
@@ -67,6 +67,12 @@ def main(argv=None) -> int:
             directory=args.events_dir, role="decode", rank=os.getpid()
         )
 
+    import jax
+
+    from dlrover_tpu.common.platform import configure_compile_cache
+
+    # A respawned replica must find what its predecessor compiled.
+    configure_compile_cache()
     model, params = build_tiny_model(
         vocab_size=args.vocab,
         hidden_size=args.hidden,
@@ -108,11 +114,19 @@ def main(argv=None) -> int:
     signal.signal(signal.SIGINT, _term)
 
     if args.ready_file:
+        devices = jax.devices()
         payload = {
             "name": args.name,
             "port": server.port,
             "pid": os.getpid(),
             "uid": server._uid,
+            # What this replica serves from, as JAX reports it: a worker
+            # on the wrong device must not be able to say nothing.
+            "device": {
+                "platform": devices[0].platform,
+                "kind": devices[0].device_kind,
+                "count": len(devices),
+            },
         }
         tmp = args.ready_file + ".tmp"
         with open(tmp, "w") as f:

@@ -215,9 +215,10 @@ class ProcessReplica:
             cmd += [f"--{str(k).replace('_', '-')}", str(v)]
         env = dict(os.environ)
         # extra_env reaches the worker before its imports run — the
-        # chaos drills arm DLROVER_FAULTS in the child this way.
+        # chaos drills arm DLROVER_FAULTS in the child this way.  The
+        # platform is inherited, never defaulted: a replica serves from
+        # the CPU only where the environment asks for it.
         env.update(extra_env or {})
-        env.setdefault("JAX_PLATFORMS", "cpu")
         self._log = open(os.path.join(workdir, f"{self.uid}.log"), "wb")
         self._proc = subprocess.Popen(
             cmd, env=env, stdout=self._log, stderr=subprocess.STDOUT
@@ -237,6 +238,8 @@ class ProcessReplica:
             info = json.load(f)
         self.pid = int(info["pid"])
         self.port = int(info["port"])
+        # {"platform", "kind", "count"} as the worker's JAX reports it.
+        self.device: Dict[str, Any] = dict(info["device"])
         self._client = TransportClient(
             f"127.0.0.1:{self.port}", timeout=rpc_timeout_s
         )
@@ -256,6 +259,14 @@ class ProcessReplica:
             "completions": list(p.completions),
             "stats": dict(p.stats),
         }
+
+    def verify(self, tokens: List[int], prompt_len: int) -> Dict[str, Any]:
+        """Score a finished completion against the plain forward of the
+        worker's own weights (``comm.ServeVerify``)."""
+        res = self._client.get(0, "gateway", comm.ServeVerify(
+            tokens=list(tokens), prompt_len=int(prompt_len),
+        ))
+        return {"row_max": list(res.row_max), "margin": list(res.margin)}
 
     def control(self, publish_prefix: Optional[bool] = None) -> bool:
         flag = -1 if publish_prefix is None else int(bool(publish_prefix))

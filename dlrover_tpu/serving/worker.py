@@ -66,6 +66,37 @@ def build_tiny_model(
     return model, params
 
 
+def reference_margins(
+    forward, params, tokens: List[int], prompt_len: int, max_len: int
+):
+    """The plain reference for a finished completion: ONE non-paged,
+    cache-free forward over prompt + completion.  Returns ``(row_max,
+    margin)`` per generated position — the reference row's maximum logit
+    and how far below it the token the engine chose sits.  Exact token
+    equality is too strict a check on seeded weights, whose logits
+    nearly tie; a margin within the dtype's tolerance is not.
+
+    ``forward`` is the server's one ``jax.jit(model.apply)``.  A sequence
+    is no longer than the engine would have served, and is padded to
+    ``max_len`` (causal attention: the tail changes no earlier row), so
+    the forward is traced and compiled for one shape, once."""
+    if not 0 < prompt_len < len(tokens) <= max_len:
+        raise ValueError(
+            f"verify takes 0 < prompt_len < len(tokens) <= {max_len}, "
+            f"got prompt_len={prompt_len}, {len(tokens)} tokens"
+        )
+    n = len(tokens)
+    ids = jnp.asarray(tokens + [0] * (max_len - n), jnp.int32)[None, :]
+    logits = forward({"params": params}, ids)[0]
+    # Row t predicts token t + 1.
+    rows = logits[prompt_len - 1: n - 1].astype(jnp.float32)
+    chosen = jnp.take_along_axis(
+        rows, ids[0, prompt_len:n, None], axis=-1
+    )[:, 0]
+    row_max = rows.max(axis=-1)
+    return row_max.tolist(), (row_max - chosen).tolist()
+
+
 def warmup_engine(model, params, **engine_kw) -> None:
     """Pre-compile the serving tick before the worker signals ready.
 
@@ -103,6 +134,8 @@ class ServingWorkerServer:
         pump_idle_s: float = 0.005,
         tick_delay_s: float = 0.0,
     ):
+        self._params, self._max_len = params, max_len
+        self._reference = jax.jit(model.apply)  # ServeVerify's forward
         self._engine = PagedServingEngine(
             model,
             params,
@@ -147,6 +180,14 @@ class ServingWorkerServer:
                 return comm.ServeSubmitResult(accepted=True)
             except ValueError as e:
                 return comm.ServeSubmitResult(accepted=False, reason=str(e))
+        if isinstance(message, comm.ServeVerify):
+            # Outside the engine lock: the reference forward shares no
+            # state with the engine, and the pump must keep ticking.
+            row_max, margin = reference_margins(
+                self._reference, self._params, list(message.tokens),
+                int(message.prompt_len), self._max_len,
+            )
+            return comm.ServeVerifyResult(row_max=row_max, margin=margin)
         if isinstance(message, comm.ServeControl):
             with self._lock:
                 if message.publish_prefix >= 0:

@@ -127,6 +127,10 @@ class Trainer:
             self._train_iter = iter(train_batches)
         self._sample_batch = sample_batch
 
+        # A restarted worker must find what its predecessor compiled.
+        from dlrover_tpu.common.platform import configure_compile_cache
+
+        configure_compile_cache()
         ok, result, strategy = auto_accelerate(
             model,
             optimizer=optimizer,
@@ -188,7 +192,7 @@ class Trainer:
             tokens = int(np.prod(ids.shape)) if ids is not None else 8192
             frac = costmodel.wus_collective_fraction(
                 delta, n_params, tokens_per_step=tokens,
-                backend=jax.default_backend(),
+                backend=costmodel.attached_generation(),
             )
             if frac is not None:
                 profiler.set_collective_fraction(frac, source="costmodel")
@@ -198,6 +202,8 @@ class Trainer:
                     wus_plan.mode, "x".join(wus_plan.axes), frac,
                     delta["opt_hbm_bytes_saved_per_chip"] / 2**20,
                 )
+        except KeyError as e:
+            logger.info("wus collective split not modeled: %s", e)
         except Exception:  # noqa: BLE001 — advisory only
             logger.exception("wus collective split install failed")
 
@@ -228,7 +234,7 @@ class Trainer:
             ))
             pred = costmodel.packed_vs_dense_prediction(
                 n_params, np.asarray(seg), heads, head_dim, layers,
-                backend=jax.default_backend(),
+                backend=costmodel.attached_generation(),
             )
             profiler.set_packed_prediction(
                 pred["packed_pred_tok_s"], pred["dense_pred_tok_s"],
@@ -242,6 +248,8 @@ class Trainer:
                 pred["reduction"], pred["packed_pred_tok_s"],
                 pred["dense_pred_tok_s"], pred["packing_efficiency"],
             )
+        except KeyError as e:
+            logger.info("packed prediction not modeled: %s", e)
         except Exception:  # noqa: BLE001 — advisory only
             logger.exception("packed prediction install failed")
 

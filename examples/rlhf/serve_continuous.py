@@ -30,10 +30,6 @@ sys.path.insert(
 
 
 def main(argv=None):
-    from dlrover_tpu.common.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
-
     p = argparse.ArgumentParser()
     p.add_argument("--smoke", action="store_true", help="tiny CI run")
     p.add_argument("--slots", type=int, default=4)

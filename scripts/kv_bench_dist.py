@@ -14,7 +14,7 @@ wire), and records per-shard-count:
   container every process time-slices ONE core, so client wall-clock
   cannot scale past 1×; service capacity is what N dedicated hosts
   would serve, the same calibrated-proxy honesty contract as the blind
-  TPU entries in PERF_LEDGER.jsonl (docs/KV_SERVICE.md §Bench
+  TPU entries in perf_history.jsonl (docs/KV_SERVICE.md §Bench
   methodology).  Entries carry ``cores``/``colocated``/``aggregation``
   flags so nobody mistakes one for the other.
 * gather latency histogram (client-observed p50/p90/p99 per batch).
@@ -24,7 +24,7 @@ wire), and records per-shard-count:
 chain, and record recovery + membership-switch time and the lost-row
 count versus a host-side oracle (must be zero).
 
-Each run appends ``kind="kv"`` entries to PERF_LEDGER.jsonl and writes
+Each run appends ``kind="kv"`` entries to perf_history.jsonl and writes
 ``KV_BENCH_DIST.json``; ``round_gate.py --kv`` fronts a small
 configuration of this same harness.
 """

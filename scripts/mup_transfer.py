@@ -127,9 +127,6 @@ def optimum(curve):
 
 
 def main():
-    from dlrover_tpu.common.platform import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     lrs = [1e-3, 3e-3, 1e-2, 3e-2, 1e-1, 3e-1]
     widths = [64, 256]
     results = sweep(widths, lrs, steps=60)

@@ -13,8 +13,8 @@ the round record asks of the observability plane:
   ready, does the canary burn produce a ``canary_divergence`` verdict?
 * do ``/fleetz.json`` and the ``top`` renderer serve the result?
 
-Prints one JSON line; ``ok`` means all four held.  Never touches the
-tunnel — scripted HTTP sources, loopback only, no model, no jax compute.
+Prints one JSON line; ``ok`` means all four held.  Never touches a
+chip — scripted HTTP sources, loopback only, no model, no jax compute.
 
 Usage: python scripts/observer_probe.py [--baseline-ticks 3]
 """

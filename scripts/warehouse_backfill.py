@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Backfill the telemetry warehouse from the repo's flat perf history.
 
-Ingests ``PERF_LEDGER.jsonl`` (every round's throughput entry, measured
+Ingests ``perf_history.jsonl`` (every round's throughput entry, measured
 or blind) and the ``BENCH_r0*.json`` harness outputs, so rounds 1..N are
 queryable through ``python -m dlrover_tpu.brain report`` and the
 warm-start API from day one.
@@ -33,7 +33,7 @@ def main(argv=None) -> int:
     )
     p.add_argument(
         "--root", default=None,
-        help="directory holding PERF_LEDGER.jsonl / BENCH_r0*.json "
+        help="directory holding perf_history.jsonl / BENCH_r0*.json "
         "(default: the repo root)",
     )
     args = p.parse_args(argv)

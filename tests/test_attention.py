@@ -55,6 +55,102 @@ class TestFlashAttention:
         np.testing.assert_allclose(out, mha_reference(q, k, v), atol=1e-5)
 
 
+class TestShardKernelOverMesh:
+    """A compiled Mosaic kernel cannot be partitioned by GSPMD, so over a
+    mesh it runs under shard_map (batch over dp x fsdp, heads over tp).
+    The wrapper is exercised here with the interpret-mode kernel; that the
+    real one then compiles for four chips is tests/test_chip_compile.py."""
+
+    @pytest.mark.parametrize("segmented", [False, True])
+    def test_fwd_and_grads_match_the_unsharded_kernel(
+        self, devices8, segmented
+    ):
+        from dlrover_tpu.ops.flash_attention import shard_kernel_over_mesh
+
+        mesh = build_mesh(MeshConfig(dp=2, fsdp=2, tp=2), devices8)
+        q, k, v = _rand_qkv(b=4, s=128, h=4, h_kv=2)
+        seg = None
+        if segmented:
+            seg = jnp.asarray(
+                np.repeat(np.arange(4)[None, :], 4, 0).repeat(32, 1),
+                jnp.int32,
+            )
+
+        def kernel(q_, k_, v_, seg_):
+            return flash_attention_gqa(
+                q_, k_, v_, segment_ids=seg_, block_q=64, block_kv=64,
+                interpret=True,
+            )
+
+        def sharded(q_, k_, v_):
+            with use_mesh(mesh):
+                return shard_kernel_over_mesh(kernel, q_, k_, v_, seg)
+
+        out = jax.jit(sharded)(q, k, v)
+        ref = kernel(q, k, v, seg)
+        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        # One (batch, head) shard per device: the work is really spread.
+        assert len({s.device for s in out.addressable_shards}) == 8
+        g1 = jax.jit(jax.grad(_loss_of(sharded), argnums=(0, 1, 2)))(q, k, v)
+        g2 = jax.grad(
+            _loss_of(lambda *a: kernel(*a, seg)), argnums=(0, 1, 2)
+        )(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)
+
+    def test_one_device_or_no_mesh_is_a_plain_call(self):
+        from dlrover_tpu.ops.flash_attention import shard_kernel_over_mesh
+
+        q, k, v = _rand_qkv(s=64)
+        seen = []
+
+        def kernel(q_, k_, v_, seg_):
+            seen.append(q_.shape)
+            return q_
+
+        assert shard_kernel_over_mesh(kernel, q, k, v) is q
+        with use_mesh(build_mesh(MeshConfig(dp=-1), jax.devices()[:1])):
+            assert shard_kernel_over_mesh(kernel, q, k, v) is q
+        assert seen == [q.shape, q.shape]  # global shapes: no shard_map
+
+    @pytest.mark.parametrize("manual", [("dp", "sp", "tp"), ("sp",)])
+    def test_inside_a_manual_region_only_automatic_axes_are_taken(
+        self, devices8, manual
+    ):
+        """A caller already under shard_map (Ulysses) has made axes manual;
+        the wrapper shards over what is left, or calls the kernel as is."""
+        from jax.sharding import PartitionSpec as P
+
+        from dlrover_tpu.ops.flash_attention import shard_kernel_over_mesh
+
+        mesh = build_mesh(MeshConfig(dp=2, sp=2, tp=2), devices8)
+        q, k, v = _rand_qkv(b=4, s=128, h=4, h_kv=4)
+        seen = []
+
+        def kernel(q_, k_, v_, seg_):
+            seen.append(q_.shape)
+            return flash_attention_gqa(
+                q_, k_, v_, block_q=64, block_kv=64, interpret=True
+            )
+
+        # Heads over sp, as Ulysses holds them between its all_to_alls.
+        spec = P(
+            "dp" if "dp" in manual else None, None,
+            ("sp", "tp") if "tp" in manual else "sp", None,
+        )
+        with use_mesh(mesh):
+            out = jax.jit(jax.shard_map(
+                lambda *a: shard_kernel_over_mesh(kernel, *a),
+                mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+                axis_names=frozenset(manual), check_vma=False,
+            ))(q, k, v)
+        np.testing.assert_allclose(
+            out, mha_reference(q, k, v), atol=2e-5, rtol=2e-5
+        )
+        # Either way the kernel sees one (batch, head) shard per device.
+        assert seen == [(2, 128, 1, 64)]
+
+
 class TestSplashAttention:
     """Off-TPU the splash wrapper must fall back to the in-tree path with
     identical semantics; on TPU the library kernel takes over (exercised by
@@ -186,6 +282,20 @@ class TestUlysses:
         g2 = jax.grad(_loss_of(mha_reference), argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=1e-3, rtol=1e-3)
+
+    def test_traces_with_the_compiled_kernel(self, devices8, monkeypatch):
+        """On a TPU the inner kernel is the compiled one, which goes through
+        shard_kernel_over_mesh: inside Ulysses' own region it must not open
+        a second shard_map over axes that are already manual.  Tracing is
+        as far as the CPU goes; tests/test_chip_compile.py compiles it."""
+        from dlrover_tpu.ops import flash_attention
+
+        monkeypatch.setattr(flash_attention, "pallas_interpret", lambda: False)
+        mesh = build_mesh(MeshConfig(dp=2, sp=2, tp=2), devices8)
+        q, k, v = _rand_qkv(b=2, s=256, h=4, h_kv=4)
+        with use_mesh(mesh):
+            jaxpr = str(jax.make_jaxpr(ulysses_attention)(q, k, v))
+        assert jaxpr.count("shard_map") == 1 and "pallas_call" in jaxpr
 
 
 class TestModelWithSPAttention:

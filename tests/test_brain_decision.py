@@ -606,7 +606,7 @@ class TestCapacityPlanner:
         finally:
             wh.close()
         assert plan["proposed"] == {
-            "max_replicas": 2, "standby_target": 1, "chip_gen": "tpu",
+            "max_replicas": 2, "standby_target": 1, "chip_gen": "v5e",
         }
         assert plan["capacity"]["per_replica_tokens_per_sec"] == 120.0
         assert plan["traffic"]["windows"] == 60

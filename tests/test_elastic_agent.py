@@ -85,7 +85,7 @@ class TestElasticTrainingAgent:
         )
         config = ElasticLaunchConfig(
             min_nodes=1, max_nodes=1, nproc_per_node=2,
-            monitor_interval=0.2, rdzv_timeout=15,
+            monitor_interval=0.2, rdzv_timeout=15, accelerator="cpu",
         )
         agent = ElasticTrainingAgent(
             config, [sys.executable, script], client
@@ -312,6 +312,7 @@ class TestTpurunCLI:
             [
                 "--nnodes", "1",
                 "--nproc_per_node", "2",
+                "--accelerator", "cpu",
                 "--monitor-interval", "0.2",
                 script,
             ]

@@ -264,7 +264,7 @@ class TestCostModel:
         must reproduce round-2's own measured throughput — that's what
         'calibrated' means."""
         pred = costmodel.predict_tokens_per_sec(
-            134105856, tokens_per_step=8 * 1024, backend="tpu", mfu=0.4839
+            134105856, tokens_per_step=8 * 1024, backend="v5e", mfu=0.4839
         )
         assert pred["predicted_tokens_per_sec"] == pytest.approx(
             118483.9, rel=0.01
@@ -281,7 +281,7 @@ class TestCostModel:
     def test_calibration_prefers_green_then_ledger_then_assumed(
         self, tmp_path, monkeypatch
     ):
-        ledger = tmp_path / "PERF_LEDGER.jsonl"
+        ledger = tmp_path / "perf_history.jsonl"
         monkeypatch.setenv("DLROVER_PERF_LEDGER", str(ledger))
         # Nothing anywhere: assumed.
         cal = costmodel.load_calibration(str(tmp_path))
@@ -299,7 +299,7 @@ class TestCostModel:
             path=str(ledger),
         )
         cal = costmodel.load_calibration(str(tmp_path))
-        assert cal["source"] == "PERF_LEDGER.jsonl"
+        assert cal["source"] == "perf_history.jsonl"
         assert cal["mfu"] == 0.48
         # BENCH_LAST_GREEN.json beats the ledger.
         with open(tmp_path / "BENCH_LAST_GREEN.json", "w") as f:
@@ -310,7 +310,7 @@ class TestCostModel:
         assert cal["mfu"] == 0.4839
 
     def test_calibrated_cpu_proxy(self, tmp_path, monkeypatch):
-        ledger = tmp_path / "PERF_LEDGER.jsonl"
+        ledger = tmp_path / "perf_history.jsonl"
         monkeypatch.setenv("DLROVER_PERF_LEDGER", str(ledger))
         assert costmodel.calibrated_cpu_proxy(50.0) is None  # no history
         costmodel.append_ledger(
@@ -341,18 +341,36 @@ class TestCostModel:
         assert entries[0]["a"] == 1 and entries[1]["b"] == 2
         assert all("ts" in e for e in entries)
 
-    def test_checked_in_ledger_calibrates_the_repo(self):
-        """The seeded repo-root ledger must yield a real calibration:
-        round 2's green measurement, not the assumed default."""
-        entries = costmodel.read_ledger(
-            os.path.join(REPO, "PERF_LEDGER.jsonl")
-        )
-        assert entries, "PERF_LEDGER.jsonl missing or empty"
-        rounds = {e.get("round") for e in entries}
-        assert {"r01", "r02", "r03", "r04", "r05"} <= rounds
-        blind = [e for e in entries if e.get("round") in
-                 ("r03", "r04", "r05") and e.get("source") == "bench"]
-        assert blind and all(e.get("blind") for e in blind)
-        cal = costmodel.load_calibration(REPO)
-        assert cal["source"] == "PERF_LEDGER.jsonl"
-        assert cal["mfu"] == pytest.approx(0.4839)
+    def test_mixed_history_calibrates_from_its_green_round(
+        self, tmp_path, monkeypatch
+    ):
+        """A history shaped like the program writes it — a failed round,
+        one green on-chip round, then blind CPU-fallback rounds and a
+        blind gate line — must calibrate from the green round: newer
+        blind or failed lines never displace it."""
+        ledger = tmp_path / costmodel.LEDGER_BASENAME
+        monkeypatch.setenv("DLROVER_PERF_LEDGER", str(ledger))
+        rows = [
+            {"round": "r01", "source": "bench", "backend": "none",
+             "tokens_per_sec": 0.0, "measured": False, "blind": True,
+             "error": "backend setup error"},
+            {"round": "r02", "source": "bench", "backend": "tpu",
+             "tokens_per_sec": 120000.0, "measured": True, "blind": False,
+             "mfu": 0.49, "n_params": 134105856, "steps": 80},
+            {"round": "r03", "source": "bench", "backend": "cpu-fallback",
+             "tokens_per_sec": 48.0, "measured": True, "blind": True,
+             "predicted_tpu_tokens_per_sec": 119000.0},
+            {"round": "r04", "source": "gate", "backend": "cpu-fallback",
+             "tokens_per_sec": None, "measured": False, "blind": True},
+            {"round": "r05", "source": "bench", "backend": "tpu",
+             "tokens_per_sec": 90000.0, "measured": True, "blind": True,
+             "mfu": 0.37, "archived": True},
+        ]
+        for row in rows:
+            costmodel.append_ledger(row, path=str(ledger))
+        entries = costmodel.read_ledger()
+        assert [e["round"] for e in entries] == [r["round"] for r in rows]
+        cal = costmodel.load_calibration(str(tmp_path))
+        assert cal["source"] == costmodel.LEDGER_BASENAME
+        assert cal["mfu"] == pytest.approx(0.49)
+        assert cal["n_params"] == 134105856

@@ -263,7 +263,7 @@ class TestBackfill:
              "tokens_per_sec": 118000.0, "mfu": 0.48, "source": "bench",
              "backend": "cpu", "measured": True, "blind": False},
         ]
-        with open(os.path.join(root, "PERF_LEDGER.jsonl"), "w") as f:
+        with open(os.path.join(root, "perf_history.jsonl"), "w") as f:
             for e in ledger:
                 f.write(json.dumps(e) + "\n")
             f.write('{"torn": ')  # crashed appender's partial line
@@ -292,17 +292,28 @@ class TestBackfill:
         assert by_round["r03"]["tokens_per_sec"] == pytest.approx(99000.0)
         wh.close()
 
-    def test_repo_backfill_ingests_real_history(self, tmp_path):
-        # the repo's own flat files are the real fixture: rounds 1..N
-        if not os.path.exists(os.path.join(REPO, "PERF_LEDGER.jsonl")):
-            pytest.skip("repo has no PERF_LEDGER.jsonl")
+    def test_backfill_reads_program_history_never_the_drivers_ledger(
+        self, tmp_path
+    ):
+        """A checkout-shaped fixture directory: every kind of line the
+        program writes is ingested, and the file under the driver's name
+        (``PERF_LEDGER.jsonl``, a decoy there) is never opened."""
+        root = os.path.join(REPO, "tests", "fixtures", "perf_history")
+        assert os.path.exists(os.path.join(root, "PERF_LEDGER.jsonl"))
         wh = _mk(tmp_path)
-        counts = wh.backfill(root=REPO)
-        assert counts["ledger"] > 0
-        assert counts["bench"] > 0
+        counts = wh.backfill(root=root)
+        assert counts == {"ledger": 7, "bench": 1}  # torn tail dropped
+        runs = {r["run"] for r in wh.runs("perf-ledger")}
+        assert runs == {"s01", "s02", "s03"}
+        assert "driver-decoy" not in runs
+        trend = wh.perf_trend()
+        assert all(p["tokens_per_sec"] != 7.0 for p in trend)
         assert any(
-            p["tokens_per_sec"] for p in wh.perf_trend()
-        ), "no measured throughput ingested from repo history"
+            p["round"] == "s02"
+            and p["tokens_per_sec"] == pytest.approx(120000.0)
+            for p in trend
+        )
+        assert wh.kv_trend() and wh.serve_trend()
         wh.close()
 
 
