@@ -1,0 +1,10 @@
+"""Median HBM-to-host drain of the window's whole saves, as the engine's
+"staged to shm" line gives it."""
+
+import runlog
+
+UNIT = "s"
+
+
+def read(run):
+    return runlog.median(g["drain_s"] for _s, g in runlog.whole_saves(run))
