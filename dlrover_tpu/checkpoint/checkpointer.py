@@ -87,6 +87,10 @@ class Checkpointer:
                 abstract_state, shardings, step=step
             )
             extra["step"] = step if step is not None else -1
+            # source ("shm" | "storage" | "none"), bytes, and how many
+            # leaves were uploaded as saved (direct) or pasted together
+            # first (assembled).
+            extra.update(self._engine.last_restore)
         return step, state
 
     def verified_steps(self, deep: bool = True):

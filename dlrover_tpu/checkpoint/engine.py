@@ -13,6 +13,7 @@ mesh layout into arrays sharded for the *current* mesh — elastic restarts
 with a different world size reshard transparently.
 """
 
+import collections
 import os
 import pickle
 import threading
@@ -37,6 +38,7 @@ from dlrover_tpu.checkpoint.ckpt_saver import (
 )
 from dlrover_tpu.checkpoint.shm_handler import (
     SharedMemoryHandler,
+    ShmCorruptError,
     _ShardEntry,
 )
 from dlrover_tpu.checkpoint.storage import (
@@ -287,6 +289,92 @@ def _assemble(entries: List[_ShardEntry], key: str = "") -> np.ndarray:
     return out
 
 
+def _upload_copies(sharding) -> bool:
+    """Whether ``device_put`` onto this sharding's devices copies the host
+    bytes into device memory.  A TPU's upload always lands in HBM.  The CPU
+    backend may alias an aligned host buffer instead, and a donating step
+    then frees memory it never owned (the crash recorded in
+    ``shm_handler.load_state_dict``): there a view is copied first."""
+    return all(d.platform != "cpu" for d in sharding.device_set)
+
+
+def _upload(arrays: List[np.ndarray], targets: List[Any]) -> List[jax.Array]:
+    """Start the host→device transfer of each array onto its target (a
+    sharding or a device); returns before the bytes have landed."""
+    return jax.device_put(arrays, targets)
+
+
+def _saved_shards(entries: List[_ShardEntry], sharding):
+    """``[(device, data)]`` when every shard the target sharding places on
+    this process's devices was saved with exactly that shard's bounds (on
+    one chip: one entry covering the whole array), else None.  Decided from
+    the saved bounds against the wanted bounds, never from an option."""
+    shape = entries[0].global_shape
+    if shape is None or not sharding.is_fully_addressable:
+        # A sharding that spans processes stays with _assemble: its
+        # coverage check is what sends a multi-host shm restore to storage.
+        return None
+    by_bounds = {e.index: e for e in entries if e.global_shape == shape}
+    shards = []
+    for device, index in sharding.addressable_devices_indices_map(
+        shape
+    ).items():
+        bounds = _slices_to_bounds(index, shape)
+        entry = by_bounds.get(bounds)
+        if entry is None:
+            return None
+        # The staged copy of a 0-d array is 1-d (np.ascontiguousarray).
+        shards.append((device, entry.data.reshape([b - a for a, b in bounds])))
+    return shards
+
+
+def _place_leaf(entries: List[_ShardEntry], key: str, sharding):
+    """One leaf of the shm block → ``(value, "direct" | "assembled")``.
+    The entries' data are views into the block.
+
+    Direct: the saved shards are the wanted shards, so each is uploaded as
+    it lies (no ``_assemble`` copy of the array onto itself).  Anything
+    else — layout changed across the restart, partial coverage, entries
+    without bounds, a leaf that stays on the host — goes through
+    ``_assemble`` and its coverage check, which pastes into owned memory."""
+    shards = None if sharding is None else _saved_shards(entries, sharding)
+    if shards is None:
+        arr = _assemble(entries, key)
+        if arr is entries[0].data:  # an entry without bounds, as it lies
+            arr = arr.copy()
+        if sharding is None:
+            return arr, "assembled"
+        return _upload([arr], [sharding])[0], "assembled"
+    datas = [data for _, data in shards]
+    if not _upload_copies(sharding):
+        datas = [d.copy() for d in datas]
+    if len(shards) == 1:
+        return _upload(datas, [sharding])[0], "direct"
+    return jax.make_array_from_single_device_arrays(
+        entries[0].global_shape, sharding,
+        _upload(datas, [device for device, _ in shards]),
+    ), "direct"
+
+
+def _flatten_target(abstract_state, shardings=None):
+    """``(flat, treedef, targets)`` of the tree a restore fills: the leaves
+    with their key paths, and for each the sharding its array is uploaded
+    to (``shardings`` if given, else the leaf's own) or None for a leaf
+    that stays on the host."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(abstract_state)
+    if shardings is not None:
+        targets = jax.tree_util.tree_leaves(shardings)
+        assert len(targets) == len(flat), (
+            "shardings tree does not match state tree"
+        )
+    else:
+        targets = [
+            leaf.sharding if isinstance(leaf, jax.Array) else None
+            for _, leaf in flat
+        ]
+    return flat, treedef, targets
+
+
 def host_tree_to_state(
     host: Dict[Tuple, Any],
     abstract_state,
@@ -306,13 +394,7 @@ def host_tree_to_state(
         else:
             objects[key] = value
 
-    flat, treedef = jax.tree_util.tree_flatten_with_path(abstract_state)
-    flat_shardings = None
-    if shardings is not None:
-        flat_shardings = jax.tree_util.tree_leaves(shardings)
-        assert len(flat_shardings) == len(flat), (
-            "shardings tree does not match state tree"
-        )
+    flat, treedef, targets = _flatten_target(abstract_state, shardings)
     leaves = []
     # Batch ALL host→device uploads into one device_put call at the end:
     # jax pipelines the transfers (the restore twin of the batched
@@ -323,11 +405,8 @@ def host_tree_to_state(
         key = jax.tree_util.keystr(path)
         if key in grouped:
             arr = _assemble(grouped[key], key)
-            if flat_shardings is not None:
-                puts.append((i, arr, flat_shardings[i]))
-                leaves.append(None)
-            elif isinstance(leaf, jax.Array):
-                puts.append((i, arr, leaf.sharding))
+            if targets[i] is not None:
+                puts.append((i, arr, targets[i]))
                 leaves.append(None)
             else:
                 leaves.append(arr)
@@ -399,6 +478,7 @@ class CheckpointEngine:
             name=f"{EVENT_QUEUE}_{uid}", create=False
         )
         self._last_queued_step: Optional[int] = None
+        self.last_restore: Dict[str, Any] = {}
         self._snapshot = _DeviceSnapshot()
         self._stager = _AsyncStager(self._stage_to_shm)
 
@@ -519,49 +599,140 @@ class CheckpointEngine:
 
         ``step`` pins the restore to a consensus-agreed step (recovery
         consensus, docs/CHECKPOINT.md): shm is only used when it holds
-        exactly that step, and storage restore targets it first."""
+        exactly that step, and storage restore targets it first.
+
+        ``last_restore`` says afterwards where the state came from and how
+        its leaves were placed (the ``restore`` span reports it)."""
+        self._count_restore("none", 0, 0, 0)
         # An in-flight async staging must land before we read shm.
         if not self._stager.wait():
             logger.warning(
                 "async staging did not finish cleanly before restore: "
                 "shm may hold an OLDER step than the last save dispatched"
             )
-        loaded = self._load_from_memory()
-        if loaded is not None and step is not None and loaded[0] != step:
-            logger.info(
-                "shm holds step %s but the world agreed on step %s; "
-                "skipping the in-memory restore", loaded[0], step,
-            )
-            loaded = None
-        if loaded is not None:
-            shm_step, host = loaded
-            try:
-                return shm_step, host_tree_to_state(
-                    host, abstract_state, shardings
-                )
-            except ValueError:
-                # Local shm doesn't cover the full state (sharding changed
-                # across the restart, or multi-host shm) → storage has it all.
-                logger.info(
-                    "shm restore incomplete for this layout; falling back "
-                    "to storage"
-                )
+        restored = self._restore_from_memory(abstract_state, shardings, step)
+        if restored is not None:
+            return restored
         loaded = self._load_from_storage(step)
         if loaded is None:
             return None, abstract_state
         step, host = loaded
         state = host_tree_to_state(host, abstract_state, shardings)
+        wanted = {
+            jax.tree_util.keystr(path)
+            for path, _ in _flatten_target(abstract_state)[0]
+        }
+        saved = [
+            (key, entry) for (key, _), entry in host.items()
+            if isinstance(entry, _ShardEntry) and key in wanted
+        ]
+        self._count_restore(
+            "storage", sum(e.data.nbytes for _, e in saved),
+            0, len({key for key, _ in saved}),
+        )
         return step, state
 
-    def _load_from_memory(self):
+    def _count_restore(self, source, nbytes, direct, assembled):
+        from dlrover_tpu.checkpoint import integrity
+
+        self.last_restore = {
+            "source": source, "bytes": int(nbytes),
+            "direct_leaves": direct, "assembled_leaves": assembled,
+        }
+        leaves = integrity._metric("dlrover_ckpt_restore_leaves_total")
+        leaves.inc(direct, path="direct")
+        leaves.inc(assembled, path="assembled")
+
+    def _restore_from_memory(self, abstract_state, shardings, step):
+        """The shm rung: ``(step, state)`` or None (→ storage).
+
+        Every tensor is crc-checked where it lies in the mapped block and
+        uploaded from there, leaf by leaf, the check of the next leaves
+        running while the previous ones upload; nothing state-sized is
+        allocated on the host.  A tensor's bytes reach ``device_put`` only
+        after its own check passed; one mismatch anywhere refuses the
+        whole block and drops the leaves already uploaded.
+
+        ``_shm_lock`` is held until the uploads have LANDED
+        (``jax.block_until_ready``), not only while bytes are copied out:
+        the uploads read from views that alias the block, and the next
+        save rewrites it."""
         try:
-            # Deliberate hold: _shm_lock is the cross-process mutex
-            # whose entire purpose is to cover this read — releasing it
-            # early would let the saver rewrite shm mid-load.
-            with self._shm_lock:
-                return self._shm_handler.load_state_dict()  # dlr: lock-held
+            self._shm_lock.acquire()
         except Exception:  # noqa: BLE001 — shm gone is a normal cold start
             return None
+        # Deliberate hold: _shm_lock is the cross-process mutex whose
+        # entire purpose is to cover this read — releasing it before the
+        # uploads land would let the saver rewrite the bytes in flight.
+        tensors = None
+        try:
+            try:
+                opened = self._shm_handler.verified_views()
+            except Exception:  # noqa: BLE001 — no block yet: cold start
+                return None
+            if opened is None:
+                return None
+            meta, objects, tensors = opened
+            if step is not None and meta.step != step:
+                logger.info(
+                    "shm holds step %s but the world agreed on step %s; "
+                    "skipping the in-memory restore", meta.step, step,
+                )
+                return None
+            state = self._place_verified(
+                meta, objects, tensors, abstract_state, shardings
+            )
+        except ShmCorruptError:
+            return None  # verdict emitted; the uploaded leaves die here
+        except ValueError:
+            # Local shm doesn't cover the full state (sharding changed
+            # across the restart, or multi-host shm) → storage has it all.
+            logger.info(
+                "shm restore incomplete for this layout; falling back "
+                "to storage"
+            )
+            return None
+        finally:
+            if tensors is not None:
+                tensors.close()
+            self._shm_lock.release()
+        return meta.step, state
+
+    def _place_verified(
+        self, meta, objects, tensors, abstract_state, shardings
+    ):
+        """Fill the target tree from the verified stream: a leaf is placed
+        as soon as its last saved shard is verified, and the tree is
+        returned once every upload has landed."""
+        flat, treedef, targets = _flatten_target(abstract_state, shardings)
+        slot = {
+            jax.tree_util.keystr(path): i for i, (path, _) in enumerate(flat)
+        }
+        leaves = [leaf for _, leaf in flat]
+        for (key, _), value in objects.items():
+            if key in slot:
+                leaves[slot[key]] = value
+        missing = collections.Counter(t.path[0] for t in meta.tensors)
+        parts: Dict[str, List[_ShardEntry]] = {}
+        counts = {"direct": 0, "assembled": 0}
+        nbytes = 0
+        for (key, _), entry in tensors:
+            missing[key] -= 1
+            if key not in slot:
+                continue
+            parts.setdefault(key, []).append(entry)
+            if missing[key]:
+                continue
+            entries = parts.pop(key)
+            i = slot[key]
+            leaves[i], how = _place_leaf(entries, key, targets[i])
+            counts[how] += 1
+            nbytes += sum(e.data.nbytes for e in entries)
+        jax.block_until_ready(leaves)  # dlr: lock-held
+        self._count_restore(
+            "shm", nbytes, counts["direct"], counts["assembled"]
+        )
+        return jax.tree_util.tree_unflatten(treedef, leaves)
 
     def _load_from_storage(self, step: Optional[int] = None):
         """Walk the restore ladder: requested/tracker step first, then

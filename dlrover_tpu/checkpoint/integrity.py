@@ -342,5 +342,9 @@ def _metric(name: str):
         "dlrover_ckpt_scrub_runs_total": (
             "Background scrubber validation sweeps."
         ),
+        "dlrover_ckpt_restore_leaves_total": (
+            "Restored array leaves by path: uploaded as saved (direct) "
+            "or pasted together on the host first (assembled)."
+        ),
     }
     return metrics.counter(name, helps.get(name, ""))

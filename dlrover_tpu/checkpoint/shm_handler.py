@@ -20,7 +20,8 @@ import pickle
 import struct
 import time
 import zlib
-from typing import Any, Dict, List, Optional, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
@@ -35,6 +36,9 @@ from dlrover_tpu.common.multi_process import (
 )
 
 _HEADER = struct.Struct("<Q")
+# Threads that crc-check tensors ahead of the restore's uploads: zlib
+# releases the GIL, and four keep ahead of one chip's host link.
+_VERIFY_WORKERS = 4
 
 
 @dataclasses.dataclass
@@ -62,6 +66,10 @@ class ShmMeta:
     objects_crc32: Optional[int] = None
 
 
+class ShmCorruptError(Exception):
+    """A tensor in the shm block failed its crc32 (verdict already out)."""
+
+
 def _leaf_entries(host_tree: Dict[Tuple, Any]):
     """Split {path: leaf} into array entries and plain-object entries."""
     arrays, objects = {}, {}
@@ -82,6 +90,15 @@ class _ShardEntry:
     data: np.ndarray
     global_shape: Optional[Tuple[int, ...]]
     index: Optional[Tuple[Tuple[int, Optional[int]], ...]]
+
+
+def _crc_matches(t: TensorMeta, data: np.ndarray) -> bool:
+    """The one crc check of both readers: the tensor's bytes, as they lie
+    in ``data``, against the digest staged with them."""
+    expected = getattr(t, "crc32", None)
+    if expected is None or not t.nbytes:
+        return True
+    return zlib.crc32(data.reshape(-1).view(np.uint8)) == expected
 
 
 def _default_job_uid() -> str:
@@ -252,6 +269,13 @@ class SharedMemoryHandler:
             bytes(buf[_HEADER.size : _HEADER.size + meta_len])
         )
 
+    def _tensor_base(self) -> int:
+        """Offset of the first tensor byte: header + pickled meta."""
+        (meta_len,) = _HEADER.unpack(
+            bytes(self.shared_memory.buf[: _HEADER.size])
+        )
+        return _HEADER.size + meta_len
+
     def load_state_dict(
         self, verify: bool = True
     ) -> Optional[Tuple[int, Dict[Tuple, Any]]]:
@@ -260,14 +284,15 @@ class SharedMemoryHandler:
         ``verify=True`` (default) checks every tensor's crc32 recorded at
         staging time — a corrupted shm snapshot is REFUSED (returns None,
         so callers fall through to verified storage) rather than handed
-        to ``device_put``."""
+        to ``device_put``.
+
+        The arrays are owned copies that outlive the shm lock: the reader
+        of the agent's saver, which writes to storage after releasing it.
+        The trainer's restore reads through :meth:`verified_views`."""
         meta = self.load_meta()
         if meta is None:
             return None
-        (meta_len,) = _HEADER.unpack(
-            bytes(self.shared_memory.buf[: _HEADER.size])
-        )
-        base = _HEADER.size + meta_len
+        base = self._tensor_base()
         if verify and not self._verify_objects(meta):
             return None
         out: Dict[Tuple, Any] = dict(pickle.loads(meta.objects))
@@ -289,14 +314,66 @@ class SharedMemoryHandler:
                     offset=base + t.offset,
                 ),
             )
-            expected = getattr(t, "crc32", None)
-            if verify and expected is not None and t.nbytes:
-                got = zlib.crc32(arr.reshape(-1).view(np.uint8))
-                if got != expected:
-                    self._emit_corrupt_verdict(meta.step, t.path)
-                    return None
+            if verify and not _crc_matches(t, arr):
+                self._emit_corrupt_verdict(meta.step, t.path)
+                return None
             out[t.path] = _ShardEntry(arr, t.global_shape, t.index)
         return meta.step, out
+
+    def verified_views(
+        self,
+    ) -> Optional[Tuple[ShmMeta, Dict[Tuple, Any], Iterator]]:
+        """The restore's reader: ``(meta, objects, tensors)`` or None.
+
+        ``tensors`` yields ``(path, _ShardEntry)`` in the order staged,
+        each entry's ``data`` a read-only VIEW into the mapped block whose
+        crc32 was checked where it lies — no owned copy of the state.  A
+        small pool checks the next tensors while the caller uploads the
+        ones already yielded (zlib releases the GIL).  The first mismatch
+        emits the ``ckpt_shm_corrupt`` verdict and raises
+        :class:`ShmCorruptError`: the caller drops what it built and falls
+        through to storage.  The object blob is checked before anything is
+        returned.  There is no unverified variant.
+
+        The views alias memory the next save rewrites: the caller holds
+        the shm lock until every byte it took has left the block (the
+        engine: until ``jax.block_until_ready`` on the uploads), and on a
+        backend whose ``device_put`` may alias host memory it copies first
+        (see the comment in :meth:`load_state_dict`)."""
+        meta = self.load_meta()
+        if meta is None or not self._verify_objects(meta):
+            return None
+        objects: Dict[Tuple, Any] = dict(pickle.loads(meta.objects))
+        return meta, objects, self._iter_verified(meta)
+
+    def _iter_verified(self, meta: ShmMeta) -> Iterator:
+        base = self._tensor_base()
+        buf = self.shared_memory.buf
+
+        def check(t: TensorMeta):
+            view = np.frombuffer(
+                buf, dtype=np.uint8, count=t.nbytes, offset=base + t.offset
+            ).view(np.dtype(t.dtype)).reshape(t.shape)
+            view.flags.writeable = False
+            # The one view that leaves this module uncopied: the engine
+            # uploads it under the shm lock, and copies first wherever
+            # device_put may alias host memory (engine._upload_copies).
+            return view, _crc_matches(t, view)  # dlr: noqa[DLR001]
+
+        pool = ThreadPoolExecutor(
+            _VERIFY_WORKERS, thread_name_prefix="ckpt-crc"
+        )
+        try:
+            for t, done in zip(
+                meta.tensors, [pool.submit(check, t) for t in meta.tensors]
+            ):
+                view, ok = done.result()
+                if not ok:
+                    self._emit_corrupt_verdict(meta.step, t.path)
+                    raise ShmCorruptError(meta.step, t.path)
+                yield t.path, _ShardEntry(view, t.global_shape, t.index)
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
 
     def _verify_objects(self, meta: ShmMeta) -> bool:
         expected = getattr(meta, "objects_crc32", None)

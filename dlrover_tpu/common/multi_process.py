@@ -13,6 +13,7 @@ Protocol: length-prefixed pickled ``(method, kwargs)`` request →
 processes of the same job on the same host behind filesystem permissions.
 """
 
+import mmap
 import os
 import pickle
 import queue
@@ -394,6 +395,26 @@ class SharedMemory(shared_memory.SharedMemory):
             resource_tracker.unregister(self._name, "shared_memory")
         except Exception:  # noqa: BLE001 — tracker may not know the block
             pass
+        if not create:
+            self._populate()
+
+    def _populate(self):
+        """Map an existing block with its pages already in this process's
+        page table (``MAP_POPULATE``).  Whoever attaches is about to read
+        or rewrite the whole block, and on the chip's host a page fault
+        costs 9 µs: the crc pass over a lazily mapped 5.84 GB checkpoint
+        took 13.8 s on one thread and 5.8 s on four, over a populated one
+        1.8 s and 0.57 s, and populating took 1 ms (PERF.md, PR 25)."""
+        try:
+            populated = mmap.mmap(
+                self._fd, self._size,
+                flags=mmap.MAP_SHARED | mmap.MAP_POPULATE,
+            )
+        except (OSError, ValueError):
+            return  # the lazy mapping of the base class stays
+        self._buf.release()
+        self._mmap.close()
+        self._mmap, self._buf = populated, memoryview(populated)
 
     def unlink(self):
         """Unlink guarded: racing unlinks across processes are fine."""

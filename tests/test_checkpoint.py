@@ -87,6 +87,38 @@ class TestIpcPrimitives:
         shm.unlink()
 
 
+    def test_attaching_maps_the_pages_at_once(self):
+        """A process that attaches to an existing block gets it with the
+        pages already in its page table (one MAP_POPULATE, no fault a
+        page as the restore walks it), and still shares the bytes."""
+        size = 8 << 20
+        name = f"test_shm_populated_{os.getpid()}"
+
+        def resident_kb(shm):
+            # Rss of this handle's own mapping, by its address.
+            start = np.frombuffer(shm.buf, np.uint8).__array_interface__[
+                "data"][0]
+            with open("/proc/self/smaps") as f:
+                lines = f.read().splitlines()
+            at = next(i for i, l in enumerate(lines)
+                      if l.startswith(f"{start:x}-"))
+            rss = next(l for l in lines[at:] if l.startswith("Rss:"))
+            return int(rss.split()[1])
+
+        shm = mp.create_shared_memory(name, create=True, size=size)
+        try:
+            shm.buf[:size] = b"\x01" * size  # the pages exist
+            other = mp.create_shared_memory(name, create=False)
+            assert resident_kb(other) >= size // 1024  # nothing touched yet
+            assert bytes(other.buf[-4:]) == b"\x01" * 4
+            other.buf[:4] = b"abcd"
+            assert bytes(shm.buf[:4]) == b"abcd"
+            other.close()
+        finally:
+            shm.close()
+            shm.unlink()
+
+
 class TestShmHandler:
     def test_roundtrip(self):
         from dlrover_tpu.checkpoint.shm_handler import (
@@ -369,3 +401,285 @@ class TestFlashCheckpoint:
         saver.save_shm_to_storage()
         assert ckpt.latest_persisted_step() == 13
         ckpt.close()
+
+
+# -- the restore reads shm in place (ISSUE 25) --------------------------------
+
+
+def _mixed_tree(scale=1.0):
+    """f32, bf16, a 0-d step, an empty array and two non-array leaves."""
+    return {
+        "w": jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32) * scale,
+        "h": (jnp.arange(16 * 8, dtype=jnp.float32) * scale)
+        .astype(jnp.bfloat16).reshape(16, 8),
+        "step": jnp.asarray(int(7 * scale), jnp.int32),
+        "empty": jnp.zeros((0, 4), jnp.float32),
+        "note": "saved" if scale == 1.0 else f"x{scale}",
+        "lr": 0.5 * scale,
+    }
+
+
+def _assert_trees_bit_equal(got, want):
+    got_flat = jax.tree_util.tree_flatten_with_path(got)[0]
+    want_flat = jax.tree_util.tree_flatten_with_path(want)[0]
+    assert [p for p, _ in got_flat] == [p for p, _ in want_flat]
+    for (path, a), (_, b) in zip(got_flat, want_flat):
+        if isinstance(b, jax.Array):
+            assert isinstance(a, jax.Array), path
+            assert a.dtype == b.dtype and a.shape == b.shape, path
+            assert a.sharding.is_equivalent_to(b.sharding, b.ndim), path
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), path
+        else:
+            assert a == b, path
+
+
+@pytest.fixture()
+def engine(tmp_path):
+    from dlrover_tpu.checkpoint.engine import CheckpointEngine
+
+    eng = CheckpointEngine(str(tmp_path / "ckpt"), start_saver=True)
+    yield eng
+    eng.close()
+
+
+def _block_bytes(eng):
+    """The whole mapped block as bytes (drop it before the block closes)."""
+    return np.frombuffer(eng._shm_handler.shared_memory.buf, np.uint8)
+
+
+def _second_lock(eng):
+    from dlrover_tpu.checkpoint.ckpt_saver import SHM_LOCK
+    from dlrover_tpu.checkpoint.shm_handler import job_uid_for
+
+    return mp.SharedLock(
+        name=f"{SHM_LOCK}_{job_uid_for(eng.checkpoint_dir)}_0"
+    )
+
+
+class TestRestoreInPlace:
+    def test_bit_equal_to_the_copying_reader(self, engine):
+        """The streaming restore and ``load_state_dict`` +
+        ``host_tree_to_state`` (the agent's reader, the storage rung's
+        builder) give the same tree, bit for bit."""
+        from dlrover_tpu.checkpoint.engine import host_tree_to_state
+
+        saved, target = _mixed_tree(), _mixed_tree(scale=3.0)
+        assert engine.save_to_memory(5, saved, block=True)
+        step, got = engine.load(target)
+        old_step, host = engine._shm_handler.load_state_dict()
+        want = host_tree_to_state(host, target)
+        assert step == old_step == 5
+        _assert_trees_bit_equal(got, want)
+        _assert_trees_bit_equal(got, saved)
+        assert engine.last_restore == {
+            "source": "shm", "direct_leaves": 4, "assembled_leaves": 0,
+            "bytes": 64 * 32 * 4 + 16 * 8 * 2 + 4,
+        }
+
+    def test_views_reach_an_uploader_that_copies(self, engine, monkeypatch):
+        """Where the upload copies into device memory (a TPU), the arrays
+        handed to it are views into the block, and the restore allocates
+        nothing state-sized on the host."""
+        import tracemalloc
+
+        from dlrover_tpu.checkpoint import engine as engine_mod
+
+        big = {
+            "a": jnp.ones((2048, 512), jnp.float32),
+            "b": jnp.full((2048, 512), 2.0, jnp.float32),
+            "step": jnp.asarray(3, jnp.int32),
+        }
+        assert engine.save_to_memory(1, big, block=True)
+        handed, large_allocs = [], []
+
+        def uploader(arrays, targets):
+            block = _block_bytes(engine)
+            handed.extend(
+                (a.nbytes, np.shares_memory(a, block), a.flags.writeable)
+                for a in arrays
+            )
+            del block
+            return [jnp.zeros(()) for _ in arrays]
+
+        def counting(fn):
+            def wrapper(shape, *args, **kw):
+                out = fn(shape, *args, **kw)
+                if out.nbytes >= 1 << 20:
+                    large_allocs.append(out.nbytes)
+                return out
+            return wrapper
+
+        monkeypatch.setattr(engine_mod, "_upload_copies", lambda s: True)
+        monkeypatch.setattr(engine_mod, "_upload", uploader)
+        monkeypatch.setattr(np, "empty", counting(np.empty))
+        monkeypatch.setattr(np, "zeros", counting(np.zeros))
+        tracemalloc.start()
+        try:
+            step, _ = engine.load(big)
+            peak = tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+        assert step == 1
+        assert handed == [
+            (2048 * 512 * 4, True, False), (2048 * 512 * 4, True, False),
+            (4, True, False),
+        ]
+        assert large_allocs == []
+        # 8 MB of state; imports and the event log stay far under a leaf.
+        assert peak < 2 << 20, f"restore allocated {peak} bytes on the host"
+
+    def test_cpu_uploads_own_their_memory(self, engine, monkeypatch):
+        """The crash recorded in ``shm_handler.load_state_dict`` stays
+        guarded: on the CPU backend, whose ``device_put`` may alias host
+        memory, the uploaded arrays are owned copies; they survive a
+        donating jit and a second save into the same block."""
+        from dlrover_tpu.checkpoint import engine as engine_mod
+
+        first = {k: v for k, v in _mixed_tree().items()
+                 if isinstance(v, jax.Array)}
+        second = jax.tree.map(lambda x: x + 1, first)
+        assert engine.save_to_memory(1, first, block=True)
+        real_upload, aliased = engine_mod._upload, []
+
+        def uploader(arrays, targets):
+            block = _block_bytes(engine)
+            aliased.extend(np.shares_memory(a, block) for a in arrays)
+            del block
+            return real_upload(arrays, targets)
+
+        monkeypatch.setattr(engine_mod, "_upload", uploader)
+        step, restored = engine.load(second)
+        assert step == 1 and aliased == [False] * 4
+        bump = jax.jit(
+            lambda t: jax.tree.map(lambda x: x + 1, t), donate_argnums=0
+        )
+        bumped = bump(restored)
+        assert engine.save_to_memory(2, second, block=True)  # same block
+        bumped = bump(bumped)
+        _assert_trees_bit_equal(
+            bumped, jax.tree.map(lambda x: x + 2, first)
+        )
+        step, again = engine.load(first)
+        assert step == 2
+        _assert_trees_bit_equal(again, second)
+
+    def test_lock_is_held_until_the_upload_has_landed(
+        self, engine, monkeypatch
+    ):
+        """Another process's handle on ``_shm_lock`` cannot take it while
+        the uploader runs, nor while the restore waits for the transfers:
+        the uploads read from views the next save would rewrite."""
+        from dlrover_tpu.checkpoint import engine as engine_mod
+
+        other = _second_lock(engine)
+        seen = []
+
+        def try_lock(when):
+            got = other.acquire(blocking=False)
+            seen.append((when, got))
+            if got:
+                other.release()
+
+        class InFlight:
+            def block_until_ready(self):
+                try_lock("landing")
+                return self
+
+        def uploader(arrays, targets):
+            try_lock("uploading")
+            return [InFlight() for _ in arrays]
+
+        tree = {"w": jnp.ones((8, 8)), "step": jnp.asarray(1, jnp.int32)}
+        assert engine.save_to_memory(1, tree, block=True)
+        monkeypatch.setattr(engine_mod, "_upload", uploader)
+        step, _ = engine.load(tree)
+        try_lock("returned")
+        other.close()
+        assert step == 1
+        assert seen == [
+            ("uploading", False), ("uploading", False),
+            ("landing", False), ("landing", False), ("returned", True),
+        ]
+
+
+def _bounds_sets(sharding, shape):
+    from dlrover_tpu.checkpoint.engine import _slices_to_bounds
+
+    return {
+        _slices_to_bounds(index, shape)
+        for index in sharding.devices_indices_map(shape).values()
+    }
+
+
+class TestDirectOrAssembled:
+    """Saved under fsdp=2 × tp=2 on the 8 virtual devices: the same mesh
+    takes every leaf's shards as saved; another mesh pastes the leaves
+    whose shard bounds changed.  The span and the counter say which."""
+
+    @pytest.mark.parametrize(
+        "restore_mesh", ["same", "other"], ids=["same-mesh", "other-mesh"]
+    )
+    def test_path_by_saved_bounds(
+        self, tmp_path, devices8, monkeypatch, restore_mesh
+    ):
+        from dlrover_tpu.checkpoint import Checkpointer, StorageType, integrity
+        from dlrover_tpu.parallel.mesh import MeshConfig
+        from dlrover_tpu.telemetry import events as tevents
+
+        tdir = str(tmp_path / "telemetry")
+        monkeypatch.setenv(tevents.ENV_TELEMETRY_DIR, tdir)
+        tevents.reset()
+        saved_cfg = MeshConfig(dp=2, fsdp=2, tp=2)
+        state, shardings, _ = _make_state(saved_cfg, devices8)
+        ckpt = Checkpointer(str(tmp_path / "ckpt"), start_saver=True)
+        try:
+            assert ckpt.save_checkpoint(
+                4, state, StorageType.MEMORY, block=True
+            )
+            target, want = _make_state(
+                saved_cfg if restore_mesh == "same"
+                else MeshConfig(dp=2, fsdp=4, tp=1),
+                devices8, seed=1,
+            )[:2]
+            counter = integrity._metric("dlrover_ckpt_restore_leaves_total")
+            before = {
+                p: counter.value(path=p) for p in ("direct", "assembled")
+            }
+            step, restored = ckpt.load_checkpoint(target, want)
+        finally:
+            ckpt.close()
+            tevents.reset()
+        assert step == 4
+        saved_sh = jax.tree_util.tree_leaves(shardings)
+        want_sh = jax.tree_util.tree_leaves(want)
+        leaves = jax.tree_util.tree_leaves(state)
+        got = jax.tree_util.tree_leaves(restored)
+        direct = 0
+        for leaf, new, old_s, new_s in zip(leaves, got, saved_sh, want_sh):
+            if not isinstance(leaf, jax.Array):
+                continue
+            assert new.sharding.is_equivalent_to(new_s, leaf.ndim)
+            np.testing.assert_array_equal(np.asarray(leaf), np.asarray(new))
+            direct += _bounds_sets(new_s, leaf.shape) <= _bounds_sets(
+                old_s, leaf.shape
+            )
+        arrays = sum(isinstance(x, jax.Array) for x in leaves)
+        if restore_mesh == "same":
+            assert direct == arrays
+        else:
+            assert 0 < direct < arrays  # scalars and norms keep their bounds
+        ends = [
+            e for e in tevents.read_dir(tdir) if e["ev"] == "restore_end"
+        ]
+        assert len(ends) == 1
+        assert ends[0]["source"] == "shm" and ends[0]["step"] == 4
+        assert ends[0]["direct_leaves"] == direct
+        assert ends[0]["assembled_leaves"] == arrays - direct
+        assert ends[0]["bytes"] == sum(
+            x.nbytes for x in leaves if isinstance(x, jax.Array)
+        )
+        assert counter.value(path="direct") - before["direct"] == direct
+        assert (
+            counter.value(path="assembled") - before["assembled"]
+            == arrays - direct
+        )

@@ -535,6 +535,106 @@ class TestShmCrcVerification:
             h.close(unlink=True)
 
 
+class TestShmRestoreRefusesCorruption:
+    """The streaming restore (ISSUE 25) keeps the trust invariant: every
+    tensor is verified before ITS bytes reach the uploader, one mismatch
+    anywhere refuses the whole block, and the ladder goes on to storage."""
+
+    TENSORS = ["a", "b", "c", "d", "e", "step"]  # staged in this order
+
+    @staticmethod
+    def _state(v):
+        tree = {
+            k: jnp.full((32, 16), float(v) + i, jnp.float32)
+            for i, k in enumerate("abcde")
+        }
+        tree.update(step=jnp.asarray(v, jnp.int32), tag=f"v{v}")
+        return tree
+
+    @staticmethod
+    def _scribble(handler, where, index):
+        """Flip one byte of tensor ``index``, or of the object blob."""
+        meta = handler.load_meta()
+        buf = handler.shared_memory.buf
+        base = handler._tensor_base()
+        if where == "objects":
+            at = bytes(buf[:base]).index(meta.objects) + len(meta.objects) // 2
+        else:
+            t = meta.tensors[index]
+            at = base + t.offset + t.nbytes // 2
+        buf[at] = buf[at] ^ 0xFF
+
+    @pytest.mark.parametrize(
+        "where,index",
+        [("first", 0), ("middle", 2), ("last", 5), ("objects", None)],
+    )
+    def test_corrupt_block_is_refused_whole(
+        self, tmp_path, monkeypatch, where, index
+    ):
+        from dlrover_tpu.checkpoint import Checkpointer, StorageType
+        from dlrover_tpu.checkpoint import engine as engine_mod
+        from dlrover_tpu.common import faults
+        from dlrover_tpu.telemetry import events as tevents
+
+        tdir = str(tmp_path / "telemetry")
+        monkeypatch.setenv(tevents.ENV_TELEMETRY_DIR, tdir)
+        tevents.reset()
+        real_upload, uploads = engine_mod._upload, []
+
+        def uploader(arrays, targets):
+            uploads.extend(np.array(a) for a in arrays)
+            return real_upload(arrays, targets)
+
+        monkeypatch.setattr(engine_mod, "_upload", uploader)
+        ckpt = Checkpointer(str(tmp_path / "ckpt"), start_saver=True)
+        try:
+            assert ckpt.save_checkpoint(1, self._state(1), StorageType.DISK)
+            assert ckpt.wait(timeout=90)
+            if where == "first":
+                # The fault flips a byte of the first tensor as it lands.
+                faults.install("ckpt_shm_corrupt:*:noop")
+            assert ckpt.save_checkpoint(
+                2, self._state(2), StorageType.MEMORY, block=True
+            )
+            engine = ckpt._engine
+            if where != "first":
+                self._scribble(engine._shm_handler, where, index)
+            meta = engine._shm_handler.load_meta()
+            assert meta.step == 2
+            assert [t.path[0].strip("[]'") for t in meta.tensors] == (
+                self.TENSORS
+            )
+            assert engine._restore_from_memory(
+                self._state(0), None, None
+            ) is None
+            # Only the tensors BEFORE the corrupt one were uploaded (the
+            # leaves in flight were dropped), and each of those verified.
+            assert len(uploads) == (index or 0)
+            for i, arr in enumerate(uploads):
+                want = 2 if self.TENSORS[i] == "step" else 2.0 + i
+                assert (arr == want).all()
+            step, state = ckpt.load_checkpoint(self._state(0))
+        finally:
+            faults.reset()
+            ckpt.close()
+            tevents.reset()
+        # Nothing of step 2 came back: the load made the same refused
+        # attempt, then restored step 1 from storage (host_tree_to_state).
+        assert step == 1 and len(uploads) == 2 * (index or 0)
+        assert state["tag"] == "v1" and int(state["step"]) == 1
+        for i, k in enumerate("abcde"):
+            assert (np.asarray(state[k]) == 1.0 + i).all()
+        assert engine.last_restore["source"] == "storage"
+        assert engine.last_restore["direct_leaves"] == 0
+        assert engine.last_restore["assembled_leaves"] == 6
+        verdicts = [
+            e for e in tevents.read_dir(tdir)
+            if e["ev"] == "verdict" and e.get("action") == "ckpt_shm_corrupt"
+        ]
+        # One verdict per refused attempt (the direct call and the load).
+        assert [v["step"] for v in verdicts] == [2, 2]
+
+
 # -- kv delta chain link verification -----------------------------------------
 
 
