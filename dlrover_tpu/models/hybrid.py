@@ -1,0 +1,349 @@
+"""Hybrid decoder: Mamba-2 state-space layers beside attention layers,
+laid out by a layer pattern.
+
+``HybridConfig.layer_types`` names each layer's mixer (``"mamba"`` or
+``"attention"``); the layers are unrolled, each its own parameters.  The
+equations are Granite-4.0-H's (``model_type: granitemoehybrid``, dense):
+
+* ``h = embedding_multiplier * E[ids]``; a layer is
+  ``h += residual_multiplier * mixer(RMSNorm(h))`` then
+  ``h += residual_multiplier * MLP(RMSNorm(h))`` (the gated MLP of
+  ``models/llama.py``); ``logits = RMSNorm(h) E^T / logits_scaling``: the
+  head is the embedding, tied, and the logits come out in ``dtype`` (the
+  loss upcasts).
+* attention mixer: grouped-query, no bias, **no rotary or other position
+  term**, scores scaled by ``attention_multiplier`` (not ``1/sqrt(d)``).
+* Mamba-2 mixer: ``z, x, B, C, dt`` projected from the hidden state, no
+  bias; a depthwise causal convolution with bias and SiLU over ``x, B, C``; the scan of
+  ``ops/ssd.py`` with ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``;
+  ``+ D * x``; ``RMSNorm(y * silu(z))`` over the whole inner width (gate
+  before the norm); output projection.  One group: ``B`` and ``C`` are
+  shared by all heads.
+
+The five input projections are separate parameters (``z_proj``, ``x_proj``,
+``b_proj``, ``c_proj``, ``dt_proj``: the published ``in_proj`` cut at its
+own boundaries), as are the convolution's three channel ranges, so that a
+rule table can shard the inner width and the heads over ``tp`` and leave
+``B`` and ``C`` whole.  Compute is ``dtype`` (bf16), parameters float32,
+the decay and the states float32 (``ops/ssd.py``).
+
+``segment_ids`` raises in a model with mamba layers: the scan's state is
+not reset at packed document boundaries, so a packed row would leak one
+document's state into the next.
+"""
+
+import dataclasses
+import math
+from collections import Counter
+from functools import partial
+from typing import Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from dlrover_tpu.models.llama import (
+    MLP,
+    Dtype,
+    RMSNorm,
+    _masked_attention,
+    param_with_axes,
+    remat_policy,
+    with_constraint,
+)
+from dlrover_tpu.ops import ssd
+from dlrover_tpu.ops.splash_attention import splash_attention_gqa
+
+LAYER_KINDS = ("mamba", "attention")
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    vocab_size: int = 100352
+    hidden_size: int = 2048
+    intermediate_size: int = 8192
+    layer_types: Tuple[str, ...] = ("mamba",) * 5 + ("attention",) + (
+        "mamba",) * 4
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    rms_norm_eps: float = 1e-5
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None  # None: 1 / sqrt(head_dim)
+    logits_scaling: float = 1.0
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
+    conv_width: int = 4
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = jnp.float32
+    attention_impl: str = "dot"  # dot | splash
+    remat_policy: str = "none"  # models/llama.py::remat_policy
+
+    def __post_init__(self):
+        # A JSON list arrives through a configuration file; a flax module
+        # attribute has to hash.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        unknown = set(self.layer_types) - set(LAYER_KINDS)
+        if unknown:
+            raise ValueError(
+                f"layer_types holds {sorted(unknown)}; known: {LAYER_KINDS}")
+        if self.attention_impl not in ("dot", "splash"):
+            raise ValueError(
+                "HybridModel's attention takes its scale from the "
+                "configuration, which only 'dot' and 'splash' accept; got "
+                f"attention_impl={self.attention_impl!r}")
+        if self.ssm_groups != 1:
+            raise ValueError(
+                f"ssm_groups={self.ssm_groups}: only one group of B and C "
+                "shared by all heads is built")
+
+    @property
+    def resolved_head_dim(self) -> int:
+        return self.hidden_size // self.num_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @classmethod
+    def tiny(cls, **kw) -> "HybridConfig":
+        """Test-scale config with both kinds of layer."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            layer_types=("mamba", "attention", "mamba"), num_heads=4,
+            num_kv_heads=2, ssm_heads=4, ssm_head_dim=16, ssm_state=16,
+            ssm_chunk=8, embedding_multiplier=12.0, residual_multiplier=0.22,
+            attention_multiplier=1 / 16, logits_scaling=8.0,
+        )
+        base.update(kw)
+        return cls(**base)
+
+
+def _dt_bias_init(key, shape, dtype):
+    """softplus^-1 of a step drawn log-uniformly from [1e-3, 1e-1]."""
+    lo, hi = math.log(1e-3), math.log(1e-1)
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _conv_init(width):
+    """U(-1/sqrt(width), 1/sqrt(width)) for a depthwise convolution's taps
+    and bias (fan-in = its width)."""
+    bound = 1.0 / math.sqrt(width)
+
+    def init(key, shape, dtype):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+    return init
+
+
+def _a_log_init(key, shape, dtype):
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)
+                   ).astype(dtype)
+
+
+class MambaMixer(nn.Module):
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, h):
+        cfg = self.cfg
+        heads, inner = cfg.ssm_heads, cfg.ssm_inner
+
+        def project(name, features, axis):
+            return nn.DenseGeneral(
+                features=features, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, use_bias=False,
+                kernel_init=param_with_axes(
+                    nn.initializers.lecun_normal(), ("embed", axis)),
+                name=name,
+            )(h)
+
+        def conv(name, value, axis):
+            width, ch = cfg.conv_width, value.shape[-1]
+            weight = self.param(
+                f"conv_{name}",
+                param_with_axes(_conv_init(width), ("conv_width", axis)),
+                (width, ch), cfg.param_dtype,
+            )
+            bias = self.param(
+                f"conv_{name}_bias",
+                param_with_axes(_conv_init(width), (axis,)),
+                (ch,), cfg.param_dtype,
+            )
+            return nn.silu(ssd.causal_conv1d(value, weight, bias))
+
+        def per_head(name, init):
+            return self.param(
+                name, param_with_axes(init, ("ssm_heads",)), (heads,),
+                cfg.param_dtype,
+            ).astype(jnp.float32)
+
+        with jax.named_scope("mamba/in_proj"):
+            z = project("z_proj", inner, "ssm_inner")
+            x = project("x_proj", inner, "ssm_inner")
+            B = project("b_proj", cfg.ssm_state, "ssm_state")
+            C = project("c_proj", cfg.ssm_state, "ssm_state")
+            dt = project("dt_proj", heads, "ssm_heads")
+        z = with_constraint(z, ("batch", "seq", "act_ssm_inner"))
+        x = with_constraint(x, ("batch", "seq", "act_ssm_inner"))
+        with jax.named_scope("mamba/conv"):
+            x = conv("x", x, "ssm_inner")
+            B = conv("b", B, "ssm_state")
+            C = conv("c", C, "ssm_state")
+        dt = jax.nn.softplus(
+            dt.astype(jnp.float32) + per_head("dt_bias", _dt_bias_init))
+        A = -jnp.exp(per_head("A_log", _a_log_init))
+        D = per_head("D", nn.initializers.ones_init())
+        x = x.reshape(*x.shape[:2], heads, cfg.ssm_head_dim)
+        y = ssd.ssd_chunked(x, dt, A, B, C, cfg.ssm_chunk)
+        with jax.named_scope("mamba/gated_norm"):
+            y = y.astype(jnp.float32) + D[:, None] * x.astype(jnp.float32)
+            y = y.reshape(*y.shape[:2], inner) * nn.silu(
+                z.astype(jnp.float32))
+            scale = self.param(
+                "norm",
+                param_with_axes(nn.initializers.ones_init(), ("ssm_inner",)),
+                (inner,), cfg.param_dtype,
+            )
+            y = y * jax.lax.rsqrt(
+                jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_norm_eps)
+            y = (y * scale.astype(jnp.float32)).astype(cfg.dtype)
+        y = with_constraint(y, ("batch", "seq", "act_ssm_inner"))
+        with jax.named_scope("mamba/out_proj"):
+            out = nn.DenseGeneral(
+                features=cfg.hidden_size, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, use_bias=False,
+                kernel_init=param_with_axes(
+                    nn.initializers.lecun_normal(), ("ssm_inner", "embed")),
+                name="out_proj",
+            )(y)
+        return with_constraint(out, ("batch", "seq", "act_embed"))
+
+
+class HybridAttention(nn.Module):
+    """Causal grouped-query attention with no position term."""
+
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, h, segment_ids=None):
+        cfg = self.cfg
+        d = cfg.resolved_head_dim
+        scale = cfg.attention_multiplier
+        if scale is None:
+            scale = 1.0 / math.sqrt(d)
+
+        def project(name, n_heads, axis):
+            return nn.DenseGeneral(
+                features=(n_heads, d), axis=-1, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, use_bias=False,
+                kernel_init=param_with_axes(
+                    nn.initializers.lecun_normal(),
+                    ("embed", axis, "head_dim")),
+                name=name,
+            )(h)
+
+        q = project("q_proj", cfg.num_heads, "heads")
+        k = project("k_proj", cfg.num_kv_heads, "kv_heads")
+        v = project("v_proj", cfg.num_kv_heads, "kv_heads")
+        q = with_constraint(q, ("batch", "seq", "act_heads", "act_head_dim"))
+        k = with_constraint(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        v = with_constraint(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        if cfg.attention_impl == "splash":
+            out = splash_attention_gqa(
+                q, k, v, segment_ids=segment_ids, scale=scale)
+        else:
+            s = q.shape[1]
+            mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
+            if segment_ids is not None:
+                mask = mask & (
+                    segment_ids[:, None, :, None]
+                    == segment_ids[:, None, None, :])
+            out = _masked_attention(q, k, v, mask, scale=scale)
+        out = with_constraint(
+            out, ("batch", "seq", "act_heads", "act_head_dim"))
+        out = nn.DenseGeneral(
+            features=cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, use_bias=False,
+            kernel_init=param_with_axes(
+                nn.initializers.lecun_normal(), ("heads", "head_dim", "embed")),
+            name="o_proj",
+        )(out)
+        return with_constraint(out, ("batch", "seq", "act_embed"))
+
+
+class HybridBlock(nn.Module):
+    cfg: HybridConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, segment_ids=None):
+        cfg = self.cfg
+        norm = partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype)
+        h = norm(name="input_norm")(x)
+        if self.kind == "mamba":
+            mixed = MambaMixer(cfg, name="mamba")(h)
+        else:
+            with jax.named_scope("hybrid/attention"):
+                mixed = HybridAttention(cfg, name="attention")(h, segment_ids)
+        x = x + cfg.residual_multiplier * mixed
+        h = norm(name="post_norm")(x)
+        with jax.named_scope("hybrid/mlp"):
+            x = x + cfg.residual_multiplier * MLP(cfg, name="mlp")(h)
+        return with_constraint(x, ("batch", "seq", "act_embed"))
+
+
+class HybridModel(nn.Module):
+    """Decoder-only LM over a layer pattern.  ``__call__`` returns logits
+    (b, s, vocab), with ``LlamaModel``'s signature (``positions`` is
+    accepted and unused: no layer has a position term)."""
+
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, input_ids, positions=None, segment_ids=None):
+        from dlrover_tpu.telemetry.spans import span
+
+        cfg = self.cfg
+        kinds = Counter(cfg.layer_types)
+        if segment_ids is not None and kinds["mamba"]:
+            raise ValueError(
+                "HybridModel: segment_ids (packed rows) are not supported "
+                "with mamba layers: the scan's state is not reset at "
+                "document boundaries (ops/ssd.py)")
+        # What each tracing of the model lowered, by layer kind, for the
+        # telemetry directory (one span a trace, not a step).
+        with span("lower", what="hybrid") as lowered:
+            lowered.update(
+                layer_types=dict(kinds), chunk=cfg.ssm_chunk,
+                n_chunks=input_ids.shape[1] // cfg.ssm_chunk,
+                attention_impl=cfg.attention_impl,
+                head_dim=cfg.resolved_head_dim,
+            )
+            embed = self.param(
+                "embed_tokens",
+                param_with_axes(
+                    nn.initializers.normal(stddev=0.02), ("vocab", "embed")),
+                (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype,
+            )
+            x = embed.astype(cfg.dtype)[input_ids] * jnp.asarray(
+                cfg.embedding_multiplier, cfg.dtype)
+            x = with_constraint(x, ("batch", "seq", "act_embed"))
+            block_cls = HybridBlock
+            if cfg.remat_policy != "none":
+                block_cls = nn.remat(
+                    HybridBlock, policy=remat_policy(cfg.remat_policy),
+                    prevent_cse=True,
+                )
+            for i, kind in enumerate(cfg.layer_types):
+                x = block_cls(cfg, kind, name=f"layers_{i}")(x, segment_ids)
+            with jax.named_scope("hybrid/head"):
+                x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
+                            name="final_norm")(x)
+                logits = jnp.einsum("bse,ve->bsv", x, embed.astype(cfg.dtype))
+                logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
+        return with_constraint(logits, ("batch", "seq", "act_vocab"))

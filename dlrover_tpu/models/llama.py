@@ -220,16 +220,21 @@ class RMSNorm(nn.Module):
         return (norm * scale.astype(jnp.float32)).astype(self.dtype)
 
 
-def _masked_attention(q, k, v, mask):
-    """Shared attention core (GQA head-repeat, 1/sqrt(d) scale, f32 masked
-    softmax): ONE numerically sensitive implementation for both the causal
-    training path and the KV-cache decode path."""
+def _masked_attention(q, k, v, mask, scale=None):
+    """Shared attention core (GQA head-repeat, 1/sqrt(d) scale unless the
+    caller gives one, f32 masked softmax): ONE numerically sensitive
+    implementation for both the causal training path and the KV-cache
+    decode path."""
     d = q.shape[-1]
     n_q, n_kv = q.shape[2], k.shape[2]
     if n_q != n_kv:
         k = jnp.repeat(k, n_q // n_kv, axis=2)
         v = jnp.repeat(v, n_q // n_kv, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(d).astype(q.dtype)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if scale is None:
+        scores = scores / jnp.sqrt(d).astype(q.dtype)
+    else:
+        scores = scores * jnp.asarray(scale, q.dtype)
     scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)

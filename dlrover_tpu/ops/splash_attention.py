@@ -141,6 +141,7 @@ def splash_attention_gqa(
     causal: bool = True,
     max_segment_len: Optional[int] = None,
     interpret: Optional[bool] = None,
+    scale: Optional[float] = None,
 ):
     """Drop-in for :func:`flash_attention_gqa` backed by the library kernel.
 
@@ -153,22 +154,29 @@ def splash_attention_gqa(
     ``LlamaConfig.flash_block_q/kv``.  ``interpret=True`` forces the library
     kernel in Pallas interpret mode (CPU correctness tests);
     ``interpret=False`` forces it compiled (compiling for a described chip).
+    ``scale`` multiplies the scores before the softmax; ``None`` is
+    ``1 / sqrt(head_dim)``.
     """
     from dlrover_tpu.ops.flash_attention import (
         flash_attention_gqa,
         shard_kernel_over_mesh,
     )
 
-    s_q, h = q.shape[1], q.shape[2]
+    s_q, h, d = q.shape[1:]
     s_kv, h_kv = k.shape[1], k.shape[2]
+    default_scale = 1.0 / math.sqrt(d)
 
     def in_tree(reason):
         _record_fallback(reason)
+        # The in-tree kernel scales by 1/sqrt(d) itself: fold the rest of
+        # a caller's scale into q.
+        q_ = q if scale is None else q * jnp.asarray(
+            scale / default_scale, q.dtype)
         # The in-tree kernel is tuned at <=512 blocks (its unfused bwd
         # has larger vmem footprints); cap here like the model's
         # attention_impl="flash" path does.
         return flash_attention_gqa(
-            q, k, v, segment_ids=segment_ids,
+            q_, k, v, segment_ids=segment_ids,
             block_q=min(block_q, 512), block_kv=min(block_kv, 512),
             causal=causal,
         )
@@ -189,6 +197,7 @@ def splash_attention_gqa(
     local = functools.partial(
         _splash_local, block_q=block_q, block_kv=block_kv, causal=causal,
         max_segment_len=max_segment_len, interpret=interpret,
+        scale=default_scale if scale is None else scale,
     )
     if interpret:  # plain HLO: GSPMD partitions it itself
         return local(q, k, v, segment_ids)
@@ -196,7 +205,7 @@ def splash_attention_gqa(
 
 
 def _splash_local(q, k, v, segment_ids, *, block_q, block_kv, causal,
-                  max_segment_len, interpret):
+                  max_segment_len, interpret, scale):
     """The library kernel on one device's (batch, heads) shard."""
     b, s_q, h, d = q.shape
     s_kv, h_kv = k.shape[1], k.shape[2]
@@ -207,7 +216,6 @@ def _splash_local(q, k, v, segment_ids, *, block_q, block_kv, causal,
         s_q, s_kv, h, block_q, block_kv, causal,
         max_segment_len=max_segment_len, interpret=interpret,
     )
-    scale = 1.0 / math.sqrt(d)
     q_t = (q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3)
     k_t = k.transpose(0, 2, 1, 3)
     v_t = v.transpose(0, 2, 1, 3)

@@ -17,6 +17,10 @@ Logical axes used by the model zoo:
     vocab   — vocabulary dim
     expert  — MoE expert dim
     layers  — stacked (scanned) layer dim
+    ssm_inner — state-space mixer's inner width (heads x head dim)
+    ssm_heads — state-space heads (dt, A, D)
+    ssm_state — state width (B, C: one group, shared by all heads)
+    conv_width— taps of the depthwise causal convolution
 """
 
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -53,6 +57,8 @@ _COMMON = (
     ("embed_out", None),
     # CLIP vision tower: flattened-patch input dim of the patch embedding.
     ("patch_dim", None),
+    # Taps of the state-space mixer's causal convolution (never sharded).
+    ("conv_width", None),
 )
 
 # Pure data parallel: params replicated, batch split on dp(+fsdp).
@@ -71,6 +77,10 @@ DP_RULES: Rules = (
     ("vocab", None),
     ("expert", None),
     ("layers", None),
+    ("act_ssm_inner", None),
+    ("ssm_inner", None),
+    ("ssm_heads", None),
+    ("ssm_state", None),
 ) + _ACT_REPLICATED + _COMMON
 
 # FSDP/ZeRO-3 analog: shard every weight's embed dim over fsdp; params are
@@ -91,6 +101,10 @@ FSDP_RULES: Rules = (
     ("vocab", None),
     ("expert", None),
     ("layers", None),
+    ("act_ssm_inner", None),
+    ("ssm_inner", None),
+    ("ssm_heads", None),
+    ("ssm_state", None),
 ) + _ACT_REPLICATED + _COMMON
 
 # Megatron-style TP composed with FSDP (+ optional sequence parallel):
@@ -112,6 +126,12 @@ FSDP_TP_RULES: Rules = (
     ("vocab", "tp"),
     ("expert", "ep"),
     ("layers", None),
+    # State-space mixer: heads (and the inner width, heads-major) over tp
+    # like attention's; B and C are one group all heads read, kept whole.
+    ("act_ssm_inner", "tp"),
+    ("ssm_inner", "tp"),
+    ("ssm_heads", "tp"),
+    ("ssm_state", None),
 ) + _ACT_REPLICATED + _COMMON
 
 PRESET_RULES: Dict[str, Rules] = {
