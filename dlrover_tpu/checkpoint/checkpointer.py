@@ -64,8 +64,9 @@ class Checkpointer:
         waits until shm actually holds this step."""
         from dlrover_tpu.telemetry.spans import span
 
-        # The span covers only the dispatch (ms); the async drain is
-        # traced agent-side by ckpt_saver's own save span.
+        # The span covers only the dispatch (ms); the pipeline into shm
+        # is the stager's ckpt_stage span, the persist the agent's own
+        # save span (ckpt_saver).
         with span("save", step=step, storage=storage_type) as extra:
             if storage_type == StorageType.MEMORY:
                 ok = self._engine.save_to_memory(step, state, block=block)

@@ -6,11 +6,13 @@ load = shm-first, storage fallback) + the FSDP flat-ckpt reshard-on-restore
 (``atorch/utils/fsdp_save_util.py``).
 
 TPU mapping: the "state dict" is any pytree of ``jax.Array``s (TrainState).
-``save_to_memory`` pulls this process's *addressable shards* to host
-(HBM→host over PCIe) and memcpys them into the agent's shm block with
-their global layout (shape + index).  Restore pastes shards from any saved
-mesh layout into arrays sharded for the *current* mesh — elastic restarts
-with a different world size reshard transparently.
+``save_to_memory`` snapshots the state on the device and hands its
+*addressable shards* to a stager thread, which runs the save as one
+pipeline: each shard is checksummed and copied into the agent's shm block,
+with its global layout (shape + index), while the next is still leaving
+the chip (HBM→host over PCIe).  Restore pastes shards from any saved mesh
+layout into arrays sharded for the *current* mesh — elastic restarts with a
+different world size reshard transparently.
 """
 
 import collections
@@ -40,6 +42,7 @@ from dlrover_tpu.checkpoint.shm_handler import (
     SharedMemoryHandler,
     ShmCorruptError,
     _ShardEntry,
+    largest_first,
 )
 from dlrover_tpu.checkpoint.storage import (
     CheckpointStorage,
@@ -59,18 +62,49 @@ def _slices_to_bounds(index, shape) -> Tuple[Tuple[int, int], ...]:
     return tuple(bounds)
 
 
-def begin_host_transfer(state) -> Callable[[], Dict[Tuple, Any]]:
-    """Start the HBM→host drain; return a thunk that completes it.
+# Transfers of one device started and not yet arrived.  Measured on a v5e
+# (PERF.md §6, PR 30): started all at once, the transfers share the link
+# and little has arrived whole until the end of the drain, so the host's
+# work is left for the tail (1.95 GB: tail 0.17 s against 0.05, save 0.95 s
+# against 0.85; 5.84 GB: 6.2 s against 3.4-4.5).  With two in flight the
+# shards arrive one by one and the link always has the next queued.
+_IN_FLIGHT = 2
 
-    Enqueues an async device→host copy for every replica-0 shard
-    (``copy_to_host_async`` — returns immediately; the DMA overlaps
-    whatever the trainer computes next).  The returned ``complete()``
-    blocks until the transfers land and builds the flat host tree
-    ``{(keystr, shard_idx): _ShardEntry | leaf}``.
-    """
+
+class _InTransit:
+    """One shard of a snapshot on its way to the host: it knows its shape
+    and dtype, and ``np.asarray`` of it returns the bytes once they have
+    arrived, having started the next transfer that waited for its device."""
+
+    def __init__(self, data, waiting: collections.deque):
+        self.shape, self.dtype = data.shape, data.dtype
+        self._data, self._waiting = data, waiting
+
+    def __array__(self, dtype=None, copy=None):
+        host = np.asarray(self._data)
+        _start_next(self._waiting)
+        return host
+
+
+def _start_next(waiting: collections.deque):
+    if waiting:
+        waiting.popleft().copy_to_host_async()
+
+
+def begin_host_transfer(state) -> Dict[Tuple, Any]:
+    """Start the HBM→host drain of a snapshot; return what the stager's
+    pipeline consumes: ``{(keystr, shard_idx): _ShardEntry | leaf}`` in the
+    order of the tree (their order in the block), every replica-0 shard an
+    entry whose ``data`` is :class:`_InTransit`.
+
+    ``copy_to_host_async`` (returns at once; the DMA runs beside whatever
+    the trainer computes next) is called here on the first ``_IN_FLIGHT``
+    shards of each device, in the order the pipeline takes them
+    (``shm_handler.largest_first``), and on the next of a device as one of
+    its shards arrives."""
     flat, _ = jax.tree_util.tree_flatten_with_path(state)
-    pending: List[Tuple[Tuple, Any, tuple, tuple]] = []
-    objects: Dict[Tuple, Any] = {}
+    tree: Dict[Tuple, Any] = {}
+    device_of: Dict[Tuple, Any] = {}
     for path, leaf in flat:
         key = jax.tree_util.keystr(path)
         if isinstance(leaf, jax.Array):
@@ -79,31 +113,21 @@ def begin_host_transfer(state) -> Callable[[], Dict[Tuple, Any]]:
                 if shard.replica_id != 0:
                     continue
                 bounds = _slices_to_bounds(shard.index, gshape)
-                data = shard.data
-                try:
-                    data.copy_to_host_async()
-                except AttributeError:  # non-PjRt array stand-ins
-                    pass
-                pending.append(((key, i), data, gshape, bounds))
+                tree[(key, i)] = _ShardEntry(shard.data, gshape, bounds)
+                device_of[(key, i)] = shard.device
         else:
-            objects[(key, -1)] = leaf
-
-    def complete() -> Dict[Tuple, Any]:
-        # ONE batched device_get — transfers were already started above,
-        # so this mostly just waits for the last DMA (measured 1.6x
-        # faster than per-shard np.asarray even without the async start).
-        host: Dict[Tuple, Any] = dict(objects)
-        fetched = jax.device_get([entry[1] for entry in pending])
-        for (key_i, _, gshape, bounds), data in zip(pending, fetched):
-            host[key_i] = _ShardEntry(np.asarray(data), gshape, bounds)
-        return host
-
-    return complete
-
-
-def state_to_host_tree(state) -> Dict[Tuple, Any]:
-    """Synchronous HBM→host drain (see :func:`begin_host_transfer`)."""
-    return begin_host_transfer(state)()
+            tree[(key, -1)] = leaf
+    waiting: Dict[Any, collections.deque] = {}
+    for key in largest_first({k: tree[k] for k in device_of}):
+        waiting.setdefault(device_of[key], collections.deque()).append(
+            tree[key].data
+        )
+    for key, device in device_of.items():
+        tree[key].data = _InTransit(tree[key].data, waiting[device])
+    for queue in waiting.values():
+        for _ in range(_IN_FLIGHT):
+            _start_next(queue)
+    return tree
 
 
 def load_storage_host_tree(
@@ -178,10 +202,10 @@ class _AsyncStager:
     disk save is never silently lost.
     """
 
-    def __init__(self, process_fn: Callable[[int, Callable, bool], bool]):
+    def __init__(self, process_fn: Callable[[int, Dict, bool], bool]):
         self._process = process_fn
         self._cond = threading.Condition()
-        self._pending: Optional[Tuple[int, Callable, bool]] = None
+        self._pending: Optional[Tuple[int, Dict, bool]] = None
         self._inflight: Optional[int] = None
         self._last_ok = True
         self._failed_sticky = False
@@ -202,7 +226,7 @@ class _AsyncStager:
             failed, self._failed_sticky = self._failed_sticky, False
             return failed
 
-    def submit(self, step: int, work: Callable, persist: bool):
+    def submit(self, step: int, tree: Dict, persist: bool):
         with self._cond:
             if self._stopped:
                 raise RuntimeError("checkpoint stager is stopped")
@@ -218,7 +242,7 @@ class _AsyncStager:
                     "(saves arriving faster than the drain)",
                     old_step, step,
                 )
-            self._pending = (step, work, persist)
+            self._pending = (step, tree, persist)
             self._cond.notify_all()
 
     def _run(self):
@@ -228,17 +252,20 @@ class _AsyncStager:
                     self._cond.wait()
                 if self._pending is None:
                     return
-                step, work, persist = self._pending
+                step, tree, persist = self._pending
                 self._pending = None
                 self._inflight = step
             ok = False
             try:
-                ok = bool(self._process(step, work, persist))
+                ok = bool(self._process(step, tree, persist))
             except Exception:  # noqa: BLE001 — staging must not die
                 logger.error(
                     "checkpoint staging failed at step %s", step,
                     exc_info=True,
                 )
+            # Staged, skipped or failed: this thread keeps nothing of the
+            # snapshot while it waits for the next submit.
+            del tree
             with self._cond:
                 self._inflight = None
                 self._last_ok = ok
@@ -397,8 +424,7 @@ def host_tree_to_state(
     flat, treedef, targets = _flatten_target(abstract_state, shardings)
     leaves = []
     # Batch ALL host→device uploads into one device_put call at the end:
-    # jax pipelines the transfers (the restore twin of the batched
-    # device_get on the save path — per-leaf puts each pay dispatch
+    # jax pipelines the transfers (per-leaf puts each pay dispatch
     # latency and serialize the DMA streams).
     puts: List[Tuple[int, np.ndarray, Any]] = []
     for i, (path, leaf) in enumerate(flat):
@@ -479,29 +505,48 @@ class CheckpointEngine:
         )
         self._last_queued_step: Optional[int] = None
         self.last_restore: Dict[str, Any] = {}
+        self.last_save: Dict[str, Any] = {}
         self._snapshot = _DeviceSnapshot()
         self._stager = _AsyncStager(self._stage_to_shm)
 
     # -- save -----------------------------------------------------------
-    def _stage_to_shm(self, step: int, work: Callable, persist: bool) -> bool:
-        """Stager-thread body: finish the HBM→host drain, memcpy into the
-        agent's shm block, and (for persists) queue the SAVE event once
-        every rank staged this step."""
-        t0 = time.time()
-        host = work()
-        t_drain = time.time()
-        acquired = self._shm_lock.acquire(timeout=60)
-        if not acquired:
-            logger.warning("shm lock busy; skipping save at step %s", step)
-            return False
-        try:
-            self._shm_handler.save_state_dict(step, host)
-        finally:
-            self._shm_lock.release()
+    def _stage_to_shm(self, step: int, tree: Dict, persist: bool) -> bool:
+        """Stager-thread body: one pipeline from the chip into the agent's
+        shm block (``shm_handler.save_state_dict``: each shard is
+        checksummed and copied while the next is still arriving), under
+        ``_shm_lock`` from the first byte written to the last, and (for
+        persists) the SAVE event once every rank staged this step.
+
+        ``last_save`` and the end of the ``ckpt_stage`` span say what it
+        took: ``bytes``, ``leaves``, ``drain_s`` (until the last shard had
+        left the chip; the log line's ``drain``), ``tail_s`` (from there
+        until shm holds the step; the log line's ``memcpy``) and
+        ``overlap_s``, the checksum + copy seconds that ran before the last
+        arrival: ``overlap_s / (overlap_s + tail_s)`` is near 1 where the
+        drain hid the host's work and 0 where the two ran one after the
+        other."""
+        from dlrover_tpu.telemetry.spans import span
+
+        with span("ckpt_stage", step=step) as extra:
+            t0 = time.monotonic()
+            if not self._shm_lock.acquire(timeout=60):
+                logger.warning(
+                    "shm lock busy; skipping save at step %s", step
+                )
+                extra["ok"] = False
+                return False
+            lock_wait = time.monotonic() - t0
+            try:
+                took = self._shm_handler.save_state_dict(step, tree)
+            finally:
+                self._shm_lock.release()
+            took["drain_s"] += lock_wait  # the transfers ran meanwhile
+            extra.update(took)
+        self.last_save = dict(took, step=step)
         logger.info(
             "step %s staged to shm (drain %.3fs, memcpy %.3fs, all "
             "off the training thread)",
-            step, t_drain - t0, time.time() - t_drain,
+            step, took["drain_s"], took["tail_s"],
         )
         if persist:
             if self._sync_fn is not None and not self._sync_fn(step):
@@ -522,14 +567,16 @@ class CheckpointEngine:
         blocking time is its GPU→pinned-shm memcpy
         (``ckpt_saver.py:517``); ours is an HBM→HBM copy dispatch.
 
-        HBM backpressure: at most ONE snapshot is ever alive.  A
-        memory-only save arriving while the previous drain is in flight
-        is skipped *without taking a snapshot* (shm would be overwritten
-        by the next save anyway).  A PERSIST save instead waits for the
-        stager to go idle — this bounds HBM and, critically, guarantees
-        every rank processes the identical sequence of persist steps, so
-        the cross-rank ``sync_fn`` barrier can never see mismatched
-        steps.
+        HBM backpressure: at most ONE snapshot is ever alive, and less
+        than one for most of its drain: the pipeline drops each shard's
+        device copy as it is staged, and the stager keeps nothing once a
+        save is staged, skipped or failed.  A memory-only save arriving
+        while the previous drain is in flight is skipped *without taking a
+        snapshot* (shm would be overwritten by the next save anyway).  A
+        PERSIST save instead waits for the stager to go idle — this bounds
+        HBM and, critically, guarantees every rank processes the identical
+        sequence of persist steps, so the cross-rank ``sync_fn`` barrier
+        can never see mismatched steps.
 
         Returns False when this save was skipped OR when a *previous*
         async staging failed (sticky — dispatch itself cannot know its
@@ -550,7 +597,7 @@ class CheckpointEngine:
             # Persist must not be dropped: block until the drain frees
             # (bounded by one drain time — the backpressure is the cost
             # of never losing a disk save).  A wedged drain (hung
-            # device_get / shm lock) must FAIL this save: snapshotting on
+            # transfer / shm lock) must FAIL this save: snapshotting on
             # top of it would break the at-most-one-snapshot HBM bound and
             # let ranks stage diverging persist-step sequences, wedging
             # the cross-rank sync barrier.
@@ -566,8 +613,8 @@ class CheckpointEngine:
                 return False
         t0 = time.time()
         snap = self._snapshot.take(state)
-        work = begin_host_transfer(snap)
-        self._stager.submit(step, work, persist)
+        self._stager.submit(step, begin_host_transfer(snap), persist)
+        del snap  # the stager's tree holds the only references now
         logger.info(
             "step %s save dispatched in %.1f ms (drain continues in "
             "background)", step, (time.time() - t0) * 1e3,

@@ -9,12 +9,17 @@ layout (shape/dtype/index), so a restore can paste shards back under a
 different mesh — the reference's FSDP flat-ckpt reshard
 (``atorch/utils/fsdp_save_util.py``) done the JAX way.
 
-Buffer layout: ``[8B meta_len][pickled meta][tensor bytes ...]``.  The meta
-is also mirrored in a SharedDict so the agent can inspect step/paths without
-touching the buffer while a write is in flight.
+Buffer layout: ``[8B meta_len][pickled meta, padded][tensor bytes ...]``.
+A save is one pipeline (:meth:`SharedMemoryHandler.save_state_dict`): each
+tensor is checksummed and copied into its place while the next is still
+arriving from the chip.  It zeroes the header first and writes it last, so
+a block is either whole or one that no reader opens.  The step is also
+mirrored in a SharedDict so the agent can inspect it without touching the
+buffer while a write is in flight.
 """
 
 import dataclasses
+import math
 import os
 import pickle
 import struct
@@ -36,9 +41,14 @@ from dlrover_tpu.common.multi_process import (
 )
 
 _HEADER = struct.Struct("<Q")
-# Threads that crc-check tensors ahead of the restore's uploads: zlib
-# releases the GIL, and four keep ahead of one chip's host link.
+# Threads that checksum tensors (and, in a save, copy them) beside the
+# transfers of a restore or a save: zlib.crc32 and np.copyto release the
+# GIL, and four keep ahead of one chip's host link.
 _VERIFY_WORKERS = 4
+# What every crc32 reads in the meta that is pickled to size the block,
+# before a byte has arrived: no crc32 pickles wider, so the real meta fits
+# the place reserved for it.
+_CRC_WIDEST = 0xFFFFFFFF
 
 
 @dataclasses.dataclass
@@ -85,11 +95,26 @@ def _leaf_entries(host_tree: Dict[Tuple, Any]):
 
 @dataclasses.dataclass
 class _ShardEntry:
-    """Host ndarray + its placement in the global array (None = replicated)."""
+    """Host ndarray + its placement in the global array (None = replicated).
+    On its way into a save ``data`` may still be in transit from the chip:
+    anything with a shape and a dtype whose ``np.asarray`` returns the bytes
+    once they have arrived."""
 
     data: np.ndarray
     global_shape: Optional[Tuple[int, ...]]
     index: Optional[Tuple[Tuple[int, Optional[int]], ...]]
+
+
+def _nbytes(data) -> int:
+    return math.prod(data.shape) * np.dtype(data.dtype).itemsize
+
+
+def largest_first(arrays: Dict[Tuple, _ShardEntry]) -> List[Tuple]:
+    """The order in which a save takes its tensors off the chip (their order
+    in the block stays the tree's): largest first, so that what remains
+    after the last arrival is a small tensor's checksum and copy.  Whoever
+    starts the transfers starts them in this order."""
+    return sorted(arrays, key=lambda path: -_nbytes(arrays[path].data))
 
 
 def _crc_matches(t: TensorMeta, data: np.ndarray) -> bool:
@@ -152,71 +177,134 @@ class SharedMemoryHandler:
         return handler
 
     # -- write path (trainer) -------------------------------------------
-    def save_state_dict(self, step: int, host_tree: Dict[Tuple, Any]):
-        """Copy a {path: ndarray | _ShardEntry | obj} dict into shm."""
-        arrays, objects = _leaf_entries(host_tree)
+    def save_state_dict(
+        self, step: int, tree: Dict[Tuple, Any]
+    ) -> Dict[str, Any]:
+        """Stage a ``{path: _ShardEntry | ndarray | obj}`` dict into shm as
+        one pipeline; returns what it took: ``bytes``, ``leaves``,
+        ``drain_s`` (until the last tensor had arrived), ``tail_s`` (from
+        there until shm holds the step) and ``overlap_s`` (checksum + copy
+        seconds that ran before the last arrival).
+
+        Shapes and dtypes place every tensor, in the order of ``tree``,
+        before a byte has arrived.  Then, :func:`largest_first`: wait for
+        one tensor (``np.asarray``; a host array has arrived already), hand
+        it to a small pool that takes its crc32 from the host copy and
+        copies it into place, and wait for the next meanwhile.  ``tree`` is
+        CONSUMED: it is emptied at once and each entry dropped as it is
+        staged, and with it the last reference to a device copy.
+
+        The caller holds the shm lock from the first byte to the last.  The
+        header is zeroed first and written last, after the meta with every
+        crc32, and ``meta_dict``'s ``step`` after that: a save cut short
+        anywhere (a kill, a lost transfer, the ``ckpt_stage_cut`` fault
+        point) leaves a block that every reader REFUSES (``load_meta`` is
+        None: the restore goes to storage), never the previous step and
+        never a mixture of two."""
+        t_start = time.monotonic()
+        arrays, objects = _leaf_entries(tree)
+        tree.clear()
         obj_blob = pickle.dumps(objects, protocol=pickle.HIGHEST_PROTOCOL)
-        metas: List[TensorMeta] = []
-        host_arrays: List[np.ndarray] = []
+        metas: Dict[Tuple, TensorMeta] = {}
         offset = 0
         for path, entry in arrays.items():
-            arr = np.ascontiguousarray(entry.data)
-            host_arrays.append(arr)
-            metas.append(
-                TensorMeta(
-                    path=path,
-                    shape=tuple(arr.shape),
-                    dtype=str(arr.dtype),
-                    offset=offset,
-                    nbytes=arr.nbytes,
-                    global_shape=entry.global_shape,
-                    index=entry.index,
-                    # Digest rides with the meta so the agent's persist
-                    # and the flash-restore both verify the shm bytes
-                    # they read are the bytes the trainer staged.
-                    crc32=zlib.crc32(arr.reshape(-1).view(np.uint8)),
-                )
+            metas[path] = TensorMeta(
+                path=path,
+                # A 0-d array is staged 1-d, as both readers expect it.
+                shape=tuple(entry.data.shape) or (1,),
+                dtype=str(np.dtype(entry.data.dtype)),
+                offset=offset,
+                nbytes=_nbytes(entry.data),
+                global_shape=entry.global_shape,
+                index=entry.index,
+                # Digest rides with the meta so the agent's persist
+                # and the flash-restore both verify the shm bytes
+                # they read are the bytes the trainer staged.
+                crc32=_CRC_WIDEST,
             )
-            offset += arr.nbytes
+            offset += metas[path].nbytes
         meta = ShmMeta(
             step=step,
-            tensors=metas,
+            tensors=list(metas.values()),
             objects=obj_blob,
             total_bytes=offset,
             created=time.time(),
             objects_crc32=zlib.crc32(obj_blob),
         )
-        meta_blob = pickle.dumps(meta, protocol=pickle.HIGHEST_PROTOCOL)
-        need = _HEADER.size + len(meta_blob) + offset
+        # The pickled meta's length varies with the crc32s' values, known
+        # only at the end: it gets the room of its widest form and is padded
+        # to it (pickle stops at its STOP opcode), so the tensors' base is
+        # settled now and readers find it as ever (header + meta_len).
+        reserved = len(pickle.dumps(meta, protocol=pickle.HIGHEST_PROTOCOL))
+        base = _HEADER.size + reserved
+        need = base + offset
         self._ensure_size(need)
         buf = self.shared_memory.buf
-        buf[: _HEADER.size] = _HEADER.pack(len(meta_blob))
-        buf[_HEADER.size : _HEADER.size + len(meta_blob)] = meta_blob
-        base = _HEADER.size + len(meta_blob)
-        for arr, tmeta in zip(host_arrays, metas):
-            if tmeta.nbytes == 0:
-                continue
-            # Hot memcpy: copy straight into the shm mapping — no tobytes()
-            # intermediate, so peak host memory stays one copy.
-            dst = np.frombuffer(
-                buf, dtype=np.uint8, count=tmeta.nbytes,
-                offset=base + tmeta.offset,
-            )
-            np.copyto(dst, arr.reshape(-1).view(np.uint8))
+        buf[: _HEADER.size] = _HEADER.pack(0)
+
+        def stage(arr: np.ndarray, tmeta: TensorMeta) -> Tuple[float, float]:
+            began = time.monotonic()
+            src = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+            tmeta.crc32 = zlib.crc32(src)
+            if tmeta.nbytes:
+                # Straight into the shm mapping: no tobytes() intermediate.
+                np.copyto(
+                    np.frombuffer(
+                        buf, dtype=np.uint8, count=tmeta.nbytes,
+                        offset=base + tmeta.offset,
+                    ),
+                    src,
+                )
+            return began, time.monotonic()
+
+        pool = ThreadPoolExecutor(
+            _VERIFY_WORKERS, thread_name_prefix="ckpt-stage"
+        )
+        try:
+            staging = []
+            for k, path in enumerate(largest_first(arrays)):
+                entry = arrays.pop(path)
+                arr = np.asarray(entry.data)  # returns on arrival
+                staging.append(pool.submit(stage, arr, metas[path]))
+                del entry, arr
+                fault_point(
+                    "ckpt_stage_cut", step=step, shard=self._shard_id, leaf=k
+                )
+            arrived = time.monotonic()
+            spans = [done.result() for done in staging]
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
         if offset and fault_point(
             "ckpt_shm_corrupt", step=step, shard=self._shard_id
         ):
             # Simulated shm scribble (stray write / DMA corruption): flip
             # one byte in the first tensor so its crc32 no longer matches.
             buf[base] = buf[base] ^ 0xFF
+        meta_blob = pickle.dumps(meta, protocol=pickle.HIGHEST_PROTOCOL)
+        if len(meta_blob) > reserved:
+            raise RuntimeError(
+                f"shm meta outgrew its place ({len(meta_blob)} > "
+                f"{reserved} bytes)"
+            )
+        buf[_HEADER.size : base] = meta_blob.ljust(reserved, b"\0")
+        buf[: _HEADER.size] = _HEADER.pack(reserved)
         self.meta_dict.update(
             {
                 "step": step,
                 "total_bytes": need,
                 "shm_gen": self._attached_gen,
-                "dirty": False,
             }
         )
+        return {
+            "bytes": offset,
+            "leaves": len({path[0] for path in metas}),
+            "drain_s": arrived - t_start,
+            "tail_s": time.monotonic() - arrived,
+            "overlap_s": sum(
+                max(0.0, min(ended, arrived) - began)
+                for began, ended in spans
+            ),
+        }
 
     def _ensure_size(self, need: int):
         if self._attached_gen < 0:

@@ -395,16 +395,18 @@ class SharedMemory(shared_memory.SharedMemory):
             resource_tracker.unregister(self._name, "shared_memory")
         except Exception:  # noqa: BLE001 — tracker may not know the block
             pass
-        if not create:
-            self._populate()
+        self._populate()
 
     def _populate(self):
-        """Map an existing block with its pages already in this process's
-        page table (``MAP_POPULATE``).  Whoever attaches is about to read
-        or rewrite the whole block, and on the chip's host a page fault
-        costs 9 µs: the crc pass over a lazily mapped 5.84 GB checkpoint
-        took 13.8 s on one thread and 5.8 s on four, over a populated one
-        1.8 s and 0.57 s, and populating took 1 ms (PERF.md, PR 25)."""
+        """Map the block with its pages already in this process's page
+        table (``MAP_POPULATE``).  Whoever attaches is about to read or
+        rewrite the whole block, and on the chip's host a page fault costs
+        9 µs: the crc pass over a lazily mapped 5.84 GB checkpoint took
+        13.8 s on one thread and 5.8 s on four, over a populated one 1.8 s
+        and 0.57 s, and populating took 1 ms (PERF.md, PR 25).  Whoever
+        creates one is about to fill it from the save's threads, and a
+        block first touched by several threads at once is one the next
+        process is slow to attach (PERF.md §6, PR 30)."""
         try:
             populated = mmap.mmap(
                 self._fd, self._size,

@@ -964,6 +964,37 @@ class TestCheckpointCorruption:
         finally:
             ckpt.close()
 
+    def test_save_cut_short_restore_falls_through_to_storage(
+        self, tmp_path, isolated_ipc
+    ):
+        from dlrover_tpu.checkpoint import Checkpointer, StorageType
+
+        root = str(tmp_path / "ckpt")
+        ckpt = Checkpointer(root, start_saver=True)
+        try:
+            assert ckpt.save_checkpoint(
+                1, self._state(1), StorageType.DISK
+            )
+            assert ckpt.wait(timeout=90)
+            # The NEXT (memory-only) save dies in the middle of its
+            # pipeline, after its first leaf was handed to the block.
+            faults.install("ckpt_stage_cut:*:raise@1")
+            assert not ckpt.save_checkpoint(
+                2, self._state(2), StorageType.MEMORY, block=True
+            )
+            assert any(
+                r["point"] == "ckpt_stage_cut" for r in faults.fired()
+            )
+            faults.reset()
+            step, state = ckpt.load_checkpoint(self._state(0))
+            # The block holds part of step 2 and no header: no reader
+            # opens it, and the ladder restores disk step 1 — neither
+            # step 2's bytes under step 1's meta nor the other way round.
+            assert step == 1
+            assert float(state["w"][1]) == 1.0
+        finally:
+            ckpt.close()
+
 
 # -- scenario: bit rot + SIGKILL → reform from the agreed verified step -------
 

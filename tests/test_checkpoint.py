@@ -90,7 +90,9 @@ class TestIpcPrimitives:
     def test_attaching_maps_the_pages_at_once(self):
         """A process that attaches to an existing block gets it with the
         pages already in its page table (one MAP_POPULATE, no fault a
-        page as the restore walks it), and still shares the bytes."""
+        page as the restore walks it), and still shares the bytes.  So
+        does the process that creates one, before the save's threads fill
+        it."""
         size = 8 << 20
         name = f"test_shm_populated_{os.getpid()}"
 
@@ -107,7 +109,9 @@ class TestIpcPrimitives:
 
         shm = mp.create_shared_memory(name, create=True, size=size)
         try:
-            shm.buf[:size] = b"\x01" * size  # the pages exist
+            assert resident_kb(shm) >= size // 1024  # nothing written yet
+            assert bytes(shm.buf[:4]) == bytes(4)
+            shm.buf[:size] = b"\x01" * size
             other = mp.create_shared_memory(name, create=False)
             assert resident_kb(other) >= size // 1024  # nothing touched yet
             assert bytes(other.buf[-4:]) == b"\x01" * 4
@@ -128,20 +132,17 @@ class TestShmHandler:
 
         master = SharedMemoryHandler.create_master(shard_id=7)
         writer = SharedMemoryHandler(shard_id=7)
+        w = np.arange(12, dtype=np.float32).reshape(3, 4)
         tree = {
-            ("w", 0): _ShardEntry(
-                np.arange(12, dtype=np.float32).reshape(3, 4),
-                (6, 4),
-                ((0, 3), (0, 4)),
-            ),
+            ("w", 0): _ShardEntry(w, (6, 4), ((0, 3), (0, 4))),
             ("step", -1): 42,
         }
-        writer.save_state_dict(5, tree)
+        took = writer.save_state_dict(5, tree)
+        assert tree == {}  # consumed: the save keeps no leaf alive
+        assert took["bytes"] == w.nbytes and took["leaves"] == 1
         step, loaded = master.load_state_dict()
         assert step == 5
-        np.testing.assert_array_equal(
-            loaded[("w", 0)].data, tree[("w", 0)].data
-        )
+        np.testing.assert_array_equal(loaded[("w", 0)].data, w)
         assert loaded[("w", 0)].index == ((0, 3), (0, 4))
         assert loaded[("step", -1)] == 42
         writer.close()
@@ -600,6 +601,347 @@ class TestRestoreInPlace:
             ("uploading", False), ("uploading", False),
             ("landing", False), ("landing", False), ("returned", True),
         ]
+
+
+# -- the save is one pipeline (ISSUE 30) ---------------------------------------
+
+
+class _Gated:
+    """Stand-in for a shard in transit whose arrival the test gates:
+    ``np.asarray`` of it calls ``gate`` first, then lets the bytes land."""
+
+    def __init__(self, data, gate):
+        self.shape, self.dtype = data.shape, data.dtype
+        self._data, self._gate = data, gate
+
+    def __array__(self, dtype=None, copy=None):
+        self._gate()
+        return np.asarray(self._data)
+
+
+def _gate_transfers(monkeypatch, gate_of):
+    """Every shard the engine hands its stager from now on arrives through
+    ``gate_of(i)()``, ``i`` its place in the tree."""
+    from dlrover_tpu.checkpoint import engine as engine_mod
+
+    real_begin = engine_mod.begin_host_transfer
+
+    def begin(snap):
+        tree = real_begin(snap)
+        entries = [v for v in tree.values() if hasattr(v, "global_shape")]
+        for i, entry in enumerate(entries):
+            entry.data = _Gated(entry.data, gate_of(i))
+        return tree
+
+    monkeypatch.setattr(engine_mod, "begin_host_transfer", begin)
+
+
+def _random_leaves(sizes):
+    rng = np.random.default_rng(0)
+    return {
+        f"leaf{i}": jnp.asarray(
+            rng.integers(0, 255, size=n, dtype=np.uint8).view(np.float32)
+        )
+        for i, n in enumerate(sizes)
+    }
+
+
+def _saved_case(case, devices8):
+    """``(saved, target, shardings)``: a mixed tree on one device (f32,
+    bf16, 0-d, a zero-size array, two non-array leaves), or a train state
+    with several shards a leaf on the 8 virtual devices."""
+    if case == "mixed":
+        return _mixed_tree(), _mixed_tree(scale=3.0), None
+    from dlrover_tpu.parallel.mesh import MeshConfig
+
+    cfg = MeshConfig(dp=2, fsdp=2, tp=2)
+    saved, shardings, _ = _make_state(cfg, devices8)
+    return saved, _make_state(cfg, devices8, seed=1)[0], shardings
+
+
+# The benchmark reads the engine's two log lines with these
+# (benchmarks/workers/train_worker.py::_StagedLines; tier-1 does not import
+# the benchmark's files).
+_STAGED_RE = r"step (\d+) staged to shm \(drain ([0-9.]+)s, memcpy ([0-9.]+)s"
+_SKIPPED_RE = r"step (\d+) memory save skipped"
+
+
+class TestSavePipeline:
+    def test_leaf_is_in_the_block_before_the_next_is_released(
+        self, engine, tmp_path, monkeypatch
+    ):
+        """Arrivals gated by the test: leaf k+1 is released only once leaf
+        k's bytes are seen in the block, so the overlap is observed, not
+        timed.  All the while the header is zero (no reader opens the
+        block); the meta, with the crc32 of the bytes staged, comes last;
+        the ``ckpt_stage`` span and ``last_save`` say what it took."""
+        import zlib
+
+        from dlrover_tpu.checkpoint.shm_handler import _HEADER
+        from dlrover_tpu.telemetry import events as tevents
+
+        tdir = str(tmp_path / "telemetry")
+        monkeypatch.setenv(tevents.ENV_TELEMETRY_DIR, tdir)
+        tevents.reset()
+        state = _random_leaves((512, 4096, 1024, 2048))
+        host = {k: np.asarray(v) for k, v in state.items()}
+        taken = [1, 3, 2, 0]  # largest first
+        seen = []
+
+        def gate_of(i):
+            def gate():
+                buf = engine._shm_handler.shared_memory.buf
+                (header,) = _HEADER.unpack(bytes(buf[: _HEADER.size]))
+                before = taken[: taken.index(i)]
+                staged = not before
+                deadline = time.time() + 10
+                while not staged and time.time() < deadline:
+                    staged = host[f"leaf{before[-1]}"].tobytes() in bytes(buf)
+                seen.append((i, staged, header))
+            return gate
+
+        try:
+            # An earlier save, so the header starts out non-zero.
+            assert engine.save_to_memory(1, {"old": jnp.ones(8)}, block=True)
+            _gate_transfers(monkeypatch, gate_of)
+            assert engine.save_to_memory(2, state, block=True)
+        finally:
+            tevents.reset()
+        assert seen == [(i, True, 0) for i in taken]
+        meta = engine._shm_handler.load_meta()
+        assert meta.step == 2
+        # In the block they lie in the order of the tree.
+        assert [t.path[0] for t in meta.tensors] == [
+            f"['leaf{i}']" for i in range(4)
+        ]
+        assert [t.offset for t in meta.tensors] == [0, 512, 4608, 5632]
+        assert [t.crc32 for t in meta.tensors] == [
+            zlib.crc32(host[f"leaf{i}"].tobytes()) for i in range(4)
+        ]
+        took = engine.last_save
+        assert took["step"] == 2 and took["leaves"] == 4
+        assert took["bytes"] == 512 + 4096 + 1024 + 2048
+        assert took["overlap_s"] > 0 and took["tail_s"] > 0
+        ends = [
+            e for e in tevents.read_dir(tdir)
+            if e["ev"] == "span_end" and e.get("name") == "ckpt_stage"
+        ]
+        assert [e["step"] for e in ends] == [1, 2]
+        for field in ("bytes", "leaves", "drain_s", "tail_s", "overlap_s"):
+            assert ends[1][field] == took[field], field
+        assert ends[1]["drain_s"] + ends[1]["tail_s"] <= ends[1]["dur"]
+
+    @pytest.mark.parametrize("reader", ["load_state_dict", "verified_views"])
+    @pytest.mark.parametrize("case", ["mixed", "sharded8"])
+    def test_reads_back_bit_equal(self, engine, devices8, case, reader):
+        """What the pipeline staged comes back bit for bit through the
+        agent's reader and through the restore's: mixed dtypes, an empty
+        leaf, non-array objects, and leaves of several shards."""
+        from dlrover_tpu.checkpoint.engine import host_tree_to_state
+
+        saved, target, shardings = _saved_case(case, devices8)
+        assert engine.save_to_memory(6, saved, block=True)
+        meta = engine._shm_handler.load_meta()
+        arrays = [
+            x for x in jax.tree_util.tree_leaves(saved)
+            if isinstance(x, jax.Array)
+        ]
+        assert meta.total_bytes == sum(x.nbytes for x in arrays)
+        assert engine.last_save["leaves"] == len(arrays)
+        sizes = [t.nbytes for t in meta.tensors]
+        if case == "sharded8":
+            assert len(meta.tensors) > len(arrays)  # several shards a leaf
+        else:
+            assert 0 in sizes and len(meta.tensors) == len(arrays)
+        if reader == "load_state_dict":
+            step, host = engine._shm_handler.load_state_dict()
+            got = host_tree_to_state(host, target, shardings)
+        else:
+            step, got = engine.load(target, shardings)
+            assert engine.last_restore["source"] == "shm"
+        assert step == 6
+        _assert_trees_bit_equal(got, saved)
+
+    @pytest.mark.parametrize("cut", ["fault_point", "lost_transfer"])
+    def test_save_cut_short_leaves_a_block_no_reader_opens(
+        self, engine, monkeypatch, cut
+    ):
+        """A save that stops after its first leaf (the ``ckpt_stage_cut``
+        fault point, or a transfer that never arrives) leaves a block that
+        both readers refuse: not the previous step, not a mixture.  The
+        next save stages as ever."""
+        from dlrover_tpu.checkpoint.shm_handler import _HEADER
+        from dlrover_tpu.common import faults
+
+        first = _random_leaves((2048, 1024, 512))
+        second = jax.tree.map(lambda x: x + 1, first)
+        assert engine.save_to_memory(1, first, block=True)
+        handler = engine._shm_handler
+        assert handler.load_state_dict()[0] == 1
+        if cut == "fault_point":
+            faults.install("ckpt_stage_cut:*:raise@1")
+        else:
+            def gate_of(i):
+                def lost():
+                    if i == 1:  # the second taken: leaf0 is on its way in
+                        raise RuntimeError("transfer lost")
+                return lost
+
+            _gate_transfers(monkeypatch, gate_of)
+        try:
+            assert not engine.save_to_memory(2, second, block=True)
+            if cut == "fault_point":
+                assert [r["ctx"]["leaf"] for r in faults.fired()] == [0]
+        finally:
+            faults.reset()
+            monkeypatch.undo()
+        block = bytes(handler.shared_memory.buf)
+        assert _HEADER.unpack(block[: _HEADER.size]) == (0,)
+        assert handler.load_state_dict() is None
+        assert handler.verified_views() is None
+        step, state = engine.load(first)
+        assert step is None and state is first  # nothing in storage either
+        # The failure is reported once, on the next save, which itself
+        # is dispatched and lands.
+        assert not engine.save_to_memory(3, second)
+        assert engine.wait_staging()
+        step, got = engine.load(first)
+        assert step == 3
+        _assert_trees_bit_equal(got, second)
+
+    @pytest.mark.parametrize("outcome", ["staged", "failed"])
+    def test_stager_keeps_nothing_of_the_snapshot(
+        self, engine, monkeypatch, outcome
+    ):
+        """Once a save is staged (or has failed) no device copy of the
+        snapshot is alive: not its leaves, not the shards handed to the
+        stager, which waits for the next submit holding nothing."""
+        import gc
+        import weakref
+
+        from dlrover_tpu.checkpoint import engine as engine_mod
+
+        refs = []
+        real_take = engine._snapshot.take
+        real_begin = engine_mod.begin_host_transfer
+
+        def take(state):
+            snap = real_take(state)
+            refs.extend(
+                weakref.ref(x) for x in jax.tree_util.tree_leaves(snap)
+            )
+            return snap
+
+        def begin(snap):
+            tree = real_begin(snap)
+            refs.extend(weakref.ref(e.data._data) for e in tree.values())
+            return tree
+
+        monkeypatch.setattr(engine._snapshot, "take", take)
+        monkeypatch.setattr(engine_mod, "begin_host_transfer", begin)
+        if outcome == "failed":
+            def gate_of(i):
+                def lost():
+                    if i == 1:
+                        raise RuntimeError("transfer lost")
+                return lost
+
+            _gate_transfers(monkeypatch, gate_of)
+        state = {
+            k: v for k, v in _mixed_tree().items() if isinstance(v, jax.Array)
+        }
+        ok = engine.save_to_memory(1, state, block=True)
+        assert ok == (outcome == "staged")
+        gc.collect()
+        assert len(refs) == 2 * len(state)
+        assert [r() for r in refs] == [None] * len(refs)
+        # The state itself was never the snapshot.
+        assert all(not x.is_deleted() for x in state.values())
+
+    def test_transfers_start_two_a_device_largest_first(
+        self, devices8, monkeypatch
+    ):
+        """``_IN_FLIGHT`` transfers a device are started at dispatch, in the
+        order the pipeline takes them, and the next of a device as one of
+        its shards arrives: never the whole snapshot at once."""
+        from dlrover_tpu.checkpoint import engine as engine_mod
+        from dlrover_tpu.checkpoint.shm_handler import largest_first
+
+        sizes = {"a": 16, "b": 64, "c": 32, "d": 48, "e": 8}
+        mesh = jax.sharding.Mesh(np.array(devices8[:2]), ("x",))
+        split = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec("x")
+        )
+        state = {
+            k: jax.device_put(jnp.arange(n, dtype=jnp.float32), split)
+            for k, n in sizes.items()
+        }
+        started = []
+        real_start = engine_mod._start_next
+
+        def start_next(waiting):
+            started.extend(list(waiting)[:1])
+            real_start(waiting)
+
+        monkeypatch.setattr(engine_mod, "_start_next", start_next)
+        tree = engine_mod.begin_host_transfer(state)
+        assert list(tree) == [(f"['{k}']", i) for k in sizes for i in (0, 1)]
+        order = largest_first(tree)
+        assert [key[0][2] for key in order] == list("bbddccaaee")
+        in_flight = 2 * engine_mod._IN_FLIGHT  # two devices
+        taken = [id(tree[key].data._data) for key in order]
+        assert sorted(map(id, started)) == sorted(taken[:in_flight])
+        for n, key in enumerate(order):
+            got = np.asarray(tree[key].data)
+            want = np.asarray(state[key[0][2]]).reshape(2, -1)[key[1]]
+            assert got.tobytes() == want.tobytes()
+            # One arrived: the next of its device, if any waits, is started.
+            assert len(started) == min(in_flight + n + 1, len(order))
+        assert sorted(map(id, started)) == sorted(taken)  # each once
+
+    def test_log_lines_read_as_the_benchmark_reads_them(self, engine):
+        """The "staged" and "skipped" lines still match the benchmark's
+        regular expressions; ``drain`` and ``memcpy`` are the pipeline's
+        ``drain_s`` and ``tail_s``."""
+        import logging
+        import re
+
+        from dlrover_tpu.common.log import logger
+
+        lines = []
+
+        class Lines(logging.Handler):
+            def emit(self, record):
+                lines.append(record.getMessage())
+
+        handler, level = Lines(), logger.level
+        logger.addHandler(handler)
+        logger.setLevel(logging.INFO)
+        gate = threading.Event()
+        stage = engine._stage_to_shm
+
+        def gated(step, tree, persist):
+            gate.wait(10)
+            return stage(step, tree, persist)
+
+        engine._stager._process = gated
+        tree = _mixed_tree()
+        try:
+            assert engine.save_to_memory(8, tree)
+            assert not engine.save_to_memory(9, tree)  # a drain in flight
+            gate.set()
+            assert engine.wait_staging()
+        finally:
+            gate.set()
+            logger.removeHandler(handler)
+            logger.setLevel(level)
+        staged = [m for m in map(re.compile(_STAGED_RE).search, lines) if m]
+        assert [m.group(1) for m in staged] == ["8"]
+        took = engine.last_save
+        assert abs(float(staged[0].group(2)) - took["drain_s"]) < 1e-3
+        assert abs(float(staged[0].group(3)) - took["tail_s"]) < 1e-3
+        skipped = [m for m in map(re.compile(_SKIPPED_RE).search, lines) if m]
+        assert [m.group(1) for m in skipped] == ["9"]
 
 
 def _bounds_sets(sharding, shape):
