@@ -153,9 +153,9 @@ def close_enough(a, b, tol):
 
 
 def run_tpurun_job(workdir, chips, tiny):
-    """One ``tpurun`` job in this process (the agent is in-process, like
-    ``goodput.py``), a watcher thread that SIGKILLs the active worker once
-    it reports, and a deadline that ends the job if it stalls.  Returns
+    """One ``tpurun`` job in this process (the agent is in-process), a
+    watcher thread that SIGKILLs the active worker once it reports, and
+    a deadline that ends the job if it stalls.  Returns
     ``(events, t_kill, killed_pid, rc)``."""
     from dlrover_tpu.launch import elastic_run
 

@@ -24,9 +24,9 @@ fails on:
 
 Additive changes — new message classes, new fields *with* defaults —
 pass, and are listed in the ``comm_schema`` verdict the JSON report
-carries (``extras``), which the round gate records in
-``GATE_STATUS.json``.  After a deliberate, reviewed schema change,
-regenerate the snapshot with::
+carries (``extras``), which ``analysis/gate.py`` copies into its
+summary and tier-1 holds to ``ok``.  After a deliberate, reviewed
+schema change, regenerate the snapshot with::
 
     python -m dlrover_tpu.analysis --update-comm-schema
 

@@ -25,7 +25,7 @@ Suppression pragma::
     risky_line()  # dlr: noqa          (all codes — use sparingly)
 
 A suppressed finding still shows up in the JSON report (``suppressed``
-list) so the gate can count how much is being waved through.
+list) so ``analysis/gate.py`` can count how much is being waved through.
 """
 
 import ast
@@ -258,7 +258,7 @@ class Report:
     checked_files: int = 0
     checkers: List[str] = field(default_factory=list)
     # Structured per-checker verdicts (``comm_schema`` etc.), surfaced
-    # in the JSON report for the round gate to record.
+    # in the JSON report for ``analysis/gate.py`` to judge.
     extras: Dict = field(default_factory=dict)
 
     @property

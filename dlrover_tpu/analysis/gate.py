@@ -1,23 +1,22 @@
-"""Round-gate helpers for the analyzer: pragma budgets and the wire
+"""The analyzer's gate over this package: pragma budget and wire
 schema verdict.
 
-``scripts/round_gate.py`` runs ``python -m dlrover_tpu.analysis --json``
-and records the summary in ``GATE_STATUS.json``.  Two policies live
-here (importable, so ``tests/test_analysis.py`` can exercise them
-without dragging in the gate script's bench machinery):
+``tests/test_analysis.py::TestTree`` runs the analyzer over
+``dlrover_tpu/`` in tier-1 and passes the JSON payload through
+:func:`analysis_summary`.  Two policies live here:
 
 * **Pragma budget** — suppressions (``# dlr: noqa[...]``) are debt.
-  The previous round's per-code suppressed tally in GATE_STATUS.json is
-  the budget; a round whose tally *grows* for any code fails the
-  analysis gate unless it was run with ``--accept-pragmas``, which
-  re-baselines on the new tally.  Shrinking is always fine (paying
-  debt never needs a flag).
+  The per-code tally committed at
+  ``tests/analysis_fixtures/pragma_budget.json`` is the budget; a tree
+  whose tally *grows* for any code fails.  A deliberate new
+  suppression re-baselines that file by hand, in review, as
+  ``--update-comm-schema`` re-baselines the wire schema.  Shrinking is
+  always fine (paying debt needs no edit).
 
 * **Wire schema verdict** — the ``comm_schema`` entry the DLR018
-  checker leaves in the report's ``extras`` is copied into the analysis
-  summary so the round record says not just "analysis green" but "the
-  wire schema is byte-compatible with the snapshot" (or what changed
-  additively).
+  checker leaves in the report's ``extras`` is copied into the summary,
+  so the verdict says not just "analysis green" but "the wire schema is
+  byte-compatible with the snapshot" (or what changed additively).
 """
 
 from typing import Dict, List, Optional
@@ -42,18 +41,14 @@ def suppressed_counts(payload: Dict) -> Dict[str, int]:
 def pragma_budget(
     current: Dict[str, int],
     baseline: Optional[Dict[str, int]],
-    accept: bool = False,
 ) -> Dict:
-    """Compare this round's suppressed tally against the previous
-    round's (the budget).  Returns::
+    """Compare the suppressed tally against the budget.  Returns::
 
         {"ok": bool, "grew": ["DLR00x: a -> b", ...],
-         "baseline": {...} | None, "accepted": bool}
+         "baseline": {...} | None}
 
-    ``baseline=None`` (first round, or a GATE_STATUS.json from before
-    budgets existed) always passes — there is nothing to diff against.
-    ``accept=True`` passes regardless and marks the verdict so the
-    round record shows the re-baseline was explicit.
+    ``baseline=None`` (a tree without a committed budget) always
+    passes — there is nothing to diff against.
     """
     grew: List[str] = []
     if baseline is not None:
@@ -61,44 +56,29 @@ def pragma_budget(
             was, now = baseline.get(code, 0), current.get(code, 0)
             if now > was:
                 grew.append(f"{code}: {was} -> {now}")
-    return {
-        "ok": accept or not grew,
-        "grew": grew,
-        "baseline": baseline,
-        "accepted": bool(accept and grew),
-    }
+    return {"ok": not grew, "grew": grew, "baseline": baseline}
 
 
 def analysis_summary(
     payload: Dict,
     rc: int,
-    previous: Optional[Dict] = None,
-    accept_pragmas: bool = False,
+    budget: Optional[Dict[str, int]] = None,
 ) -> Dict:
-    """The ``analysis`` section for GATE_STATUS.json.
+    """The gate's verdict on one analyzer run.
 
-    ``previous`` is the prior round's ``analysis`` section (its
-    ``suppressed_counts`` is the pragma budget).  ``ok`` requires a
-    clean exit AND a respected pragma budget.
+    ``budget`` is the per-code suppressed tally the tree is held to.
+    ``ok`` requires a clean exit AND a respected pragma budget.
     """
     counts = suppressed_counts(payload)
-    baseline = None
-    if previous and isinstance(
-        previous.get("suppressed_counts"), dict
-    ):
-        baseline = {
-            str(k): int(v)
-            for k, v in previous["suppressed_counts"].items()
-        }
-    budget = pragma_budget(counts, baseline, accept=accept_pragmas)
+    verdict = pragma_budget(counts, budget)
     summary = {
-        "ok": rc == 0 and budget["ok"],
+        "ok": rc == 0 and verdict["ok"],
         "rc": rc,
         "finding_count": len(payload.get("findings", [])),
         "suppressed_count": len(payload.get("suppressed", [])),
         "counts": payload.get("counts", {}),
         "suppressed_counts": counts,
-        "pragma_budget": budget,
+        "pragma_budget": verdict,
         "checked_files": payload.get("checked_files"),
     }
     schema = payload.get("extras", {}).get("comm_schema")

@@ -1,6 +1,6 @@
 """Render an analysis :class:`~dlrover_tpu.analysis.core.Report` as
-human text, machine JSON (the round gate stores the JSON summary in
-``GATE_STATUS.json``), or SARIF 2.1.0 for code-scanning UIs."""
+human text, machine JSON (what ``analysis/gate.py`` summarises), or
+SARIF 2.1.0 for code-scanning UIs."""
 
 import json
 

@@ -295,8 +295,8 @@ class CheckpointOptimization(Optimization):
 # tensor would exceed this many bytes (bf16).  256MB ≈ a 32k-vocab
 # batch-8 seq-1024 step — below it the plain head is fine, above it the
 # logits buffer starts crowding HBM (2 GB at 128k vocab).  This is the
-# memory-bound crossover; re-pin from the on-chip `fusedce` speed probe
-# (scripts/perf_probe.py) when it lands.
+# memory-bound crossover; not measured on the chip (ROADMAP S4: a cell
+# whose head and loss are a large share settles it).
 FUSED_CE_AUTO_LOGITS_BYTES = 256 * 2**20
 
 

@@ -775,8 +775,8 @@ def brain_strategy(
     calibrated cost model, and returns ``(strategy, plan)`` with the
     strategy's ``source`` set to ``"brain"`` and a ``plan_source``
     verdict emitted.  When no AOT ``probe`` is injected the proposal
-    rests on the analytic tables alone (the probe path is how the
-    round gate confirms HBM fit on real XLA numbers).
+    rests on the analytic tables alone (the probe path confirms HBM
+    fit on real XLA numbers: ``tests/test_brain_decision.py``).
     """
     from dlrover_tpu.auto.analyser import Analyser, DeviceContext
     from dlrover_tpu.brain.decision import LayoutProfile, plan_layout

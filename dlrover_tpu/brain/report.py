@@ -1,7 +1,6 @@
 """Fleet report: render the telemetry warehouse as markdown + JSON.
 
-Consumed by ``python -m dlrover_tpu.brain report`` and the round gate's
-warehouse stage.  The report answers the three questions an operator
+Consumed by ``python -m dlrover_tpu.brain report``.  The report answers the three questions an operator
 asks of fleet history: how is goodput/MFU trending, what keeps going
 wrong (incident frequency by trigger), and is it the same hardware every
 time (straggler repeat offenders).

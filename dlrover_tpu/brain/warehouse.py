@@ -385,10 +385,10 @@ class TelemetryWarehouse:
     def add_kv_summary(
         self, job_uid: str, entry: dict, run: str = "", attempt: int = 0
     ):
-        """One embedding-service summary (``kind: "kv"`` ledger shape —
-        kv_bench / kv_bench_mt / kv_bench_dist / gate kv stage).  Value
-        is the headline rows/s for whichever bench produced it, so the
-        trend query can plot a single capacity line per source."""
+        """One embedding-service summary (``kind: "kv"`` line of the
+        program's perf history).  Value is the headline rows/s of
+        whichever source produced it, so the trend query can plot a
+        single capacity line per source."""
         value = None
         for k in ("aggregate_rows_per_s", "contended_gather_rows_per_s",
                   "gather_rows_per_s", "hot_key_skew"):
@@ -404,8 +404,8 @@ class TelemetryWarehouse:
     def add_serve_summary(
         self, job_uid: str, entry: dict, run: str = "", attempt: int = 0
     ):
-        """One serving-bench summary (``kind: "serve"`` ledger shape —
-        serve_bench / gate serve stage).  Value is the gateway's
+        """One serving summary (``kind: "serve"`` line of the program's
+        perf history).  Value is the gateway's
         generated tokens/s, the headline the trend query plots; the
         legacy-engine baseline and servput numbers ride in the
         payload."""

@@ -32,8 +32,8 @@ Layout:
   lease-fenced promotion + health polling (:class:`KvHaManager`), and
   the anti-entropy digest scan — always-on serving for the keyspace
   (docs/KV_SERVICE.md §Replication).
-* ``__main__`` — real-process shard entrypoint for the CPU harness,
-  ``scripts/kv_bench_dist.py`` and the chaos/HA drills.
+* ``__main__`` — real-process shard entrypoint for the CPU harness
+  and the chaos/HA drills.
 
 The client is duck-type compatible with :class:`KvVariable` for the
 surfaces training uses (``dim``/``slots``/``gather_or_init``/

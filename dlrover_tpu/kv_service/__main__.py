@@ -4,10 +4,9 @@
 starts one :class:`KvShardServer` on an ephemeral port and writes a
 JSON ready file ``{"name", "port", "http_port", "pid", "restored_rows",
 "recovery_s"}`` once serving — the same handshake idiom as the CPU
-harness (``runtime/harness.py``).  Used by ``scripts/kv_bench_dist.py``,
-the ``round_gate`` kv stage, and the chaos drill, all of which need the
-shard to be a genuinely separate OS process (its own GIL, its own C++
-store, killable with SIGKILL).
+harness (``runtime/harness.py``).  Used by ``scripts/kv_ha_drill.py``
+and the chaos tests, which need the shard to be a genuinely separate OS
+process (its own GIL, its own C++ store, killable with SIGKILL).
 """
 
 import argparse

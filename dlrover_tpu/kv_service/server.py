@@ -19,9 +19,8 @@ the master uses, shared-secret token included), plus:
   (``time.thread_time``): on a colocated CI box, wall clock around the
   op would charge a shard for timeslices the OS gave its neighbours,
   making aggregate capacity look flat.  CPU time is what the shard
-  actually spends serving — the service-capacity metric
-  ``scripts/kv_bench_dist.py`` aggregates to predict an N-host
-  deployment (docs/KV_SERVICE.md §Bench methodology).
+  actually spends serving (``/kvz`` reports it per op; no cell
+  measures the service yet, ROADMAP S8).
 * **Serving-time HTTP lookup** — the telemetry-httpd pattern:
   ``/lookup?keys=1,2,3`` (read-only gather-or-zeros) and ``/kvz``
   stats, for online traffic that shouldn't speak gRPC.

@@ -22,13 +22,12 @@ Lowering honesty (this matters for reading the AOT census): jaxlib
 0.4.36's TPU pipeline materializes "partial gradient → scattered
 layout" as ``all-reduce + dynamic-slice`` rather than a literal
 ``reduce-scatter`` HLO op; the fused reduce-scatter only appears for
-explicit ``lax.psum_scatter`` in manual (shard_map) regions — see the
-ring-attention program in ``AOT_SLICE.json``, which does emit it.  The
-HBM reduction and the ÷N update math are compiler-verified either way
-(``memory_analysis``); ``telemetry/costmodel.py`` predicts both
-lowerings' collective bytes and the census records which one XLA
-picked, so a toolchain upgrade that starts fusing AR+DS shows up in
-the ledger as a win, not a mystery.
+explicit ``lax.psum_scatter`` in manual (shard_map) regions — the
+ring-attention program of ``scripts/aot_slice_compile.py`` does emit
+it.  The HBM reduction and the ÷N update math are compiler-verified
+either way (``memory_analysis``); ``telemetry/costmodel.py`` predicts
+both lowerings' collective bytes and the census says which one XLA
+picked, so a toolchain upgrade that starts fusing AR+DS is seen.
 
 Two modes (``make_train_step(weight_update_sharding=...)``):
 

@@ -1,22 +1,21 @@
 """XLA cost-model oracle: predicted step time / MFU without chips.
 
 ``scripts/aot_slice_compile.py`` proved the flagship programs compile
-for real slice topologies and recorded ``compiled.cost_analysis()``
-flops/bytes per step.  This module promotes that pipeline into a
-library (one source of truth — the script and ``scripts/perf_probe.py``
-import from here) and adds the half that makes the numbers *predictive*:
+for real slice topologies and reads ``compiled.cost_analysis()``
+flops/bytes per step.  This module holds that pipeline as a library
+(the script imports from here) and the half that turns the numbers
+into a *prediction*:
 
 * a per-backend peak-FLOPs table;
-* a calibration factor (achieved MFU) learned from the last green
-  on-chip measurement (``BENCH_LAST_GREEN.json``, else the newest
-  measured TPU entry in the perf ledger), so the prediction inherits
-  everything the static model can't see (runtime overheads, input
-  pipeline, attention FLOPs) from the closest real run;
-* an append-only ``perf_history.jsonl`` at the repo root (git-ignored;
-  the driver owns the checked-in perf ledger) recording every
-  round's number — measured or predicted, flagged which — so the perf
-  trajectory is never blind again (ROADMAP open item 5; AMP in
-  PAPERS.md validates cost-model ranking over compile artifacts).
+* a calibration factor (achieved MFU) read from
+  ``BENCH_LAST_GREEN.json`` where a checkout still has one, else from
+  the newest measured TPU entry in the program's perf history, else a
+  default;
+* readers and an appender for ``perf_history.jsonl`` at the repo root
+  (git-ignored; not the driver's ledger).  Nothing in
+  the tree writes either file any more: a prediction made here is a
+  planning input (``brain/decision``, ``auto/``), never a measurement
+  (ROADMAP D1(b)).
 
 Nothing here imports jax at module import time: the AOT helpers are
 used from subprocesses that must pin the platform first.
@@ -323,6 +322,20 @@ def abstract_sharded_state(model, optimizer, mesh, rules, batch_abs):
     return abs_with_sharding, shardings
 
 
+def _held_bytes(mem) -> Optional[int]:
+    """What one chip must hold to run the program: arguments, outputs
+    that alias no donated argument, and temporaries.  The temporaries
+    alone read 0 for a small program, and leave out the state."""
+    if mem is None:
+        return None
+    return int(
+        mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+        - mem.alias_size_in_bytes
+        + mem.temp_size_in_bytes
+    )
+
+
 def compile_and_analyze(lowered, name: str, topology: str,
                         n_params: int = 0) -> dict:
     """Shared compile + HLO/cost/memory extraction for the train-step
@@ -344,33 +357,9 @@ def compile_and_analyze(lowered, name: str, topology: str,
         ),
         "collective_census": collective_census(txt),
         "flops_per_step": cost.get("flops"),
-        "hbm_bytes_per_chip": getattr(mem, "temp_size_in_bytes", None),
+        "hbm_bytes_per_chip": _held_bytes(mem),
         "output_bytes": cost.get("bytes accessed output", None),
     }
-
-
-def build_train_program(model, optimizer, mesh, rules, sample,
-                        rng_key=None):
-    """The CONCRETE build both measurement paths share (bench.py and
-    scripts/perf_probe.py): sharded state + jitted train step + the
-    sample placed with the data sharding.  Returns
-    ``(state, step_fn, sample)``."""
-    import jax
-
-    from dlrover_tpu.trainer.step import (
-        create_sharded_state,
-        data_sharding,
-        make_train_step,
-    )
-
-    if rng_key is None:
-        rng_key = jax.random.key(0)
-    state, shardings = create_sharded_state(
-        model, optimizer, mesh, rules, rng_key, sample
-    )
-    step_fn = make_train_step(model, mesh, rules, shardings)
-    sample = jax.device_put(sample, data_sharding(mesh, rules))
-    return state, step_fn, sample
 
 
 # ----------------------------------------------------------------------
@@ -472,8 +461,8 @@ def packed_vs_dense_prediction(
     """Predicted tokens/s for a packed batch vs the same batch priced as
     dense causal: parameter FLOPs (6·N·tokens) plus the mask-aware /
     dense attention term respectively.  Feeds
-    ``StepPhaseProfiler.set_packed_prediction`` and the round gate's
-    packed census — model outputs, labeled as such by every consumer.
+    ``StepPhaseProfiler.set_packed_prediction`` — a model output,
+    labeled as such by every consumer.
     """
     attn = packed_attention_summary(
         segment_ids, num_heads, head_dim, num_layers
@@ -576,9 +565,9 @@ def predict_tokens_per_sec(
 
     Uses measured ``flops_per_step`` from ``compiled.cost_analysis()``
     when the caller has one (the AOT path), else the 6·N·tokens
-    parameter-FLOPs estimate — the same formula bench.py's MFU uses, so
-    a prediction calibrated on a green bench run round-trips to that
-    run's own throughput.
+    parameter-FLOPs estimate, the formula the calibration's MFU was
+    reckoned with, so a prediction calibrated on a measured run
+    round-trips to that run's own throughput.
     """
     if flops_per_step is None:
         flops_per_step = 6.0 * float(n_params) * float(tokens_per_step)
@@ -617,8 +606,7 @@ def predict_serving_tokens_per_sec(
     shared, the same roofline split vLLM-style gateways report.
 
     Returns TTFT (the prefill latency), TPOT (one decode tick) and
-    the decode-bound fraction alongside the headline prediction so
-    ``serve_bench`` can ledger the full blind contract.
+    the decode-bound fraction alongside the headline prediction.
     """
     peak = _row(PEAK_FLOPS, backend)
     hbm = _row(HBM_BW_BYTES, backend)
@@ -699,44 +687,6 @@ def wus_collective_fraction(
     if t_coll + t_comp <= 0:
         return None
     return t_coll / (t_coll + t_comp)
-
-
-def calibrated_cpu_proxy(
-    cpu_tokens_per_sec: float, repo: Optional[str] = None
-) -> Optional[Dict[str, Any]]:
-    """Scale a raw CPU-fallback throughput into TPU-equivalent units.
-
-    The scale is learned from history: the newest measured green TPU
-    entry over the newest measured CPU-fallback entry in the ledger
-    (both must exist and be > 0).  Returns None when history can't
-    support a calibration — callers then lean on the cost-model
-    prediction alone.
-    """
-    entries = read_ledger(
-        path=None if repo is None
-        else os.path.join(repo, LEDGER_BASENAME)
-    )
-    tpu = cpu = None
-    for entry in reversed(entries):
-        tok_s = entry.get("tokens_per_sec") or 0.0
-        if tok_s <= 0 or not entry.get("measured"):
-            continue
-        backend = entry.get("backend", "")
-        if tpu is None and backend == "tpu":
-            tpu = entry
-        elif cpu is None and backend == "cpu-fallback":
-            cpu = entry
-        if tpu is not None and cpu is not None:
-            break
-    if tpu is None or cpu is None:
-        return None
-    scale = float(tpu["tokens_per_sec"]) / float(cpu["tokens_per_sec"])
-    return {
-        "proxy_tokens_per_sec": float(cpu_tokens_per_sec) * scale,
-        "scale": scale,
-        "tpu_anchor": tpu.get("round") or tpu.get("ts"),
-        "cpu_anchor": cpu.get("round") or cpu.get("ts"),
-    }
 
 
 # ----------------------------------------------------------------------

@@ -1,9 +1,8 @@
 """Online goodput accountant: event stream → wall-clock attribution.
 
-The offline harness (top-level ``goodput.py``) reconstructs goodput from
-its private event file after the run; this module computes the same
-number live, continuously, from the telemetry event stream — per rank,
-aggregated on the master (servicer ``report`` RPC feeds
+This module computes goodput live, continuously, from the telemetry
+event stream — per rank, aggregated on the master (servicer ``report``
+RPC feeds
 :meth:`GoodputAccountant.ingest`, the telemetry HTTP endpoint serves
 :meth:`summary` at ``/goodput.json``).
 

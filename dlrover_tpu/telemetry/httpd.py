@@ -4,7 +4,7 @@ No third-party server, no framework: ``http.server.ThreadingHTTPServer``
 on a daemon thread, bound to an ephemeral port by default
 (``DLROVER_TELEMETRY_HTTP_PORT`` pins it).  Started by the local and
 distributed job masters; the bound address is exported through
-``DLROVER_TELEMETRY_HTTP_ADDR`` so in-process harnesses (goodput.py)
+``DLROVER_TELEMETRY_HTTP_ADDR`` so in-process harnesses
 and co-hosted tooling can discover it without plumbing.
 
 ``/metrics``        Prometheus text exposition of the default registry

@@ -416,7 +416,7 @@ class MetricsRegistry:
             self._metrics.clear()
 
     def counts(self) -> Dict[str, int]:
-        """{metric name: series count} — the round-gate snapshot."""
+        """{metric name: series count}."""
         with self._lock:
             metrics = list(self._metrics.values())
         return {m.name: m.series_count() for m in metrics}

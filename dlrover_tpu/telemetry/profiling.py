@@ -1,6 +1,6 @@
 """Per-step phase breakdown, device-memory watermarks, trace capture.
 
-The goodput accountant (goodput.py) explains where *wall-clock* went
+The goodput accountant (``telemetry/goodput.py``) explains where *wall-clock* went
 between steps; this module explains where time goes *inside* a step.
 Three instruments, cheapest first:
 
@@ -226,7 +226,7 @@ class StepPhaseProfiler:
 
 
 # The process's default profiler — the trainer grabs this so tests and
-# the bench can read the same instance's summary.
+# a harness can read the same instance's summary.
 _default_profiler: Optional[StepPhaseProfiler] = None
 _default_lock = threading.Lock()
 

@@ -402,7 +402,7 @@ class SloEngine:
             logger.debug("slo warehouse record failed", exc_info=True)
 
     def persist_budget(self) -> None:
-        """Checkpoint the current account (gate stages call this)."""
+        """Checkpoint the current account."""
         self._persist(None)
 
     # -- exposure ----------------------------------------------------------

@@ -11,8 +11,8 @@ model and times three save flavors on your machine:
 - DISK (block=True): the synchronous save other frameworks make you pay.
 
 Then it kills the "process" state and restores from the freshest copy
-(shm first, disk fallback) — the recovery path the goodput harness
-(`goodput.py`) measures under real SIGKILLs.
+(shm first, disk fallback) — the recovery path the benchmark's
+`mistral7b.preempt` cell measures under a real SIGKILL.
 
     python examples/flash_checkpoint/fcp_demo.py
 """

@@ -23,8 +23,10 @@ Programs:
      ``weight_update_sharding="scatter"`` — collective census delta and
      compiler-verified per-chip HBM drop (parallel/wus.py).
 
-Writes AOT_SLICE.json; asserts the expected collectives appear in the
-compiled HLO.  Tiny-config regression: tests/test_aot_topology.py.
+Prints one JSON line with every program's analysis (compiled bytes and
+collectives, never a time on a chip); asserts the expected collectives
+appear in the compiled HLO.  Tiny-config regression:
+tests/test_aot_topology.py.
 
 Usage: python scripts/aot_slice_compile.py  (no TPU needed: the topology
 client never dials a device.)
@@ -50,9 +52,9 @@ def log(msg):
 T0 = time.time()
 
 
-# The AOT pipeline lives in the telemetry cost model now (one source of
-# truth shared with scripts/perf_probe.py and bench.py's predictions);
-# the old private names stay as aliases for the program functions below.
+# The AOT pipeline lives in the telemetry cost model (shared with the
+# decision plane's probe); the old private names stay as aliases for the
+# program functions below.
 from dlrover_tpu.telemetry.costmodel import (  # noqa: E402
     COLLECTIVE_OPS as _COLLECTIVE_OPS,
     abstract_sharded_state as _abstract_sharded_state,
@@ -542,14 +544,7 @@ def main():
         r = _run_isolated(fn_name)
         results.append(r)
         log(f"{r['name']}: ok={r['ok']}")
-    out = os.path.join(REPO, "AOT_SLICE.json")
-    with open(out, "w") as f:
-        json.dump({"ts": time.strftime("%Y-%m-%dT%H:%M:%S"),
-                   "programs": results}, f, indent=1)
-    print(json.dumps({"programs": [
-        {k: r.get(k) for k in ("name", "ok", "collectives", "compile_s")}
-        for r in results
-    ]}))
+    print(json.dumps({"programs": results}))
     return 0 if all(r["ok"] for r in results) else 1
 
 

@@ -1,4 +1,4 @@
-"""Report-only KV high-availability drill for the round gate.
+"""Report-only KV high-availability drill.
 
 Runs the always-on embedding-service story end to end against
 in-process shard servers on loopback RPC (no subprocesses, no jax
@@ -21,14 +21,14 @@ device work — the replication plane's wind tunnel):
 
 All ``kv_failover`` verdicts land in a throwaway Brain warehouse via
 ``ingest_events``, the promoted shard's hot-key top-K summary lands
-via ``add_kv_summary``, and the drill smokes ``fleet_report()`` so
-GATE_STATUS.json records that ``brain report`` renders the failover
-incidents and the hot-key skew rows.
+via ``add_kv_summary``, and the drill smokes ``fleet_report()`` to see
+that ``brain report`` renders the failover incidents and the hot-key
+skew rows.
 
 Never gates (tier-1 owns the real-process SIGKILL promotion drill in
-tests/test_kv_replication.py); this is the round record's "promotion
-still beats chain restore and the freshness plane still accounts"
-receipt.  Forced CPU, pure host-side, never touches a chip.
+tests/test_kv_replication.py); this is an operator's "promotion
+still works and the freshness plane still accounts" receipt: its
+seconds are the CPU's and prove no speed.  Forced CPU, pure host-side, never touches a chip.
 """
 
 import json

@@ -1,10 +1,10 @@
-"""Fleet-observer probe for the round gate (report-only).
+"""Fleet-observer probe (report-only).
 
 Stands up a miniature fleet — two fake worker telemetry endpoints with
 known metric values, a fake serve gateway (scripted ``/generate`` +
 ``/healthz``), and a real kv shard when the kv service imports — then
 points an :class:`ObserverDaemon` at it and answers the four questions
-the round record asks of the observability plane:
+an operator asks of the observability plane:
 
 * does federation reproduce the hand-merged oracle (counters summed,
   fleet p99 from merged cumulative buckets)?

@@ -1,4 +1,4 @@
-"""Report-only serving-fleet chaos drill for the round gate.
+"""Report-only serving-fleet chaos drill.
 
 Runs the warm-standby acceptance story end to end against scripted
 in-process replicas (the fleet logic's wind tunnel — no engine, no
@@ -19,12 +19,12 @@ number — the promoted reform must lose strictly fewer points than the
 cold one.  All fleet verdicts (promotion, brownout transitions) land
 in a throwaway Brain warehouse — wave verdicts live through
 ``attach_warehouse``, brownout verdicts through ``ingest_events`` —
-and the drill smokes ``fleet_report()`` so GATE_STATUS.json records
-that ``brain report`` renders them as incident rows.
+and the drill smokes ``fleet_report()`` to see that ``brain report``
+renders them as incident rows.
 
 Never gates (tier-1 owns the real-process SIGKILL drill in
-tests/test_serving_fleet.py); this is the round record's "failover
-still beats cold respawn and brownout still releases" receipt.
+tests/test_serving_fleet.py); this is an operator's "failover still
+promotes and brownout still releases" receipt on scripted replicas.
 Forced CPU, pure host-side, never touches a chip.
 """
 

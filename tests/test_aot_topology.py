@@ -6,7 +6,7 @@ upgrades the 8-virtual-CPU-device dryrun ("the sharded program executes
 somewhere") to "the real program compiles for real slice hardware".
 This keeps a tiny always-on regression; the flagship programs (llama-7B
 fsdp x tp on v5e-16 and the int8 DCN Local-SGD sync on 2 slices) are
-compiled by scripts/aot_slice_compile.py into AOT_SLICE.json.
+compiled by scripts/aot_slice_compile.py.
 
 No TPU involved: the topology client never dials a device.
 """

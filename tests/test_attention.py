@@ -154,7 +154,7 @@ class TestShardKernelOverMesh:
 class TestSplashAttention:
     """Off-TPU the splash wrapper must fall back to the in-tree path with
     identical semantics; on TPU the library kernel takes over (exercised by
-    bench.py / perf probes, not CPU CI)."""
+    the benchmark's cells and ``tests/test_chip_compile.py``, not here)."""
 
     def test_cpu_fallback_matches_reference(self):
         from dlrover_tpu.ops.splash_attention import splash_attention_gqa

@@ -573,7 +573,7 @@ def _seed_plan_db(path, with_serve=True):
             })
         if with_serve:
             wh.add_serve_summary("job-p", {
-                "ts": 600.0, "source": "serve_bench",
+                "ts": 600.0, "source": "serve",
                 "gateway_tokens_per_sec": 120.0, "measured": True,
             })
     finally:

@@ -578,8 +578,6 @@ class TestDoctorOnChaosBundle:
         the incident to the exact injected fault point on the exact
         first-failing rank, and (b) prices the run's incidents so their
         cost sum agrees with (100 − online goodput) within ±3 points."""
-        import shutil
-
         from dlrover_tpu.telemetry import bundle as tbundle
         from dlrover_tpu.telemetry import events as tevents
         from dlrover_tpu.telemetry.goodput import GoodputAccountant
@@ -632,12 +630,6 @@ class TestDoctorOnChaosBundle:
             tevents.reset()
         assert bundle_path and os.path.exists(bundle_path)
         assert os.path.basename(bundle_path) == "bundle_chaosdoc_1.tar.gz"
-
-        # round_gate's doctor smoke stage re-reads this bundle.
-        export_dir = os.environ.get("DLROVER_CHAOS_EXPORT_DIR")
-        if export_dir:
-            os.makedirs(export_dir, exist_ok=True)
-            shutil.copy(bundle_path, export_dir)
 
         out_dir = tmp_path / "report"
         proc = subprocess.run(

@@ -28,8 +28,8 @@ def _sources(*roots):
                     yield os.path.join(base, name)
 
 
-PROGRAM = ("dlrover_tpu", "scripts", "examples", "bench.py", "goodput.py",
-           "chip_smoke.py", "__graft_entry__.py")
+PROGRAM = ("dlrover_tpu", "scripts", "examples", "chip_smoke.py",
+           "__graft_entry__.py")
 
 
 # ---------------------------------------------------------------------------
@@ -339,8 +339,7 @@ def test_program_history_is_not_the_drivers_ledger(monkeypatch):
     assert "/" + costmodel.LEDGER_BASENAME in ignored
     named = [
         os.path.relpath(path, REPO)
-        for path in _sources("dlrover_tpu", "scripts", "bench.py",
-                             "goodput.py", "chip_smoke.py")
+        for path in _sources("dlrover_tpu", "scripts", "chip_smoke.py")
         if re.search(r"PERF_LEDGER\.jsonl|BENCHMARK\.json",
                      open(path, encoding="utf-8").read())
     ]

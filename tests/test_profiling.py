@@ -309,27 +309,6 @@ class TestCostModel:
         assert cal["source"] == "BENCH_LAST_GREEN.json"
         assert cal["mfu"] == 0.4839
 
-    def test_calibrated_cpu_proxy(self, tmp_path, monkeypatch):
-        ledger = tmp_path / "perf_history.jsonl"
-        monkeypatch.setenv("DLROVER_PERF_LEDGER", str(ledger))
-        assert costmodel.calibrated_cpu_proxy(50.0) is None  # no history
-        costmodel.append_ledger(
-            {"backend": "tpu", "measured": True,
-             "tokens_per_sec": 118000.0, "round": "r02"},
-            path=str(ledger),
-        )
-        assert costmodel.calibrated_cpu_proxy(50.0) is None  # no cpu anchor
-        costmodel.append_ledger(
-            {"backend": "cpu-fallback", "measured": True,
-             "tokens_per_sec": 50.0, "round": "r04"},
-            path=str(ledger),
-        )
-        proxy = costmodel.calibrated_cpu_proxy(60.0)
-        assert proxy["scale"] == pytest.approx(2360.0)
-        assert proxy["proxy_tokens_per_sec"] == pytest.approx(141600.0)
-        assert proxy["tpu_anchor"] == "r02"
-        assert proxy["cpu_anchor"] == "r04"
-
     def test_ledger_append_read_and_torn_line(self, tmp_path):
         path = str(tmp_path / "ledger.jsonl")
         assert costmodel.append_ledger({"a": 1}, path=path) == path

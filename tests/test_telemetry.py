@@ -626,20 +626,14 @@ class TestSatellites:
         assert 12345 not in progress.read_progress(d)
         assert progress.max_progress_step(d) == 7
 
-    def test_round_gate_snapshot(self):
-        sys.path.insert(
-            0, os.path.join(os.path.dirname(__file__), "..", "scripts")
-        )
-        try:
-            import round_gate
-        finally:
-            sys.path.pop(0)
-        snap = round_gate.telemetry_snapshot()
-        assert "metric_series" in snap
-        assert snap["metric_series"].get(
+    def test_speed_monitor_step_reaches_the_registry(self):
+        from dlrover_tpu.master.monitor.speed_monitor import SpeedMonitor
+
+        SpeedMonitor().collect_global_step(1, time.time())
+        assert tmetrics.REGISTRY.counts().get(
             "dlrover_training_global_step"
         ) == 1
-        assert snap["prometheus_bytes"] > 0
+        assert "dlrover_training_global_step" in tmetrics.REGISTRY.render()
 
 
 # -- 2-process kill/recovery through the full online pipeline ----------------

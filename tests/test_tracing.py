@@ -381,7 +381,7 @@ class TestSloEngine:
         h.observe(2.0, exemplar="t-wh")
         fired = engine.tick(1001.0)
         assert fired  # the alert forces a kind="slo" record
-        engine.persist_budget()  # and the gate-stage checkpoint path
+        engine.persist_budget()  # and the explicit checkpoint path
         trend = wh.slo_trend()
         assert len(trend) == 2
         assert all(r["job_uid"] == "job-slo" for r in trend)

@@ -161,25 +161,39 @@ class TestEquivalence:
         ]
         assert any("dp" in str(spec) for spec in stored)
         db = jax.device_put(batch, data_sharding(mesh22, rules))
-        for _ in range(5):
+
+        def hold(loss_tol, param_atol):
+            for m in (m1, m2):
+                np.testing.assert_allclose(
+                    float(m["loss"]), float(m0["loss"]), **loss_tol
+                )
+            for a, b, c in zip(jax.tree.leaves(s0.params),
+                               jax.tree.leaves(s1.params),
+                               jax.tree.leaves(s2.params)):
+                for other in (b, c):
+                    np.testing.assert_allclose(
+                        np.asarray(other), np.asarray(a),
+                        rtol=0, atol=param_atol,
+                    )
+
+        for k in range(5):
             s0, m0 = step0(s0, db)
             s1, m1 = step1(s1, db)
             s2, m2 = step2(s2, db)
-        np.testing.assert_allclose(
-            float(m1["loss"]), float(m0["loss"]), rtol=0, atol=1e-6
-        )
-        np.testing.assert_allclose(
-            float(m2["loss"]), float(m0["loss"]), rtol=0, atol=1e-6
-        )
-        for a, b, c in zip(jax.tree.leaves(s0.params),
-                           jax.tree.leaves(s1.params),
-                           jax.tree.leaves(s2.params)):
-            np.testing.assert_allclose(
-                np.asarray(b), np.asarray(a), rtol=0, atol=1e-6
-            )
-            np.testing.assert_allclose(
-                np.asarray(c), np.asarray(a), rtol=0, atol=1e-6
-            )
+            if k == 0:
+                # One update from one state: a mode that computed
+                # something else would show here.  Observed: loss
+                # bit-equal, grad norm and params one f32 ulp apart
+                # (1.2e-7), the scattered reduction's order.
+                hold(dict(rtol=0, atol=1e-6), 1e-6)
+        # Five updates: that ulp goes through Adam's m / sqrt(v), which
+        # turns a relative difference in a small gradient into an
+        # absolute one in the parameter (with SGD the same five steps
+        # stay within 1.3e-6; the losses are bit-equal through step 4).
+        # Observed at step 5: loss 1.7e-5 apart at 0.86, parameters
+        # 2.4e-4.  Held to 1e-4 relative in the loss and, in the
+        # parameters, a tenth of one update at this learning rate.
+        hold(dict(rtol=1e-4, atol=0), 1e-3)
 
     def test_int8_scatter_matches_replicated_int8(self, mesh22):
         """int8 blockwise Adam under WUS: codes/absmax are scattered 1/N
