@@ -1,9 +1,27 @@
-"""Hybrid decoder: Mamba-2 state-space layers beside attention layers,
-laid out by a layer pattern.
+"""Hybrid decoder: a layer pattern of mixers (Mamba-2 state-space layers,
+gated short convolutions, attention) with a dense or a routed-expert FFN
+behind each.
 
-``HybridConfig.layer_types`` names each layer's mixer (``"mamba"`` or
-``"attention"``); the layers are unrolled, each its own parameters.  The
-equations are Granite-4.0-H's (``model_type: granitemoehybrid``, dense):
+``HybridConfig.layer_types`` names each layer's mixer in the published
+spellings: ``"mamba"`` and ``"attention"`` (Granite-4.0-H), ``"conv"`` and
+``"full_attention"`` (LFM2); the layers are unrolled, each its own
+parameters.  Two families are written down here.  The fields that tell
+them apart default to Granite's, so its program is what it was.
+
+**LFM2** (``model_type: lfm2_moe``).  Every multiplier is 1 and the head is
+the embedding, tied.  ``conv`` mixer: ``B, C, x`` projected from the hidden
+state (no bias), ``u = B * x``, a depthwise causal convolution of
+``conv_width`` taps with neither bias nor activation, ``out = W_out (C *
+conv(u))``.  ``full_attention``: grouped-query attention with an RMSNorm
+over the head dim on q and on k (``qk_norm``, each its own scale) before
+half-split rotary positions (``rope_theta``), scores scaled by
+``1 / sqrt(head_dim)``.  FFN: the gated MLP of ``intermediate_size`` in the
+first ``num_dense_layers`` layers, after them the dropless routed-expert
+layer of ``models/moe.py`` (``num_experts`` router outputs, top
+``num_experts_per_token``, ``moe_intermediate_size`` wide, the block
+``expert_block`` of ``experts_held`` experts held here).
+
+**Granite-4.0-H** (``model_type: granitemoehybrid``, dense):
 
 * ``h = embedding_multiplier * E[ids]``; a layer is
   ``h += residual_multiplier * mixer(RMSNorm(h))`` then
@@ -24,12 +42,13 @@ The five input projections are separate parameters (``z_proj``, ``x_proj``,
 ``b_proj``, ``c_proj``, ``dt_proj``: the published ``in_proj`` cut at its
 own boundaries), as are the convolution's three channel ranges, so that a
 rule table can shard the inner width and the heads over ``tp`` and leave
-``B`` and ``C`` whole.  Compute is ``dtype`` (bf16), parameters float32,
+``B`` and ``C`` whole; the short convolution's ``b_proj``, ``c_proj`` and
+``x_proj`` are cut the same way.  Compute is ``dtype`` (bf16), parameters float32,
 the decay and the states float32 (``ops/ssd.py``).
 
-``segment_ids`` raises in a model with mamba layers: the scan's state is
-not reset at packed document boundaries, so a packed row would leak one
-document's state into the next.
+``segment_ids`` raises in a model with mamba or conv layers: the scan's
+state and the convolution's taps are not reset at packed document
+boundaries, so a packed row would leak one document into the next.
 """
 
 import dataclasses
@@ -47,14 +66,16 @@ from dlrover_tpu.models.llama import (
     Dtype,
     RMSNorm,
     _masked_attention,
+    _rope,
     param_with_axes,
     remat_policy,
     with_constraint,
 )
-from dlrover_tpu.ops import ssd
+from dlrover_tpu.models.moe import RoutedExperts
+from dlrover_tpu.ops import grouped_matmul, ssd
 from dlrover_tpu.ops.splash_attention import splash_attention_gqa
 
-LAYER_KINDS = ("mamba", "attention")
+LAYER_KINDS = ("mamba", "attention", "conv", "full_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,6 +98,18 @@ class HybridConfig:
     ssm_groups: int = 1
     ssm_chunk: int = 256
     conv_width: int = 4
+    # Attention's position term and q/k norm: none in Granite-4.0-H.
+    rope_theta: Optional[float] = None  # None: no rotary positions
+    qk_norm: bool = False
+    # FFN by layer: dense everywhere unless ``num_experts``; then the first
+    # ``num_dense_layers`` are dense and the rest routed (models/moe.py).
+    num_dense_layers: int = 0
+    num_experts: int = 0  # the router's outputs, the whole model's experts
+    num_experts_per_token: int = 0
+    moe_intermediate_size: int = 0
+    experts_held: Optional[int] = None  # None: all of them
+    expert_block: int = 0  # which block of ``experts_held`` is held here
+    routed_scaling_factor: float = 1.0
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
     attention_impl: str = "dot"  # dot | splash
@@ -99,6 +132,12 @@ class HybridConfig:
             raise ValueError(
                 f"ssm_groups={self.ssm_groups}: only one group of B and C "
                 "shared by all heads is built")
+        if self.num_experts and not (
+                0 < self.num_experts_per_token <= self.num_experts
+                and self.moe_intermediate_size > 0):
+            raise ValueError(
+                f"num_experts={self.num_experts} needs "
+                "num_experts_per_token and moe_intermediate_size")
 
     @property
     def resolved_head_dim(self) -> int:
@@ -107,6 +146,24 @@ class HybridConfig:
     @property
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
+
+    def routed(self, layer: int) -> bool:
+        """Whether layer ``layer``'s FFN is the routed-expert layer."""
+        return bool(self.num_experts) and layer >= self.num_dense_layers
+
+    @classmethod
+    def tiny_lfm2(cls, **kw) -> "HybridConfig":
+        """Test-scale LFM2: a dense layer, then conv and attention layers
+        over 8 experts of which 4 go to a token."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            layer_types=("conv", "full_attention", "conv"), num_heads=4,
+            num_kv_heads=2, conv_width=3, rope_theta=1e6, qk_norm=True,
+            num_dense_layers=1, num_experts=8, num_experts_per_token=4,
+            moe_intermediate_size=32,
+        )
+        base.update(kw)
+        return cls(**base)
 
     @classmethod
     def tiny(cls, **kw) -> "HybridConfig":
@@ -224,13 +281,54 @@ class MambaMixer(nn.Module):
         return with_constraint(out, ("batch", "seq", "act_embed"))
 
 
-class HybridAttention(nn.Module):
-    """Causal grouped-query attention with no position term."""
+class ShortConv(nn.Module):
+    """LFM2's gated short convolution: ``W_out (C * conv(B * x))``."""
 
     cfg: HybridConfig
 
     @nn.compact
-    def __call__(self, h, segment_ids=None):
+    def __call__(self, h):
+        cfg = self.cfg
+        inner = cfg.hidden_size
+
+        def project(name, axes):  # the inner width is the hidden size
+            return nn.DenseGeneral(
+                features=inner, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, use_bias=False,
+                kernel_init=param_with_axes(
+                    nn.initializers.lecun_normal(), axes),
+                name=name,
+            )
+
+        with jax.named_scope("conv/in_proj"):
+            B, C, x = (
+                with_constraint(
+                    project(name, ("embed", "conv_inner"))(h),
+                    ("batch", "seq", "act_conv_inner"))
+                for name in ("b_proj", "c_proj", "x_proj"))
+        with jax.named_scope("conv/conv"):
+            taps = self.param(
+                "conv",
+                param_with_axes(
+                    _conv_init(cfg.conv_width), ("conv_width", "conv_inner")),
+                (cfg.conv_width, inner), cfg.param_dtype,
+            )
+            y = C * ssd.causal_conv1d(B * x, taps)
+        y = with_constraint(y, ("batch", "seq", "act_conv_inner"))
+        with jax.named_scope("conv/out_proj"):
+            out = project("out_proj", ("conv_inner", "embed"))(y)
+        return with_constraint(out, ("batch", "seq", "act_embed"))
+
+
+class HybridAttention(nn.Module):
+    """Causal grouped-query attention; ``cfg.qk_norm`` and
+    ``cfg.rope_theta`` add LFM2's RMSNorm on q and k and its rotary
+    positions (Granite-4.0-H has neither)."""
+
+    cfg: HybridConfig
+
+    @nn.compact
+    def __call__(self, h, positions=None, segment_ids=None):
         cfg = self.cfg
         d = cfg.resolved_head_dim
         scale = cfg.attention_multiplier
@@ -250,6 +348,25 @@ class HybridAttention(nn.Module):
         q = project("q_proj", cfg.num_heads, "heads")
         k = project("k_proj", cfg.num_kv_heads, "kv_heads")
         v = project("v_proj", cfg.num_kv_heads, "kv_heads")
+        if cfg.qk_norm:
+            def head_norm(name, t):
+                weight = self.param(
+                    name,
+                    param_with_axes(
+                        nn.initializers.ones_init(), ("head_dim",)),
+                    (d,), cfg.param_dtype,
+                ).astype(jnp.float32)
+                t = t.astype(jnp.float32)
+                t = t * jax.lax.rsqrt(
+                    jnp.mean(t * t, axis=-1, keepdims=True)
+                    + cfg.rms_norm_eps)
+                return (t * weight).astype(cfg.dtype)
+
+            q, k = head_norm("q_norm", q), head_norm("k_norm", k)
+        if cfg.rope_theta is not None:
+            if positions is None:
+                positions = jnp.arange(h.shape[1])[None]
+            q, k = _rope(q, k, positions, d, cfg.rope_theta)
         q = with_constraint(q, ("batch", "seq", "act_heads", "act_head_dim"))
         k = with_constraint(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
         v = with_constraint(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
@@ -276,31 +393,54 @@ class HybridAttention(nn.Module):
         return with_constraint(out, ("batch", "seq", "act_embed"))
 
 
+def routed_experts(cfg: HybridConfig, **kw) -> RoutedExperts:
+    """The routed-expert FFN of a configuration (``scripts/logits_check.py``
+    builds it alone to hold one layer to the reference)."""
+    return RoutedExperts(
+        hidden_size=cfg.hidden_size,
+        intermediate_size=cfg.moe_intermediate_size,
+        num_experts=cfg.num_experts,
+        num_experts_per_token=cfg.num_experts_per_token,
+        experts_held=cfg.experts_held,
+        expert_block=cfg.expert_block,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        dtype=cfg.dtype, param_dtype=cfg.param_dtype, **kw)
+
+
 class HybridBlock(nn.Module):
     cfg: HybridConfig
     kind: str
+    routed: bool = False
 
     @nn.compact
-    def __call__(self, x, segment_ids=None):
+    def __call__(self, x, positions=None, segment_ids=None):
         cfg = self.cfg
         norm = partial(RMSNorm, cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype)
         h = norm(name="input_norm")(x)
         if self.kind == "mamba":
             mixed = MambaMixer(cfg, name="mamba")(h)
+        elif self.kind == "conv":
+            mixed = ShortConv(cfg, name="conv")(h)
         else:
             with jax.named_scope("hybrid/attention"):
-                mixed = HybridAttention(cfg, name="attention")(h, segment_ids)
+                mixed = HybridAttention(cfg, name="attention")(
+                    h, positions, segment_ids)
         x = x + cfg.residual_multiplier * mixed
         h = norm(name="post_norm")(x)
-        with jax.named_scope("hybrid/mlp"):
-            x = x + cfg.residual_multiplier * MLP(cfg, name="mlp")(h)
+        if self.routed:
+            ffn = routed_experts(cfg, name="experts")(h)
+        else:
+            with jax.named_scope("hybrid/mlp"):
+                ffn = MLP(cfg, name="mlp")(h)
+        x = x + cfg.residual_multiplier * ffn
         return with_constraint(x, ("batch", "seq", "act_embed"))
 
 
 class HybridModel(nn.Module):
     """Decoder-only LM over a layer pattern.  ``__call__`` returns logits
-    (b, s, vocab), with ``LlamaModel``'s signature (``positions`` is
-    accepted and unused: no layer has a position term)."""
+    (b, s, vocab), with ``LlamaModel``'s signature (``positions`` reaches
+    the attention layers of a configuration with ``rope_theta``; ``None``
+    counts each row from 0)."""
 
     cfg: HybridConfig
 
@@ -310,20 +450,36 @@ class HybridModel(nn.Module):
 
         cfg = self.cfg
         kinds = Counter(cfg.layer_types)
-        if segment_ids is not None and kinds["mamba"]:
+        if segment_ids is not None and (kinds["mamba"] or kinds["conv"]):
             raise ValueError(
                 "HybridModel: segment_ids (packed rows) are not supported "
-                "with mamba layers: the scan's state is not reset at "
-                "document boundaries (ops/ssd.py)")
+                "with mamba or conv layers: neither the scan's state nor "
+                "the convolution's taps are reset at document boundaries "
+                "(ops/ssd.py)")
         # What each tracing of the model lowered, by layer kind, for the
         # telemetry directory (one span a trace, not a step).
         with span("lower", what="hybrid") as lowered:
             lowered.update(
-                layer_types=dict(kinds), chunk=cfg.ssm_chunk,
-                n_chunks=input_ids.shape[1] // cfg.ssm_chunk,
+                layer_types=dict(kinds),
                 attention_impl=cfg.attention_impl,
                 head_dim=cfg.resolved_head_dim,
             )
+            if kinds["mamba"]:
+                lowered.update(
+                    chunk=cfg.ssm_chunk,
+                    n_chunks=input_ids.shape[1] // cfg.ssm_chunk)
+            if cfg.num_experts:
+                pairs = input_ids.size * cfg.num_experts_per_token
+                h, m = cfg.hidden_size, cfg.moe_intermediate_size
+                lowered.update(
+                    num_experts=cfg.num_experts,
+                    experts_held=cfg.experts_held or cfg.num_experts,
+                    top_k=cfg.num_experts_per_token, pairs_rows=pairs,
+                    routed_layers=sum(
+                        cfg.routed(i) for i in range(len(cfg.layer_types))),
+                    gmm_gate_up=grouped_matmul.plan(pairs, h, 2 * m),
+                    gmm_down=grouped_matmul.plan(pairs, m, h),
+                )
             embed = self.param(
                 "embed_tokens",
                 param_with_axes(
@@ -340,7 +496,8 @@ class HybridModel(nn.Module):
                     prevent_cse=True,
                 )
             for i, kind in enumerate(cfg.layer_types):
-                x = block_cls(cfg, kind, name=f"layers_{i}")(x, segment_ids)
+                x = block_cls(cfg, kind, cfg.routed(i), name=f"layers_{i}")(
+                    x, positions, segment_ids)
             with jax.named_scope("hybrid/head"):
                 x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                             name="final_norm")(x)

@@ -1,18 +1,39 @@
-"""Mixture-of-Experts FFN with capacity-based token dispatch.
+"""Mixture-of-Experts FFNs: two layers that share nothing but this file.
 
-Reference parity: ``atorch/modules/moe/moe_layer.py:161`` (``MOELayer`` with
-``_AllToAll:87`` dispatch), ``topk_gating.py``, ``switch_gating.py``,
-``grouped_gemm_moe.py``.  TPU redesign (GShard/Switch formulation): dispatch
-and combine are dense einsums over a static capacity dim — no gather/scatter,
-no torch all-to-all calls.  Expert weights carry the ``expert`` logical axis;
-when the rule table maps it to the ``ep`` mesh axis, GSPMD lowers the
-dispatch/combine einsums to the all-to-alls the reference hand-codes, and the
-per-expert matmuls to grouped GEMMs on local experts.
+* :class:`MoEMLP` — capacity-based dispatch for ``LlamaModel``
+  (``LlamaConfig.num_experts``).  It has never run on the chip.
+* :class:`RoutedExperts` — the dropless layer of the layer-pattern models
+  (``models/hybrid.py``): told which experts it holds, it routes over all
+  of them and computes its own experts' part.  This is the one that runs
+  on the chip (the benchmark's ``lfm2moe.steady``).
 
-Gating (top-1 "switch" or top-k) adds two sown losses the train step folds
-into the objective:
+``MoEMLP``.  Reference parity: ``atorch/modules/moe/moe_layer.py:161``
+(``MOELayer`` with ``_AllToAll:87`` dispatch), ``topk_gating.py``,
+``switch_gating.py``, ``grouped_gemm_moe.py``.  TPU redesign (GShard/Switch
+formulation): dispatch and combine are dense einsums over a static capacity
+dim — no gather/scatter, no torch all-to-all calls; a token over its
+expert's capacity is **dropped**.  Expert weights carry the ``expert``
+logical axis; when the rule table maps it to the ``ep`` mesh axis, GSPMD
+lowers the dispatch/combine einsums to the all-to-alls the reference
+hand-codes, and the per-expert matmuls to grouped GEMMs on local experts.
+Gating (top-1 "switch" or top-k, softmax) adds two sown losses the train
+step folds into the objective:
 - ``moe_aux_loss``: load-balancing loss  E * Σ_e f_e · P_e  (Switch eq. 4);
 - ``moe_z_loss``: router logit magnitude regularizer.
+
+``RoutedExperts``.  ``s = sigmoid(W_g n)`` in float32 over all
+``num_experts``; picks = top-k of ``s + b``, where the per-expert bias ``b``
+enters the selection only; weights ``s[picks] / (Σ s[picks] + 1e-6)`` times
+``routed_scaling_factor``; ``out = Σ_picks w_e · SwiGLU_e(n)`` over the
+picks whose expert is **held here** (a contiguous block, ``experts_held``
+wide, number ``expert_block``; held = all is the uncut layer).  Picks whose
+expert lies elsewhere add nothing: on one chip the layer runs without its
+``ep`` exchange, and the partial sum is what goes on.  No token is dropped
+whatever the imbalance: the (token, pick) pairs are sorted by expert into a
+buffer sized for the worst case (every pick here), and the grouped products
+(``ops/grouped_matmul.py``) do the work of the pairs that are there.  No
+shared expert, no auxiliary loss; the layer sows ``moe_load``, the pairs
+routed to each held expert and, last, elsewhere, and ``moe_picks``.
 """
 
 from typing import Optional
@@ -20,6 +41,8 @@ from typing import Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 
 param_with_axes = nn.with_logical_partitioning
 with_constraint = nn.with_logical_constraint
@@ -150,6 +173,184 @@ class MoEMLP(nn.Module):
 
         out = jnp.einsum("bsec,ebch->bsh", combine, expert_out)
         return with_constraint(out, ("batch", "seq", "act_embed"))
+
+
+@jax.custom_vjp
+def _rows_of_pairs(tokens, order, position):
+    """Row ``order[j] % t`` of ``tokens`` (t, h) for every sorted slot
+    ``j``: the token of the pair sorted there (pair ``p * t + i`` is token
+    ``i``'s pick ``p``).  ``position`` is ``order``'s inverse.  The
+    gradient is a gather too (each pair's slot, summed over a token's
+    picks), where autodiff would scatter-add 2048-wide rows."""
+    return tokens[order % tokens.shape[0]]
+
+
+def _rows_of_pairs_fwd(tokens, order, position):
+    return _rows_of_pairs(tokens, order, position), (
+        position, tokens.shape[0])
+
+
+def _rows_of_pairs_bwd(residual, grad):
+    position, t = residual
+    by_pair = grad[position].reshape(-1, t, grad.shape[-1])
+    return jnp.sum(by_pair, axis=0, dtype=jnp.float32).astype(grad.dtype), \
+        None, None
+
+
+_rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
+
+
+@jax.custom_vjp
+def _unsort(rows, order, position):
+    """``rows[position]``: every pair reads the slot it was sorted to.
+    ``order`` is ``position``'s inverse, so the gradient is
+    ``grad[order]``."""
+    return rows[position]
+
+
+def _unsort_fwd(rows, order, position):
+    return rows[position], order
+
+
+def _unsort_bwd(order, grad):
+    return grad[order], None, None
+
+
+_unsort.defvjp(_unsort_fwd, _unsort_bwd)
+
+
+def router_scores(tokens, router):
+    """sigmoid(tokens . router) in float32 whatever the compute dtype: on
+    a TPU a float32 product at the default precision is one bf16 pass, and
+    a score rounded to 8 bits moves the picks of near-ties."""
+    return jax.nn.sigmoid(jnp.dot(
+        tokens.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+
+
+def route(scores, bias, k: int, scaling: float = 1.0):
+    """scores: (t, e) float32 in (0, 1); bias: (e,).  Picks are the top
+    ``k`` of ``scores + bias``; the weights come from the scores alone,
+    normalised over the picks.  Returns (picks (t, k) int32, weights)."""
+    _, picks = jax.lax.top_k(scores + bias, k)
+    weights = jnp.take_along_axis(scores, picks, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    return picks, weights * scaling
+
+
+def sort_pairs(picks, first: int, held: int):
+    """The (token, pick) pairs in order of expert, those of experts
+    ``first .. first + held - 1`` in front, every other pair behind them.
+    picks: (t, k); pair ``p * t + i`` is token ``i``'s pick ``p`` (picks
+    outermost: a (k, t, h) view of the pairs' rows is then a free reshape,
+    where (t, k, h) pads k = 4 to a tile of 8 and copies).  Returns
+    ``order`` (slot -> pair), ``position`` (pair -> slot) and ``sizes``
+    (held + 1,): pairs of each held expert, then of elsewhere.  Every pair
+    has a slot: none is dropped."""
+    local = picks.T.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    # The inverse permutation by a second sort, and the counts by a
+    # comparison: a scatter of 10^5 scalars runs one by one on a TPU.
+    position = jnp.argsort(order).astype(jnp.int32)
+    sizes = jnp.sum(
+        key[:, None] == jnp.arange(held + 1, dtype=key.dtype), axis=0,
+        dtype=jnp.int32)
+    return order, position, sizes
+
+
+class RoutedExperts(nn.Module):
+    """The dropless routed-expert FFN (module docstring).  x: (b, s, h)."""
+
+    hidden_size: int
+    intermediate_size: int
+    num_experts: int
+    num_experts_per_token: int
+    experts_held: Optional[int] = None  # None: all of them
+    expert_block: int = 0
+    routed_scaling_factor: float = 1.0
+    dtype: jnp.dtype = jnp.bfloat16
+    param_dtype: jnp.dtype = jnp.float32
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, h = x.shape
+        e, k, held = self.num_experts, self.num_experts_per_token, self.held
+        first = self.expert_block * held
+        if first + held > e:
+            raise ValueError(
+                f"block {self.expert_block} of {held} experts lies past "
+                f"the router's {e}")
+        m = self.intermediate_size
+        tokens = x.reshape(b * s, h)
+
+        def weights(name, init, shape, axes):
+            return self.param(
+                name, param_with_axes(init, axes), shape, self.param_dtype)
+
+        with jax.named_scope("moe/router"):
+            router = weights(
+                "router", nn.initializers.normal(stddev=0.02), (h, e),
+                ("embed", "router"))
+            # Selection only: no gradient reaches it, and the step has no
+            # other rule for it (the aux-loss-free update is a recipe the
+            # published config does not give).
+            bias = weights(
+                "expert_bias", nn.initializers.zeros_init(), (e,),
+                ("router",))
+            picks, pick_weights = route(
+                router_scores(tokens, router), bias.astype(jnp.float32), k,
+                self.routed_scaling_factor)
+        with jax.named_scope("moe/sort"):
+            order, position, sizes = sort_pairs(picks, first, held)
+            self.sow("intermediates", "moe_load", sizes)
+            # For a comparison of routing (scripts/logits_check.py); the
+            # step fetches the load only, so this costs a step nothing.
+            self.sow("intermediates", "moe_picks", picks)
+            rows = _rows_of_pairs(tokens, order, position)
+        lecun = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                             batch_axis=(0,))
+        w_gate = weights("gate_proj", lecun, (held, h, m),
+                         ("expert", "embed", "mlp"))
+        w_up = weights("up_proj", lecun, (held, h, m),
+                       ("expert", "embed", "mlp"))
+        w_down = weights("down_proj", lecun, (held, m, h),
+                         ("expert", "mlp", "embed"))
+        with jax.named_scope("moe/gate_up"):
+            gate_up = grouped_matmul(
+                rows,
+                jnp.concatenate(
+                    [w_gate.astype(self.dtype), w_up.astype(self.dtype)], -1),
+                sizes[:held])
+            act = nn.silu(gate_up[:, :m]) * gate_up[:, m:]
+        with jax.named_scope("moe/down"):
+            out = grouped_matmul(act, w_down.astype(self.dtype), sizes[:held])
+        with jax.named_scope("moe/combine"):
+            # A pair sorted behind the held experts reads a zero row.
+            by_pair = _unsort(out, order, position).reshape(k, b * s, h)
+            out = jnp.sum(
+                by_pair.astype(jnp.float32) * pick_weights.T[..., None],
+                axis=0,
+            ).astype(self.dtype)
+        return with_constraint(
+            out.reshape(b, s, h), ("batch", "seq", "act_embed"))
+
+
+def collect_moe_load(intermediates):
+    """The sown ``moe_load`` leaves by layer, ``{layer name: (held + 1,)
+    int32}``; empty where the model has no dropless layer."""
+    loads = {}
+    if not intermediates:
+        return loads
+    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
+        names = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
+        if "moe_load" in names:
+            loads["/".join(names[:names.index("moe_load")])] = leaf
+    return loads
 
 
 def collect_moe_losses(intermediates) -> jnp.ndarray:

@@ -102,12 +102,14 @@ def ssd_chunked(x, dt, A, B, C, chunk: int):
     return y.reshape(b, s, h, p).astype(dtype)
 
 
-def causal_conv1d(x, weight, bias):
+def causal_conv1d(x, weight, bias=None):
     """Depthwise causal convolution along the sequence.  x: (b, s, ch);
     weight: (width, ch), its last row multiplying the current token;
-    bias: (ch,).  float32 inside, ``x.dtype`` out."""
+    bias: (ch,) or None.  float32 inside, ``x.dtype`` out."""
     width, s = weight.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
     weight = weight.astype(jnp.float32)
     y = sum(padded[:, k:k + s] * weight[k] for k in range(width))
-    return (y + bias.astype(jnp.float32)).astype(x.dtype)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(x.dtype)

@@ -21,6 +21,9 @@ Logical axes used by the model zoo:
     ssm_heads — state-space heads (dt, A, D)
     ssm_state — state width (B, C: one group, shared by all heads)
     conv_width— taps of the depthwise causal convolution
+    conv_inner— gated short convolution's width (B, C, x and the taps)
+    router    — the router's outputs, one an expert of the whole model
+                (never sharded: every chip routes over all of them)
 """
 
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -59,6 +62,8 @@ _COMMON = (
     ("patch_dim", None),
     # Taps of the state-space mixer's causal convolution (never sharded).
     ("conv_width", None),
+    # The routed-expert layer's router and selection bias (never sharded).
+    ("router", None),
 )
 
 # Pure data parallel: params replicated, batch split on dp(+fsdp).
@@ -81,6 +86,8 @@ DP_RULES: Rules = (
     ("ssm_inner", None),
     ("ssm_heads", None),
     ("ssm_state", None),
+    ("act_conv_inner", None),
+    ("conv_inner", None),
 ) + _ACT_REPLICATED + _COMMON
 
 # FSDP/ZeRO-3 analog: shard every weight's embed dim over fsdp; params are
@@ -105,6 +112,8 @@ FSDP_RULES: Rules = (
     ("ssm_inner", None),
     ("ssm_heads", None),
     ("ssm_state", None),
+    ("act_conv_inner", None),
+    ("conv_inner", None),
 ) + _ACT_REPLICATED + _COMMON
 
 # Megatron-style TP composed with FSDP (+ optional sequence parallel):
@@ -132,6 +141,10 @@ FSDP_TP_RULES: Rules = (
     ("ssm_inner", "tp"),
     ("ssm_heads", "tp"),
     ("ssm_state", None),
+    # Gated short convolution: depthwise, so its width shards like an
+    # MLP's (column-parallel in, row-parallel out).
+    ("act_conv_inner", "tp"),
+    ("conv_inner", "tp"),
 ) + _ACT_REPLICATED + _COMMON
 
 PRESET_RULES: Dict[str, Rules] = {

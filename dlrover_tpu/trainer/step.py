@@ -212,34 +212,37 @@ def make_train_step(
                 loss = fused_ce_loss(fused_cfg, params, logits, batch)
             else:
                 loss = loss_fn(logits, batch)
-            # MoE load-balancing/z losses arrive sown in intermediates.
-            from dlrover_tpu.models.moe import collect_moe_losses
-
-            loss = loss + collect_moe_losses(
-                aux_vars.get("intermediates", {})
+            # MoE load-balancing/z losses arrive sown in intermediates, as
+            # does the dropless layer's load (pairs routed to each held
+            # expert and to elsewhere, by layer): a step metric.
+            from dlrover_tpu.models.moe import (
+                collect_moe_load,
+                collect_moe_losses,
             )
-            if not extra_keys:
-                return loss
-            return loss, {k: aux_vars[k] for k in extra_keys}
 
+            sown = aux_vars.get("intermediates", {})
+            loss = loss + collect_moe_losses(sown)
+            return loss, (
+                {k: aux_vars[k] for k in extra_keys}, collect_moe_load(sown))
+
+        if gradient_fn_factory is not None:
+            (loss, ), grads = gradient_fn_factory(
+                lambda p: compute_loss(p)[0])(full_params)
+            new_vars, moe_load = {}, {}
+        else:
+            (loss, (new_vars, moe_load)), grads = jax.value_and_grad(
+                compute_loss, has_aux=True)(full_params)
+        if wus_plan is not None:
+            # The reduce-scatter point: grads leave their base layout for
+            # the 1/N-scattered one, so the optimizer below runs on each
+            # replica's shard of grads + state.
+            grads = wus_plan.scatter_grads(grads)
         if extra_keys:
-            (loss, new_vars), grads = jax.value_and_grad(
-                compute_loss, has_aux=True
-            )(full_params)
-            if wus_plan is not None:
-                grads = wus_plan.scatter_grads(grads)
             new_state = state.apply_gradients(
                 grads=grads,
                 variables=jax.lax.stop_gradient(new_vars),
             )
         else:
-            make_grad = gradient_fn_factory or _value_and_grad
-            (loss, ), grads = make_grad(compute_loss)(full_params)
-            if wus_plan is not None:
-                # The reduce-scatter point: grads leave their base
-                # layout for the 1/N-scattered one, so the optimizer
-                # below runs on each replica's shard of grads + state.
-                grads = wus_plan.scatter_grads(grads)
             new_state = state.apply_gradients(grads=grads)
         gnorm = optax.global_norm(grads)
         metrics = {
@@ -247,16 +250,9 @@ def make_train_step(
             "grad_norm": gnorm,
             "step": new_state.step,
         }
+        if moe_load:
+            metrics["moe_load"] = moe_load
         return new_state, metrics
-
-    def _value_and_grad(f):
-        vg = jax.value_and_grad(f)
-
-        def wrapped(params):
-            loss, grads = vg(params)
-            return (loss,), grads
-
-        return wrapped
 
     jitted = jax.jit(
         _step,

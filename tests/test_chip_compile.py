@@ -98,6 +98,22 @@ def _dequant(sds):
     )
 
 
+def _grouped_matmul_case(m, k, n, groups):
+    """fwd + both backward products of ``ops/grouped_matmul.py`` at one of
+    lfm2moe.steady's shapes (the pairs buffer of 4 x 8192 tokens x top-4)."""
+    def build(sds):
+        from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+
+        def loss(lhs, rhs, sizes):
+            out = grouped_matmul(lhs, rhs, sizes, interpret=False)
+            return jnp.sum(out.astype(jnp.float32))
+
+        return jax.grad(loss, (0, 1)), [
+            sds((m, k), jnp.bfloat16), sds((groups, k, n), jnp.bfloat16),
+            sds((groups,), jnp.int32)]
+    return build
+
+
 def _fused_adam(sds):
     from dlrover_tpu.ops.quantize_pallas import fused_adam8bit_update
 
@@ -124,6 +140,8 @@ KERNELS = {
         "flash", 2, 2048, 12, 12, 64, segmented=True,
         block_q=512, block_kv=512,
     ),
+    "grouped_matmul_gate_up": _grouped_matmul_case(131072, 2048, 3584, 8),
+    "grouped_matmul_down": _grouped_matmul_case(131072, 1792, 2048, 8),
     "quantize_blockwise": _quant,
     "dequantize_blockwise_log": _dequant,
     "fused_adam8bit_update": _fused_adam,
