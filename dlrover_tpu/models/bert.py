@@ -21,7 +21,8 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models.gpt_neox import LayerNorm
 from dlrover_tpu.models.layers import BiasedGeluMLP, BiasedSelfAttention
-from dlrover_tpu.models.llama import param_with_axes, with_constraint
+from dlrover_tpu.models.llama import param_with_axes
+from dlrover_tpu.parallel.sharding import constrain
 
 Dtype = Any
 
@@ -79,7 +80,7 @@ class BertBlock(nn.Module):
             cfg.layer_norm_eps, cfg.dtype, cfg.param_dtype,
             name="output_norm",
         )(x + h)
-        return with_constraint(x, ("batch", "seq", "act_embed")), None
+        return constrain(x, ("batch", "seq", "act_embed")), None
 
 
 class BertModel(nn.Module):
@@ -151,7 +152,7 @@ class BertModel(nn.Module):
             cfg.layer_norm_eps, cfg.dtype, cfg.param_dtype,
             name="embeddings_norm",
         )(x)
-        x = with_constraint(x, ("batch", "seq", "act_embed"))
+        x = constrain(x, ("batch", "seq", "act_embed"))
 
         if cfg.scan_layers:
             x, _ = nn.scan(
@@ -196,7 +197,7 @@ class BertModel(nn.Module):
         )(h)
         if cfg.logits_f32_output:
             logits = logits.astype(jnp.float32)
-        return with_constraint(logits, ("batch", "seq", "act_vocab"))
+        return constrain(logits, ("batch", "seq", "act_vocab"))
 
 
 def mlm_loss(logits, labels, mlm_mask):

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models.gpt_neox import LayerNorm
 from dlrover_tpu.models.layers import BiasedGeluMLP, BiasedSelfAttention
-from dlrover_tpu.models.llama import param_with_axes, with_constraint
+from dlrover_tpu.models.llama import param_with_axes
+from dlrover_tpu.parallel.sharding import constrain
 
 Dtype = Any
 
@@ -86,7 +87,7 @@ class _TowerBlock(nn.Module):
             dtype=self.dtype, param_dtype=self.param_dtype, name="mlp",
         )(h)
         x = x + h
-        return with_constraint(x, ("batch", "seq", "act_embed"))
+        return constrain(x, ("batch", "seq", "act_embed"))
 
 
 class VisionTower(nn.Module):
@@ -137,7 +138,7 @@ class VisionTower(nn.Module):
             cfg.param_dtype,
         )
         x = x + pos.astype(cfg.dtype)[None]
-        x = with_constraint(x, ("batch", "seq", "act_embed"))
+        x = constrain(x, ("batch", "seq", "act_embed"))
         for i in range(cfg.vision_layers):
             x = _TowerBlock(
                 cfg.vision_hidden, cfg.vision_heads, False,
@@ -183,7 +184,7 @@ class TextTower(nn.Module):
             cfg.param_dtype,
         )
         x = embed.astype(cfg.dtype)[input_ids] + pos.astype(cfg.dtype)[:s][None]
-        x = with_constraint(x, ("batch", "seq", "act_embed"))
+        x = constrain(x, ("batch", "seq", "act_embed"))
         for i in range(cfg.text_layers):
             x = _TowerBlock(
                 cfg.text_hidden, cfg.text_heads, True,

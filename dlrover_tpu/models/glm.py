@@ -25,8 +25,8 @@ from dlrover_tpu.models.llama import (
     cross_entropy_loss,
     param_with_axes,
     remat_policy,
-    with_constraint,
 )
+from dlrover_tpu.parallel.sharding import constrain
 
 Dtype = Any
 
@@ -118,9 +118,9 @@ class GLMAttention(nn.Module):
         q = proj("q_proj", cfg.num_heads, ("embed", "heads", "head_dim"))
         k = proj("k_proj", cfg.num_kv_heads, ("embed", "kv_heads", "head_dim"))
         v = proj("v_proj", cfg.num_kv_heads, ("embed", "kv_heads", "head_dim"))
-        q = with_constraint(q, ("batch", "seq", "act_heads", "act_head_dim"))
-        k = with_constraint(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
-        v = with_constraint(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        q = constrain(q, ("batch", "seq", "act_heads", "act_head_dim"))
+        k = constrain(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        v = constrain(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
         q, k = _rope(q, k, positions, d, cfg.rope_theta)
         pl_arr = jnp.asarray(prefix_len)
         if pl_arr.ndim == 2:
@@ -135,7 +135,7 @@ class GLMAttention(nn.Module):
         else:
             mask = prefix_lm_mask(x.shape[1], prefix_len)
             out = _masked_attention(q, k, v, mask)
-        out = with_constraint(
+        out = constrain(
             out, ("batch", "seq", "act_heads", "act_head_dim")
         )
         out = nn.DenseGeneral(
@@ -149,7 +149,7 @@ class GLMAttention(nn.Module):
             ),
             name="o_proj",
         )(out)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 class GLMBlock(nn.Module):
@@ -168,7 +168,7 @@ class GLMBlock(nn.Module):
             cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype, name="post_norm"
         )(x)
         x = x + MLP(cfg, name="mlp")(h)
-        return with_constraint(x, ("batch", "seq", "act_embed")), None
+        return constrain(x, ("batch", "seq", "act_embed")), None
 
 
 class GLMModel(nn.Module):
@@ -204,7 +204,7 @@ class GLMModel(nn.Module):
             cfg.param_dtype,
         )
         x = embed.astype(cfg.dtype)[input_ids]
-        x = with_constraint(x, ("batch", "seq", "act_embed"))
+        x = constrain(x, ("batch", "seq", "act_embed"))
 
         block_cls = GLMBlock
         if cfg.remat_policy != "none":
@@ -243,7 +243,7 @@ class GLMModel(nn.Module):
         )(x)
         if cfg.logits_f32_output:
             logits = logits.astype(jnp.float32)
-        return with_constraint(logits, ("batch", "seq", "act_vocab"))
+        return constrain(logits, ("batch", "seq", "act_vocab"))
 
 
 glm_lm_loss = cross_entropy_loss

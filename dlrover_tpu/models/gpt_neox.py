@@ -28,8 +28,8 @@ from dlrover_tpu.models.llama import (
     cross_entropy_loss,
     dot_product_attention,
     param_with_axes,
-    with_constraint,
 )
+from dlrover_tpu.parallel.sharding import constrain
 
 Dtype = Any
 
@@ -137,9 +137,9 @@ class NeoXAttention(nn.Module):
         q, k, v = (
             qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :],
         )
-        q = with_constraint(q, ("batch", "seq", "act_heads", "act_head_dim"))
-        k = with_constraint(k, ("batch", "seq", "act_heads", "act_head_dim"))
-        v = with_constraint(v, ("batch", "seq", "act_heads", "act_head_dim"))
+        q = constrain(q, ("batch", "seq", "act_heads", "act_head_dim"))
+        k = constrain(k, ("batch", "seq", "act_heads", "act_head_dim"))
+        v = constrain(v, ("batch", "seq", "act_heads", "act_head_dim"))
         q, k = _partial_rope(
             q, k, positions, d, cfg.rotary_pct, cfg.rope_theta
         )
@@ -158,7 +158,7 @@ class NeoXAttention(nn.Module):
             ),
             name="o_proj",
         )(out)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 class NeoXMLP(nn.Module):
@@ -197,7 +197,7 @@ class NeoXBlock(nn.Module):
             )
             + NeoXMLP(cfg, name="mlp")(mlp_in)
         )
-        return with_constraint(x, ("batch", "seq", "act_embed")), None
+        return constrain(x, ("batch", "seq", "act_embed")), None
 
 
 class GPTNeoXModel(nn.Module):
@@ -220,7 +220,7 @@ class GPTNeoXModel(nn.Module):
             cfg.param_dtype,
         )
         x = embed.astype(cfg.dtype)[input_ids]
-        x = with_constraint(x, ("batch", "seq", "act_embed"))
+        x = constrain(x, ("batch", "seq", "act_embed"))
 
         if cfg.scan_layers:
             x, _ = nn.scan(
@@ -252,7 +252,7 @@ class GPTNeoXModel(nn.Module):
         )(x)
         if cfg.logits_f32_output:
             logits = logits.astype(jnp.float32)
-        return with_constraint(logits, ("batch", "seq", "act_vocab"))
+        return constrain(logits, ("batch", "seq", "act_vocab"))
 
 
 neox_lm_loss = cross_entropy_loss

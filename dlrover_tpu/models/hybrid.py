@@ -69,11 +69,11 @@ from dlrover_tpu.models.llama import (
     _rope,
     param_with_axes,
     remat_policy,
-    with_constraint,
 )
 from dlrover_tpu.models.moe import RoutedExperts
 from dlrover_tpu.ops import grouped_matmul, ssd
 from dlrover_tpu.ops.splash_attention import splash_attention_gqa
+from dlrover_tpu.parallel.sharding import constrain
 
 LAYER_KINDS = ("mamba", "attention", "conv", "full_attention")
 
@@ -245,8 +245,8 @@ class MambaMixer(nn.Module):
             B = project("b_proj", cfg.ssm_state, "ssm_state")
             C = project("c_proj", cfg.ssm_state, "ssm_state")
             dt = project("dt_proj", heads, "ssm_heads")
-        z = with_constraint(z, ("batch", "seq", "act_ssm_inner"))
-        x = with_constraint(x, ("batch", "seq", "act_ssm_inner"))
+        z = constrain(z, ("batch", "seq", "act_ssm_inner"))
+        x = constrain(x, ("batch", "seq", "act_ssm_inner"))
         with jax.named_scope("mamba/conv"):
             x = conv("x", x, "ssm_inner")
             B = conv("b", B, "ssm_state")
@@ -269,7 +269,7 @@ class MambaMixer(nn.Module):
             y = y * jax.lax.rsqrt(
                 jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_norm_eps)
             y = (y * scale.astype(jnp.float32)).astype(cfg.dtype)
-        y = with_constraint(y, ("batch", "seq", "act_ssm_inner"))
+        y = constrain(y, ("batch", "seq", "act_ssm_inner"))
         with jax.named_scope("mamba/out_proj"):
             out = nn.DenseGeneral(
                 features=cfg.hidden_size, dtype=cfg.dtype,
@@ -278,7 +278,7 @@ class MambaMixer(nn.Module):
                     nn.initializers.lecun_normal(), ("ssm_inner", "embed")),
                 name="out_proj",
             )(y)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 class ShortConv(nn.Module):
@@ -302,7 +302,7 @@ class ShortConv(nn.Module):
 
         with jax.named_scope("conv/in_proj"):
             B, C, x = (
-                with_constraint(
+                constrain(
                     project(name, ("embed", "conv_inner"))(h),
                     ("batch", "seq", "act_conv_inner"))
                 for name in ("b_proj", "c_proj", "x_proj"))
@@ -314,10 +314,10 @@ class ShortConv(nn.Module):
                 (cfg.conv_width, inner), cfg.param_dtype,
             )
             y = C * ssd.causal_conv1d(B * x, taps)
-        y = with_constraint(y, ("batch", "seq", "act_conv_inner"))
+        y = constrain(y, ("batch", "seq", "act_conv_inner"))
         with jax.named_scope("conv/out_proj"):
             out = project("out_proj", ("conv_inner", "embed"))(y)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 class HybridAttention(nn.Module):
@@ -367,9 +367,9 @@ class HybridAttention(nn.Module):
             if positions is None:
                 positions = jnp.arange(h.shape[1])[None]
             q, k = _rope(q, k, positions, d, cfg.rope_theta)
-        q = with_constraint(q, ("batch", "seq", "act_heads", "act_head_dim"))
-        k = with_constraint(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
-        v = with_constraint(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        q = constrain(q, ("batch", "seq", "act_heads", "act_head_dim"))
+        k = constrain(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        v = constrain(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
         if cfg.attention_impl == "splash":
             out = splash_attention_gqa(
                 q, k, v, segment_ids=segment_ids, scale=scale)
@@ -381,7 +381,7 @@ class HybridAttention(nn.Module):
                     segment_ids[:, None, :, None]
                     == segment_ids[:, None, None, :])
             out = _masked_attention(q, k, v, mask, scale=scale)
-        out = with_constraint(
+        out = constrain(
             out, ("batch", "seq", "act_heads", "act_head_dim"))
         out = nn.DenseGeneral(
             features=cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
@@ -390,7 +390,7 @@ class HybridAttention(nn.Module):
                 nn.initializers.lecun_normal(), ("heads", "head_dim", "embed")),
             name="o_proj",
         )(out)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 def routed_experts(cfg: HybridConfig, **kw) -> RoutedExperts:
@@ -433,7 +433,7 @@ class HybridBlock(nn.Module):
             with jax.named_scope("hybrid/mlp"):
                 ffn = MLP(cfg, name="mlp")(h)
         x = x + cfg.residual_multiplier * ffn
-        return with_constraint(x, ("batch", "seq", "act_embed"))
+        return constrain(x, ("batch", "seq", "act_embed"))
 
 
 class HybridModel(nn.Module):
@@ -488,7 +488,7 @@ class HybridModel(nn.Module):
             )
             x = embed.astype(cfg.dtype)[input_ids] * jnp.asarray(
                 cfg.embedding_multiplier, cfg.dtype)
-            x = with_constraint(x, ("batch", "seq", "act_embed"))
+            x = constrain(x, ("batch", "seq", "act_embed"))
             block_cls = HybridBlock
             if cfg.remat_policy != "none":
                 block_cls = nn.remat(
@@ -503,4 +503,4 @@ class HybridModel(nn.Module):
                             name="final_norm")(x)
                 logits = jnp.einsum("bse,ve->bsv", x, embed.astype(cfg.dtype))
                 logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
-        return with_constraint(logits, ("batch", "seq", "act_vocab"))
+        return constrain(logits, ("batch", "seq", "act_vocab"))

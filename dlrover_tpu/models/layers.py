@@ -9,11 +9,8 @@ from typing import Any
 import flax.linen as nn
 import jax.numpy as jnp
 
-from dlrover_tpu.models.llama import (
-    _masked_attention,
-    param_with_axes,
-    with_constraint,
-)
+from dlrover_tpu.models.llama import _masked_attention, param_with_axes
+from dlrover_tpu.parallel.sharding import constrain
 
 Dtype = Any
 
@@ -51,9 +48,9 @@ class BiasedSelfAttention(nn.Module):
         q = proj("q_proj", ("embed", "heads", "head_dim"))
         k = proj("k_proj", ("embed", "heads", "head_dim"))
         v = proj("v_proj", ("embed", "heads", "head_dim"))
-        q = with_constraint(q, ("batch", "seq", "act_heads", "act_head_dim"))
-        k = with_constraint(k, ("batch", "seq", "act_heads", "act_head_dim"))
-        v = with_constraint(v, ("batch", "seq", "act_heads", "act_head_dim"))
+        q = constrain(q, ("batch", "seq", "act_heads", "act_head_dim"))
+        k = constrain(k, ("batch", "seq", "act_heads", "act_head_dim"))
+        v = constrain(v, ("batch", "seq", "act_heads", "act_head_dim"))
         s = x.shape[1]
         if self.causal:
             mask = jnp.tril(jnp.ones((s, s), dtype=bool))[None, None]
@@ -83,7 +80,7 @@ class BiasedSelfAttention(nn.Module):
             ),
             name="o_proj",
         )(out)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 class BiasedGeluMLP(nn.Module):
@@ -108,7 +105,7 @@ class BiasedGeluMLP(nn.Module):
             name="up_proj",
         )(x)
         h = nn.gelu(h)
-        h = with_constraint(h, ("batch", "seq", "act_mlp"))
+        h = constrain(h, ("batch", "seq", "act_mlp"))
         out = nn.DenseGeneral(
             features=self.hidden_size,
             dtype=self.dtype,
@@ -122,4 +119,4 @@ class BiasedGeluMLP(nn.Module):
             ),
             name="down_proj",
         )(h)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))

@@ -24,10 +24,11 @@ import jax
 import jax.numpy as jnp
 from flax.linen import partitioning as nn_partitioning
 
+from dlrover_tpu.parallel.sharding import constrain
+
 Dtype = Any
 
 param_with_axes = nn.with_logical_partitioning
-with_constraint = nn.with_logical_constraint
 
 
 def _fp8_kwargs(cfg):
@@ -338,9 +339,9 @@ class Attention(nn.Module):
             ),
             name="v_proj",
         )(x)
-        q = with_constraint(q, ("batch", "seq", "act_heads", "act_head_dim"))
-        k = with_constraint(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
-        v = with_constraint(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        q = constrain(q, ("batch", "seq", "act_heads", "act_head_dim"))
+        k = constrain(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
+        v = constrain(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
         q, k = _rope(q, k, positions, d, cfg.rope_theta)
 
         if cfg.decode:
@@ -393,7 +394,7 @@ class Attention(nn.Module):
                 out = dot_product_attention(q, k, v, cfg, segment_ids)
             else:
                 out = attn_fn(q, k, v, segment_ids=segment_ids)
-        out = with_constraint(out, ("batch", "seq", "act_heads", "act_head_dim"))
+        out = constrain(out, ("batch", "seq", "act_heads", "act_head_dim"))
         out = nn.DenseGeneral(
             features=cfg.hidden_size,
             axis=(-2, -1),
@@ -406,7 +407,7 @@ class Attention(nn.Module):
             ),
             name="o_proj",
         )(out)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 class MLP(nn.Module):
@@ -437,7 +438,7 @@ class MLP(nn.Module):
             name="up_proj",
         )(x)
         h = nn.silu(gate) * up
-        h = with_constraint(h, ("batch", "seq", "act_mlp"))
+        h = constrain(h, ("batch", "seq", "act_mlp"))
         out = dense(
             features=cfg.hidden_size,
             kernel_init=param_with_axes(
@@ -445,7 +446,7 @@ class MLP(nn.Module):
             ),
             name="down_proj",
         )(h)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 class DecoderBlock(nn.Module):
@@ -478,7 +479,7 @@ class DecoderBlock(nn.Module):
             )(h)
         else:
             x = x + MLP(cfg, name="mlp")(h)
-        return with_constraint(x, ("batch", "seq", "act_embed")), None
+        return constrain(x, ("batch", "seq", "act_embed")), None
 
 
 _REMAT_POLICIES = {
@@ -522,7 +523,7 @@ class LlamaModel(nn.Module):
             cfg.param_dtype,
         )
         x = embed.astype(cfg.dtype)[input_ids]
-        x = with_constraint(x, ("batch", "seq", "act_embed"))
+        x = constrain(x, ("batch", "seq", "act_embed"))
 
         block_cls = DecoderBlock
         if cfg.remat_policy != "none":
@@ -596,7 +597,7 @@ class LlamaModel(nn.Module):
                 )(jnp.zeros((1, 1, cfg.hidden_size), cfg.dtype))
             if cfg.mup_readout_mult != 1.0:
                 x = x / cfg.mup_readout_mult
-            return with_constraint(x, ("batch", "seq", "act_embed"))
+            return constrain(x, ("batch", "seq", "act_embed"))
         if cfg.tie_embeddings:
             logits = jnp.einsum("bse,ve->bsv", x, embed.astype(cfg.dtype))
         else:
@@ -619,7 +620,7 @@ class LlamaModel(nn.Module):
             logits = logits / cfg.mup_readout_mult
         if cfg.logits_f32_output:
             logits = logits.astype(jnp.float32)
-        return with_constraint(logits, ("batch", "seq", "act_vocab"))
+        return constrain(logits, ("batch", "seq", "act_vocab"))
 
 
 def fused_ce_loss(cfg: LlamaConfig, params, hidden, batch):

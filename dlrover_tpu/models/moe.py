@@ -43,9 +43,9 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.ops.grouped_matmul import grouped_matmul
+from dlrover_tpu.parallel.sharding import constrain
 
 param_with_axes = nn.with_logical_partitioning
-with_constraint = nn.with_logical_constraint
 
 
 def _top_k_mask(router_probs, k: int):
@@ -142,7 +142,7 @@ class MoEMLP(nn.Module):
         # -- dispatch -> expert FFN -> combine --------------------------
         # (b, s, e, c) x (b, s, h) -> (e, b, c, h): the all-to-all under ep.
         expert_in = jnp.einsum("bsec,bsh->ebch", dispatch, x)
-        expert_in = with_constraint(
+        expert_in = constrain(
             expert_in, ("act_expert", "batch", "act_capacity", "act_embed")
         )
 
@@ -163,16 +163,16 @@ class MoEMLP(nn.Module):
         gate = jnp.einsum("ebch,ehm->ebcm", expert_in, cast(w_gate))
         up = jnp.einsum("ebch,ehm->ebcm", expert_in, cast(w_up))
         act = nn.silu(gate) * up
-        act = with_constraint(
+        act = constrain(
             act, ("act_expert", "batch", "act_capacity", "act_mlp")
         )
         expert_out = jnp.einsum("ebcm,emh->ebch", act, cast(w_down))
-        expert_out = with_constraint(
+        expert_out = constrain(
             expert_out, ("act_expert", "batch", "act_capacity", "act_embed")
         )
 
         out = jnp.einsum("bsec,ebch->bsh", combine, expert_out)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))
 
 
 @jax.custom_vjp
@@ -336,7 +336,7 @@ class RoutedExperts(nn.Module):
                 by_pair.astype(jnp.float32) * pick_weights.T[..., None],
                 axis=0,
             ).astype(self.dtype)
-        return with_constraint(
+        return constrain(
             out.reshape(b, s, h), ("batch", "seq", "act_embed"))
 
 

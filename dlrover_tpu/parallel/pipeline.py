@@ -56,7 +56,7 @@ from typing import Any, Optional, Type
 import flax.linen as nn
 import jax.numpy as jnp
 
-with_constraint = nn.with_logical_constraint
+from dlrover_tpu.parallel.sharding import constrain
 
 
 class Pipeline(nn.Module):
@@ -132,8 +132,8 @@ class Pipeline(nn.Module):
         else:
             seg_mb = segment_ids.reshape(M, mb, s)
 
-        def constrain(buf, trailing):
-            return with_constraint(buf, ("stage",) + trailing)
+        def constrain_stages(buf, trailing):
+            return constrain(buf, ("stage",) + trailing)
 
         state = jnp.zeros((S, mb, s, h), x.dtype)
         state_pos = jnp.zeros((S, mb, s), pos_mb.dtype)
@@ -150,9 +150,9 @@ class Pipeline(nn.Module):
                 # into slot 0.  Zero it — otherwise that dead computation
                 # leaks gradients through sown MoE losses.
                 state = state.at[0].set(jnp.zeros((mb, s, h), x.dtype))
-            state = constrain(state, ("batch", "seq", "act_embed"))
+            state = constrain_stages(state, ("batch", "seq", "act_embed"))
             y, _ = stages(state, state_pos, state_seg)
-            y = constrain(y, ("batch", "seq", "act_embed"))
+            y = constrain_stages(y, ("batch", "seq", "act_embed"))
             if t >= S - 1:  # microbatch t-(S-1) exits the last stage
                 outputs.append(y[-1])
             # Hand each stage's output to its successor: a CollectivePermute
@@ -162,4 +162,4 @@ class Pipeline(nn.Module):
             state_seg = jnp.roll(state_seg, 1, axis=0)
 
         out = jnp.stack(outputs, axis=0).reshape(b, s, h)
-        return with_constraint(out, ("batch", "seq", "act_embed"))
+        return constrain(out, ("batch", "seq", "act_embed"))

@@ -26,10 +26,16 @@ Logical axes used by the model zoo:
                 (never sharded: every chip routes over all of them)
 """
 
+import contextlib
+import contextvars
+import math
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import flax.linen as nn
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from dlrover_tpu.parallel.mesh import current_mesh
 
 Rules = Tuple[Tuple[str, Union[str, Tuple[str, ...], None]], ...]
 
@@ -190,12 +196,61 @@ def tree_to_shardings(logical_tree, rules: Rules, mesh: Mesh):
     )
 
 
-def with_logical_constraint(x, logical_axes: Sequence[Optional[str]],
-                            rules: Optional[Rules], mesh: Optional[Mesh]):
-    """Constrain an activation's sharding inside jit (no-op without mesh)."""
-    if rules is None or mesh is None:
+# Constraints `constrain` put into the program while a trace ran under
+# `count_constraints`; the train step's `compile` span reports the count.
+_CONSTRAINT_COUNT: contextvars.ContextVar[Optional[List[int]]] = (
+    contextvars.ContextVar("dlrover_tpu_constraint_count", default=None)
+)
+
+
+@contextlib.contextmanager
+def count_constraints():
+    """Yields a one-element list that holds how many constraints
+    ``constrain`` applied inside the block."""
+    count = [0]
+    token = _CONSTRAINT_COUNT.set(count)
+    try:
+        yield count
+    finally:
+        _CONSTRAINT_COUNT.reset(token)
+
+
+def constrain(x, logical_axes: Sequence[Optional[str]]):
+    """Hold an activation to the sharding its logical axes name: the one
+    call every model makes on q/k/v, the MLP's hidden, the residual
+    stream and the logits, so that GSPMD moves the weights to the
+    activations and not the activations to the weights.
+
+    The rule table is the one in scope (``nn_partitioning.axis_rules``)
+    and the mesh is ``current_mesh()``; the steps set both while they
+    trace.  With no rules, no mesh or a mesh of one device there is
+    nothing to hold and ``x`` itself is returned: nothing is added to
+    the program.  A dimension its mesh axes do not divide raises; it is
+    not skipped.
+    """
+    rules = nn.get_logical_axis_rules()
+    mesh = current_mesh()
+    if not rules or mesh is None or mesh.size == 1:
         return x
     spec = logical_to_spec(logical_axes, rules)
+    if len(spec) != x.ndim:
+        raise ValueError(
+            f"constrain: logical axes {tuple(logical_axes)} name "
+            f"{len(spec)} dimensions, the array has shape {x.shape}"
+        )
+    for dim, entry in zip(x.shape, spec):
+        axes = (entry,) if isinstance(entry, str) else tuple(entry or ())
+        ways = math.prod(mesh.shape[a] for a in axes)
+        if dim % ways:
+            raise ValueError(
+                f"constrain: logical axes {tuple(logical_axes)} of shape "
+                f"{x.shape} put mesh axes {axes} ({ways} ways) on a "
+                f"dimension of {dim}, which they do not divide "
+                f"(mesh {dict(mesh.shape)})"
+            )
+    count = _CONSTRAINT_COUNT.get()
+    if count is not None:
+        count[0] += 1
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 

@@ -18,7 +18,6 @@ from dlrover_tpu.models.llama import (
 )
 
 param_with_axes = nn.with_logical_partitioning
-with_constraint = nn.with_logical_constraint
 
 
 def tiny_actor_factory():

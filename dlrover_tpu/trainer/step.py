@@ -33,6 +33,7 @@ from dlrover_tpu.parallel import wus
 from dlrover_tpu.parallel.mesh import use_mesh
 from dlrover_tpu.parallel.sharding import (
     Rules,
+    count_constraints,
     logical_to_spec,
     replica_axes_from_rules,
 )
@@ -264,9 +265,11 @@ def make_train_step(
     compiled = [False]
 
     def step_with_rules(state, batch):
-        # Activation with_logical_constraint (and ring/ulysses shard_map
-        # regions) need the rule table + mesh in scope at trace time;
-        # afterwards the jit cache makes this context free.
+        # The models' activation constraints (`sharding.constrain`) and
+        # the ring/ulysses shard_map regions read the rule table and the
+        # mesh at trace time: `constrain` takes the mesh from
+        # `current_mesh()`, which `use_mesh` sets.  Afterwards the jit
+        # cache makes this context free.
         with nn_partitioning.axis_rules(list(rules)), use_mesh(mesh):
             if not compiled[0]:
                 # First call pays trace+XLA compile: a telemetry span so
@@ -276,8 +279,11 @@ def make_train_step(
                 compiled[0] = True
                 from dlrover_tpu.telemetry.spans import span
 
-                with span("compile", what="train_step"):
-                    return jitted(state, batch)
+                with span("compile", what="train_step") as end, \
+                        count_constraints() as applied:
+                    out = jitted(state, batch)
+                    end["activation_constraints"] = applied[0]
+                    return out
             return jitted(state, batch)
 
     step_with_rules.jitted = jitted

@@ -162,8 +162,8 @@ def _make_state(mesh_cfg, devices, seed=0):
     cfg = LlamaConfig.tiny(dtype=jnp.float32)
     model = LlamaModel(cfg)
     batch = {
-        "input_ids": jnp.zeros((4, 16), jnp.int32),
-        "labels": jnp.zeros((4, 16), jnp.int32),
+        "input_ids": jnp.zeros((8, 16), jnp.int32),
+        "labels": jnp.zeros((8, 16), jnp.int32),
     }
     state, shardings = create_sharded_state(
         model, optax.adam(1e-3), mesh, rules, jax.random.key(seed), batch

@@ -208,7 +208,8 @@ class TestPipeline:
         model = LlamaModel(cfg)
         mesh = build_mesh(MeshConfig(dp=-1, pp=2), jax.devices())
         rules = PRESET_RULES["fsdp"]
-        batch = make_batch(cfg)
+        # 4 microbatches of 4 rows: a microbatch is what dp=4 splits
+        batch = make_batch(cfg, batch=16)
         state, shardings = create_sharded_state(
             model, default_optimizer(), mesh, rules, jax.random.key(0), batch
         )
@@ -245,7 +246,7 @@ class TestPipeline:
 
         mesh = build_mesh(MeshConfig(dp=-1, pp=2), jax.devices())
         rules = PRESET_RULES["fsdp"]
-        batch = make_batch(cfg_1f1b)
+        batch = make_batch(cfg_1f1b, batch=16)  # 4 rows a microbatch
         model = LlamaModel(cfg_1f1b)
         state, shardings = create_sharded_state(
             model, default_optimizer(), mesh, rules, jax.random.key(0), batch

@@ -57,7 +57,7 @@ class TestOrbaxRoundTrip:
         model = LlamaModel(cfg)
         mesh = build_mesh(MeshConfig(dp=-1, fsdp=2), devices8)
         rules = PRESET_RULES["fsdp"]
-        sample = {"input_ids": jnp.zeros((4, 16), jnp.int32)}
+        sample = {"input_ids": jnp.zeros((8, 16), jnp.int32)}
         state, shardings = create_sharded_state(
             model, optax.adamw(1e-3), mesh, rules, jax.random.key(0), sample
         )
