@@ -4,9 +4,25 @@ behind each.
 
 ``HybridConfig.layer_types`` names each layer's mixer in the published
 spellings: ``"mamba"`` and ``"attention"`` (Granite-4.0-H), ``"conv"`` and
-``"full_attention"`` (LFM2); the layers are unrolled, each its own
-parameters.  Two families are written down here.  The fields that tell
+``"full_attention"`` (LFM2), ``"sliding_attention"`` and
+``"full_attention"`` (AFMoE); the layers are unrolled, each its own
+parameters.  Three families are written down here.  The fields that tell
 them apart default to Granite's, so its program is what it was.
+
+**AFMoE** (``model_type: afmoe``, Trinity).  ``h = sqrt(hidden) * E[ids]``
+(``embedding_multiplier``); a layer is ``h += N2(attn(N1(h)))`` then ``h +=
+N4(ffn(N3(h)))``: four RMSNorms a layer (``sandwich_norm``; N1
+``input_norm``, N2 ``mixer_out_norm``, N3 ``post_norm``, N4
+``ffn_out_norm``); the head is its own matrix (``tie_word_embeddings``
+False).  Attention at ``head_dim`` (a key of its own: 32 heads of 128 over
+a hidden size of 2048), RMSNorm on q and k (``qk_norm``), an output gate
+``out = W_o (attn * sigmoid(W_g n))`` (``attention_gate``).  A
+``sliding_attention`` layer has rotary positions and sees the last
+``sliding_window`` keys, the query's own counted; a ``full_attention``
+layer is causal and has **no position term** (``rope_layers`` names the
+kinds that carry one).  FFN: dense, then the routed layer with
+``num_shared_experts`` shared experts computed for every token beside the
+routed ones (``models/moe.py``).
 
 **LFM2** (``model_type: lfm2_moe``).  Every multiplier is 1 and the head is
 the embedding, tied.  ``conv`` mixer: ``B, C, x`` projected from the hidden
@@ -72,10 +88,11 @@ from dlrover_tpu.models.llama import (
 )
 from dlrover_tpu.models.moe import RoutedExperts
 from dlrover_tpu.ops import grouped_matmul, ssd
-from dlrover_tpu.ops.splash_attention import splash_attention_gqa
+from dlrover_tpu.ops.splash_attention import mask_plan, splash_attention_gqa
 from dlrover_tpu.parallel.sharding import constrain
 
-LAYER_KINDS = ("mamba", "attention", "conv", "full_attention")
+ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
+LAYER_KINDS = ("mamba", "conv") + ATTENTION_KINDS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,7 +117,18 @@ class HybridConfig:
     conv_width: int = 4
     # Attention's position term and q/k norm: none in Granite-4.0-H.
     rope_theta: Optional[float] = None  # None: no rotary positions
+    # The attention kinds that carry them; None: every one (LFM2).
+    rope_layers: Optional[Tuple[str, ...]] = None
     qk_norm: bool = False
+    head_dim: Optional[int] = None  # None: hidden_size // num_heads
+    # What a "sliding_attention" layer sees: the last ``sliding_window``
+    # keys, the query's own position counted.
+    sliding_window: Optional[int] = None
+    attention_gate: bool = False  # out = W_o (attn * sigmoid(W_g n))
+    # Four norms a layer: the mixer's and the FFN's outputs are normed
+    # before they join the residual.
+    sandwich_norm: bool = False
+    tie_word_embeddings: bool = True
     # FFN by layer: dense everywhere unless ``num_experts``; then the first
     # ``num_dense_layers`` are dense and the rest routed (models/moe.py).
     num_dense_layers: int = 0
@@ -110,6 +138,8 @@ class HybridConfig:
     experts_held: Optional[int] = None  # None: all of them
     expert_block: int = 0  # which block of ``experts_held`` is held here
     routed_scaling_factor: float = 1.0
+    route_norm_eps: float = 1e-6  # in the picks' normaliser
+    num_shared_experts: int = 0  # beside the routed ones, for every token
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
     attention_impl: str = "dot"  # dot | splash
@@ -119,6 +149,13 @@ class HybridConfig:
         # A JSON list arrives through a configuration file; a flax module
         # attribute has to hash.
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.rope_layers is not None:
+            object.__setattr__(self, "rope_layers", tuple(self.rope_layers))
+        if "sliding_attention" in self.layer_types and not (
+                self.sliding_window and self.sliding_window > 0):
+            raise ValueError(
+                "a sliding_attention layer needs sliding_window > 0; got "
+                f"{self.sliding_window}")
         unknown = set(self.layer_types) - set(LAYER_KINDS)
         if unknown:
             raise ValueError(
@@ -141,7 +178,16 @@ class HybridConfig:
 
     @property
     def resolved_head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_dim or self.hidden_size // self.num_heads
+
+    def window(self, kind: str) -> Optional[int]:
+        """The sliding window of a layer of ``kind``; None: causal."""
+        return self.sliding_window if kind == "sliding_attention" else None
+
+    def rotary(self, kind: str) -> bool:
+        """Whether an attention layer of ``kind`` has rotary positions."""
+        return self.rope_theta is not None and (
+            self.rope_layers is None or kind in self.rope_layers)
 
     @property
     def ssm_inner(self) -> int:
@@ -161,6 +207,26 @@ class HybridConfig:
             num_kv_heads=2, conv_width=3, rope_theta=1e6, qk_norm=True,
             num_dense_layers=1, num_experts=8, num_experts_per_token=4,
             moe_intermediate_size=32,
+        )
+        base.update(kw)
+        return cls(**base)
+
+    @classmethod
+    def tiny_afmoe(cls, **kw) -> "HybridConfig":
+        """Test-scale AFMoE: a dense layer, then a whole period of
+        sliding and global attention over 16 experts, 4 a token, and a
+        shared expert; heads of 32 over a hidden size of 64."""
+        base = dict(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            layer_types=("sliding_attention", "sliding_attention",
+                         "full_attention", "sliding_attention"),
+            num_heads=4, num_kv_heads=2, head_dim=32, sliding_window=8,
+            rope_theta=1e4, rope_layers=("sliding_attention",),
+            qk_norm=True, attention_gate=True, sandwich_norm=True,
+            tie_word_embeddings=False, embedding_multiplier=8.0,
+            num_dense_layers=1, num_experts=16, num_experts_per_token=4,
+            moe_intermediate_size=32, num_shared_experts=1,
+            routed_scaling_factor=2.826, route_norm_eps=1e-20,
         )
         base.update(kw)
         return cls(**base)
@@ -320,17 +386,36 @@ class ShortConv(nn.Module):
         return constrain(out, ("batch", "seq", "act_embed"))
 
 
+def causal_mask(s: int, window: Optional[int] = None, segment_ids=None):
+    """The ``dot`` path's (1 | b, 1, s, s) mask: key ``j`` is seen by query
+    ``t`` iff ``0 <= t - j`` (``< window`` with one) and, with
+    ``segment_ids`` (b, s), both lie in one document."""
+    mask = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        mask = mask & ~jnp.tril(mask, -window)
+    mask = mask[None, None]
+    if segment_ids is not None:
+        mask = mask & (
+            segment_ids[:, None, :, None] == segment_ids[:, None, None, :])
+    return mask
+
+
 class HybridAttention(nn.Module):
     """Causal grouped-query attention; ``cfg.qk_norm`` and
     ``cfg.rope_theta`` add LFM2's RMSNorm on q and k and its rotary
-    positions (Granite-4.0-H has neither)."""
+    positions (Granite-4.0-H has neither).  ``kind`` is the layer's entry
+    of ``layer_types``: it decides the window (``cfg.window``) and whether
+    this layer has rotary positions (``cfg.rotary``); ``cfg.attention_gate``
+    adds AFMoE's sigmoid gate on the heads' outputs."""
 
     cfg: HybridConfig
+    kind: str = "attention"
 
     @nn.compact
     def __call__(self, h, positions=None, segment_ids=None):
         cfg = self.cfg
         d = cfg.resolved_head_dim
+        window = cfg.window(self.kind)
         scale = cfg.attention_multiplier
         if scale is None:
             scale = 1.0 / math.sqrt(d)
@@ -363,24 +448,28 @@ class HybridAttention(nn.Module):
                 return (t * weight).astype(cfg.dtype)
 
             q, k = head_norm("q_norm", q), head_norm("k_norm", k)
-        if cfg.rope_theta is not None:
+        if cfg.rotary(self.kind):
             if positions is None:
                 positions = jnp.arange(h.shape[1])[None]
             q, k = _rope(q, k, positions, d, cfg.rope_theta)
         q = constrain(q, ("batch", "seq", "act_heads", "act_head_dim"))
         k = constrain(k, ("batch", "seq", "act_kv_heads", "act_head_dim"))
         v = constrain(v, ("batch", "seq", "act_kv_heads", "act_head_dim"))
-        if cfg.attention_impl == "splash":
-            out = splash_attention_gqa(
-                q, k, v, segment_ids=segment_ids, scale=scale)
-        else:
-            s = q.shape[1]
-            mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
-            if segment_ids is not None:
-                mask = mask & (
-                    segment_ids[:, None, :, None]
-                    == segment_ids[:, None, None, :])
-            out = _masked_attention(q, k, v, mask, scale=scale)
+        with jax.named_scope(
+                "attn/sliding" if window is not None else "attn/full"):
+            if cfg.attention_impl == "splash":
+                out = splash_attention_gqa(
+                    q, k, v, segment_ids=segment_ids, scale=scale,
+                    window=window)
+            else:
+                out = _masked_attention(
+                    q, k, v, causal_mask(q.shape[1], window, segment_ids),
+                    scale=scale)
+        if cfg.attention_gate:
+            with jax.named_scope("attn/gate"):
+                gate = project("gate_proj", cfg.num_heads, "heads")
+                out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(cfg.dtype)
         out = constrain(
             out, ("batch", "seq", "act_heads", "act_head_dim"))
         out = nn.DenseGeneral(
@@ -404,6 +493,8 @@ def routed_experts(cfg: HybridConfig, **kw) -> RoutedExperts:
         experts_held=cfg.experts_held,
         expert_block=cfg.expert_block,
         routed_scaling_factor=cfg.routed_scaling_factor,
+        route_norm_eps=cfg.route_norm_eps,
+        num_shared_experts=cfg.num_shared_experts,
         dtype=cfg.dtype, param_dtype=cfg.param_dtype, **kw)
 
 
@@ -423,8 +514,10 @@ class HybridBlock(nn.Module):
             mixed = ShortConv(cfg, name="conv")(h)
         else:
             with jax.named_scope("hybrid/attention"):
-                mixed = HybridAttention(cfg, name="attention")(
+                mixed = HybridAttention(cfg, self.kind, name="attention")(
                     h, positions, segment_ids)
+        if cfg.sandwich_norm:
+            mixed = norm(name="mixer_out_norm")(mixed)
         x = x + cfg.residual_multiplier * mixed
         h = norm(name="post_norm")(x)
         if self.routed:
@@ -432,6 +525,8 @@ class HybridBlock(nn.Module):
         else:
             with jax.named_scope("hybrid/mlp"):
                 ffn = MLP(cfg, name="mlp")(h)
+        if cfg.sandwich_norm:
+            ffn = norm(name="ffn_out_norm")(ffn)
         x = x + cfg.residual_multiplier * ffn
         return constrain(x, ("batch", "seq", "act_embed"))
 
@@ -464,6 +559,17 @@ class HybridModel(nn.Module):
                 attention_impl=cfg.attention_impl,
                 head_dim=cfg.resolved_head_dim,
             )
+            if kinds["sliding_attention"]:
+                seq = input_ids.shape[1]
+                lowered.update(
+                    sliding_window=cfg.sliding_window,
+                    rope_layers=sorted(
+                        k for k in ATTENTION_KINDS
+                        if kinds[k] and cfg.rotary(k)),
+                    attention_masks={
+                        k: mask_plan(seq, cfg.window(k))
+                        for k in ATTENTION_KINDS if kinds[k]},
+                )
             if kinds["mamba"]:
                 lowered.update(
                     chunk=cfg.ssm_chunk,
@@ -475,7 +581,8 @@ class HybridModel(nn.Module):
                     num_experts=cfg.num_experts,
                     experts_held=cfg.experts_held or cfg.num_experts,
                     top_k=cfg.num_experts_per_token, pairs_rows=pairs,
-                    routed_layers=sum(
+                    num_shared_experts=cfg.num_shared_experts,
+                                routed_layers=sum(
                         cfg.routed(i) for i in range(len(cfg.layer_types))),
                     gmm_gate_up=grouped_matmul.plan(pairs, h, 2 * m),
                     gmm_down=grouped_matmul.plan(pairs, m, h),
@@ -501,6 +608,17 @@ class HybridModel(nn.Module):
             with jax.named_scope("hybrid/head"):
                 x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, cfg.param_dtype,
                             name="final_norm")(x)
-                logits = jnp.einsum("bse,ve->bsv", x, embed.astype(cfg.dtype))
+                if cfg.tie_word_embeddings:
+                    logits = jnp.einsum(
+                        "bse,ve->bsv", x, embed.astype(cfg.dtype))
+                else:
+                    logits = nn.DenseGeneral(
+                        features=cfg.vocab_size, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype, use_bias=False,
+                        kernel_init=param_with_axes(
+                            nn.initializers.lecun_normal(),
+                            ("embed", "vocab")),
+                        name="lm_head",
+                    )(x)
                 logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         return constrain(logits, ("batch", "seq", "act_vocab"))

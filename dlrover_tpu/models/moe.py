@@ -23,19 +23,27 @@ step folds into the objective:
 
 ``RoutedExperts``.  ``s = sigmoid(W_g n)`` in float32 over all
 ``num_experts``; picks = top-k of ``s + b``, where the per-expert bias ``b``
-enters the selection only; weights ``s[picks] / (Σ s[picks] + 1e-6)`` times
-``routed_scaling_factor``; ``out = Σ_picks w_e · SwiGLU_e(n)`` over the
-picks whose expert is **held here** (a contiguous block, ``experts_held``
+enters the selection only; weights ``s[picks] / (Σ s[picks] +
+route_norm_eps)`` times ``routed_scaling_factor``; ``out = Σ_picks w_e ·
+SwiGLU_e(n)`` over the picks whose expert is **held here** (a contiguous block, ``experts_held``
 wide, number ``expert_block``; held = all is the uncut layer).  Picks whose
 expert lies elsewhere add nothing: on one chip the layer runs without its
 ``ep`` exchange, and the partial sum is what goes on.  No token is dropped
 whatever the imbalance: the (token, pick) pairs are sorted by expert into a
 buffer sized for the worst case (every pick here), and the grouped products
-(``ops/grouped_matmul.py``) do the work of the pairs that are there.  No
-shared expert, no auxiliary loss; the layer sows ``moe_load``, the pairs
+(``ops/grouped_matmul.py``) do the work of the pairs that are there.
+``num_shared_experts`` > 0 adds a shared expert: one more SwiGLU
+(``models/llama.py::MLP``, ``num_shared_experts x intermediate_size`` wide)
+that every token passes, unweighted, whole on every chip that shares the
+layer; it joins the routed sum in float32, before the one cast to the
+compute dtype.  No auxiliary loss; the layer sows ``moe_load``, the pairs
 routed to each held expert and, last, elsewhere, and ``moe_picks``.
+
+The bias ``b`` is a leaf of zeros that no gradient reaches and the step has
+no rule for (an optimizer's weight decay is all that touches it).
 """
 
+import dataclasses
 from typing import Optional
 
 import flax.linen as nn
@@ -228,13 +236,14 @@ def router_scores(tokens, router):
         precision=jax.lax.Precision.HIGHEST))
 
 
-def route(scores, bias, k: int, scaling: float = 1.0):
+def route(scores, bias, k: int, scaling: float = 1.0, eps: float = 1e-6):
     """scores: (t, e) float32 in (0, 1); bias: (e,).  Picks are the top
     ``k`` of ``scores + bias``; the weights come from the scores alone,
-    normalised over the picks.  Returns (picks (t, k) int32, weights)."""
+    normalised over the picks (``eps`` in the normaliser).  Returns (picks
+    (t, k) int32, weights)."""
     _, picks = jax.lax.top_k(scores + bias, k)
     weights = jnp.take_along_axis(scores, picks, axis=-1)
-    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     return picks, weights * scaling
 
 
@@ -259,6 +268,16 @@ def sort_pairs(picks, first: int, held: int):
     return order, position, sizes
 
 
+@dataclasses.dataclass(frozen=True)
+class _SharedWidths:
+    """What ``models/llama.py::MLP`` reads of a configuration."""
+
+    hidden_size: int
+    intermediate_size: int
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+
 class RoutedExperts(nn.Module):
     """The dropless routed-expert FFN (module docstring).  x: (b, s, h)."""
 
@@ -269,6 +288,8 @@ class RoutedExperts(nn.Module):
     experts_held: Optional[int] = None  # None: all of them
     expert_block: int = 0
     routed_scaling_factor: float = 1.0
+    route_norm_eps: float = 1e-6
+    num_shared_experts: int = 0
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
 
@@ -304,7 +325,7 @@ class RoutedExperts(nn.Module):
                 ("router",))
             picks, pick_weights = route(
                 router_scores(tokens, router), bias.astype(jnp.float32), k,
-                self.routed_scaling_factor)
+                self.routed_scaling_factor, self.route_norm_eps)
         with jax.named_scope("moe/sort"):
             order, position, sizes = sort_pairs(picks, first, held)
             self.sow("intermediates", "moe_load", sizes)
@@ -335,7 +356,16 @@ class RoutedExperts(nn.Module):
             out = jnp.sum(
                 by_pair.astype(jnp.float32) * pick_weights.T[..., None],
                 axis=0,
-            ).astype(self.dtype)
+            )
+        if self.num_shared_experts:
+            from dlrover_tpu.models.llama import MLP
+
+            with jax.named_scope("moe/shared"):
+                shared = MLP(_SharedWidths(
+                    h, m * self.num_shared_experts, self.dtype,
+                    self.param_dtype), name="shared")(x)
+                out = out + shared.reshape(b * s, h).astype(jnp.float32)
+        out = out.astype(self.dtype)
         return constrain(
             out.reshape(b, s, h), ("batch", "seq", "act_embed"))
 

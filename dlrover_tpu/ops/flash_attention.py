@@ -39,7 +39,7 @@ def _pick_chunk(s: int, cap: int) -> int:
     return s
 
 
-def _segmented_reference(q, k, v, causal, segment_ids, q_chunk):
+def _segmented_reference(q, k, v, causal, segment_ids, q_chunk, window=None):
     """Packed-row reference attention, chunked over q.
 
     The (b, s, s) boolean segment mask is never materialized in HBM (64M
@@ -64,9 +64,10 @@ def _segmented_reference(q, k, v, causal, segment_ids, q_chunk):
         pred = seg_q[:, None, :, None] == segment_ids[:, None, None, :]
         if causal:
             qpos = i * c + jnp.arange(c)
-            pred = jnp.logical_and(
-                pred, (qpos[:, None] >= kpos[None, :])[None, None]
-            )
+            seen = qpos[:, None] >= kpos[None, :]
+            if window is not None:
+                seen = seen & (qpos[:, None] - kpos[None, :] < window)
+            pred = jnp.logical_and(pred, seen[None, None])
         scores = jnp.where(pred, scores, _NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -78,30 +79,71 @@ def _segmented_reference(q, k, v, causal, segment_ids, q_chunk):
 
 
 def mha_reference(
-    q, k, v, causal: bool = True, segment_ids=None, q_chunk: int = 512
+    q, k, v, causal: bool = True, segment_ids=None, q_chunk: int = 512,
+    window: Optional[int] = None,
 ):
     """Plain-XLA reference (and fallback) attention; exact.
 
     Dense path is O(s²) memory; with ``segment_ids`` the predicate is
     fused per q-chunk (:func:`_segmented_reference`) so packed rows never
-    materialize the (b, s, s) segment mask.
+    materialize the (b, s, s) segment mask.  ``window`` (causal only): key
+    ``j`` is seen by query ``t`` iff ``0 <= t - j < window``.
     """
+    check_window(window, causal)
     b, s, hq, d = q.shape
     hkv = k.shape[2]
     if hq != hkv:
         k = jnp.repeat(k, hq // hkv, axis=2)
         v = jnp.repeat(v, hq // hkv, axis=2)
     if segment_ids is not None:
-        return _segmented_reference(q, k, v, causal, segment_ids, q_chunk)
+        return _segmented_reference(
+            q, k, v, causal, segment_ids, q_chunk, window)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     scores = scores / math.sqrt(d)
     mask = jnp.ones((s, k.shape[1]), dtype=bool)
     if causal:
         mask = jnp.tril(mask)
+    if window is not None:
+        mask = mask & ~jnp.tril(mask, -window)
     mask = mask[None, None]
     scores = jnp.where(mask, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def check_window(window, causal):
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window}: a sliding window is a causal mask over the "
+            f"last `window` >= 1 positions (causal={causal})")
+
+
+def block_live(iq, ik, block_q, block_kv, causal, window):
+    """Whether any (query, key) pair of q block ``iq`` and kv block ``ik``
+    is seen: not wholly above the diagonal, not wholly behind the window
+    (program ids in a kernel, plain ints in ``splash_attention.mask_plan``)."""
+    if not causal:
+        return True
+    live = ik * block_kv <= iq * block_q + block_q - 1
+    if window is not None:
+        live = live & (ik * block_kv + block_kv - 1 > iq * block_q - window)
+    return live
+
+
+def _position_mask(iq, ik, block_q, block_kv, causal, window):
+    """The causal (and windowed) predicate of one block, or None."""
+    if not causal:
+        return None
+    qpos = iq * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_kv), 0
+    )
+    kpos = ik * block_kv + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_kv), 1
+    )
+    mask = qpos >= kpos
+    if window is not None:
+        mask = jnp.logical_and(mask, qpos - kpos < window)
+    return mask
 
 
 def _seg_lane_blocks(segment_ids):
@@ -122,7 +164,7 @@ def _seg_lane_blocks(segment_ids):
 
 def _fwd_kernel(
     *refs, sm_scale: float, causal: bool, segmented: bool, block_q: int,
-    block_kv: int, num_kv_blocks: int,
+    block_kv: int, num_kv_blocks: int, window: Optional[int] = None,
 ):
     """Grid = (batch, q_heads, q_blocks, kv_blocks); kv dim is sequential
     ("arbitrary") so the (m, l, acc) scratch carries across kv steps.
@@ -145,12 +187,11 @@ def _fwd_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # Causal: blocks strictly above the diagonal are fully masked — skip
-    # their FLOPs entirely (the ~2x saving flash attention exists for).
-    block_live = (
-        ik * block_kv <= iq * block_q + block_q - 1 if causal else True
-    )
+    # their FLOPs entirely (the ~2x saving flash attention exists for);
+    # with a window, so are the blocks wholly behind it.
+    live = block_live(iq, ik, block_q, block_kv, causal, window)
 
-    @pl.when(block_live)
+    @pl.when(live)
     def _compute():
         # Matmuls stay in the input dtype (bf16 on TPU: full MXU rate, 8x
         # the f32 rate on v5e) with f32 ACCUMULATION via
@@ -163,15 +204,7 @@ def _fwd_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale  # (block_q, block_kv) f32
 
-        mask = None
-        if causal:
-            qpos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0
-            )
-            kpos = ik * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1
-            )
-            mask = qpos >= kpos
+        mask = _position_mask(iq, ik, block_q, block_kv, causal, window)
         if segmented:
             seg_mask = seg_q_ref[0][:, :1] == seg_kv_ref[0][:1, :]
             mask = seg_mask if mask is None else jnp.logical_and(mask, seg_mask)
@@ -209,7 +242,8 @@ def _fwd_kernel(
 
 
 def _flash_fwd(
-    q_t, k_t, v_t, segment_ids, *, causal, block_q, block_kv, interpret
+    q_t, k_t, v_t, segment_ids, *, causal, block_q, block_kv, interpret,
+    window=None,
 ):
     """q_t (b, h, s, d); k_t/v_t (b, h_kv, s_kv, d) → (out, lse) in t-layout.
     ``segment_ids`` (b, s) or None selects the segmented kernel variant."""
@@ -228,6 +262,7 @@ def _flash_fwd(
         block_q=block_q,
         block_kv=block_kv,
         num_kv_blocks=num_kv_blocks,
+        window=window,
     )
     grid = (b, h, s_q // block_q, num_kv_blocks)
     in_specs = [
@@ -292,6 +327,7 @@ def _flash_fwd(
 
 def _bwd_dkdv_kernel(
     *refs, sm_scale, causal, segmented, block_q, block_kv, num_q_blocks,
+    window=None,
 ):
     """Grid (b, h, kv_blocks, q_blocks); q dim sequential so (dk, dv)
     accumulate in scratch for one kv block."""
@@ -310,11 +346,9 @@ def _bwd_dkdv_kernel(
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     # Causal: q blocks strictly below the diagonal contribute nothing.
-    block_live = (
-        i * block_q + block_q - 1 >= j * block_kv if causal else True
-    )
+    live = block_live(i, j, block_q, block_kv, causal, window)
 
-    @pl.when(block_live)
+    @pl.when(live)
     def _compute():
         q = q_ref[0, 0]  # (bq, d)
         k = k_ref[0, 0]  # (bkv, d)
@@ -328,15 +362,7 @@ def _bwd_dkdv_kernel(
             preferred_element_type=jnp.float32,
         ) * sm_scale  # (bq, bkv)
         p = jnp.exp(s - lse)
-        mask = None
-        if causal:
-            qpos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0
-            )
-            kpos = j * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1
-            )
-            mask = qpos >= kpos
+        mask = _position_mask(i, j, block_q, block_kv, causal, window)
         if segmented:
             seg_mask = seg_q_ref[0][:, :1] == seg_kv_ref[0][:1, :]
             mask = seg_mask if mask is None else jnp.logical_and(mask, seg_mask)
@@ -368,6 +394,7 @@ def _bwd_dkdv_kernel(
 
 def _bwd_dq_kernel(
     *refs, sm_scale, causal, segmented, block_q, block_kv, num_kv_blocks,
+    window=None,
 ):
     """Grid (b, h, q_blocks, kv_blocks); kv dim sequential, dq in scratch."""
     if segmented:
@@ -383,11 +410,9 @@ def _bwd_dq_kernel(
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    block_live = (
-        j * block_kv <= i * block_q + block_q - 1 if causal else True
-    )
+    live = block_live(i, j, block_q, block_kv, causal, window)
 
-    @pl.when(block_live)
+    @pl.when(live)
     def _compute():
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -401,15 +426,7 @@ def _bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         ) * sm_scale
         p = jnp.exp(s - lse)
-        mask = None
-        if causal:
-            qpos = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 0
-            )
-            kpos = j * block_kv + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1
-            )
-            mask = qpos >= kpos
+        mask = _position_mask(i, j, block_q, block_kv, causal, window)
         if segmented:
             seg_mask = seg_q_ref[0][:, :1] == seg_kv_ref[0][:1, :]
             mask = seg_mask if mask is None else jnp.logical_and(mask, seg_mask)
@@ -432,7 +449,7 @@ def _bwd_dq_kernel(
 
 def _flash_bwd_pallas(
     q_t, k_t, v_t, out_t, lse, do_t, segment_ids,
-    *, causal, block_q, block_kv, interpret
+    *, causal, block_q, block_kv, interpret, window=None,
 ):
     """FA-2 backward as two Pallas kernels; all tensors in t-layout
     (b, h, s, d) with k/v carrying h_kv heads (GQA folded outside)."""
@@ -479,6 +496,7 @@ def _flash_bwd_pallas(
             _bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
             segmented=segmented,
             block_q=block_q, block_kv=block_kv, num_q_blocks=nq,
+            window=window,
         ),
         grid=(b, h, nk, nq),
         in_specs=dkdv_in_specs,
@@ -550,6 +568,7 @@ def _flash_bwd_pallas(
             _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
             segmented=segmented,
             block_q=block_q, block_kv=block_kv, num_kv_blocks=nk,
+            window=window,
         ),
         grid=(b, h, nq, nk),
         in_specs=dq_in_specs,
@@ -618,23 +637,25 @@ def shard_kernel_over_mesh(kernel, q, k, v, segment_ids=None):
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8)
 )
 def _flash_attention(q, k, v, segment_ids, causal, block_q, block_kv,
-                     interpret):
+                     interpret, window=None):
     out, _ = _fa_fwd(
-        q, k, v, segment_ids, causal, block_q, block_kv, interpret
+        q, k, v, segment_ids, causal, block_q, block_kv, interpret, window
     )
     return out
 
 
-def _fa_fwd(q, k, v, segment_ids, causal, block_q, block_kv, interpret):
+def _fa_fwd(q, k, v, segment_ids, causal, block_q, block_kv, interpret,
+            window=None):
     q_t = q.transpose(0, 2, 1, 3)
     k_t = k.transpose(0, 2, 1, 3)
     v_t = v.transpose(0, 2, 1, 3)
     out_t, lse = _flash_fwd(
         q_t, k_t, v_t, segment_ids,
         causal=causal, block_q=block_q, block_kv=block_kv, interpret=interpret,
+        window=window,
     )
     return (
         out_t.transpose(0, 2, 1, 3),
@@ -642,13 +663,13 @@ def _fa_fwd(q, k, v, segment_ids, causal, block_q, block_kv, interpret):
     )
 
 
-def _fa_bwd(causal, block_q, block_kv, interpret, res, do):
+def _fa_bwd(causal, block_q, block_kv, interpret, window, res, do):
     q_t, k_t, v_t, out_t, lse, segment_ids = res
     do_t = do.transpose(0, 2, 1, 3)
     dq, dk, dv = _flash_bwd_pallas(
         q_t, k_t, v_t, out_t, lse, do_t, segment_ids,
         causal=causal, block_q=block_q, block_kv=block_kv,
-        interpret=interpret,
+        interpret=interpret, window=window,
     )
     return (
         dq.transpose(0, 2, 1, 3),
@@ -670,15 +691,21 @@ def flash_attention_gqa(
     block_kv: int = 512,
     causal: bool = True,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ):
     """Blockwise fused attention; q (b, s, h, d), k/v (b, s, h_kv, d).
 
     ``segment_ids`` (b, s) runs the segmented kernel variant (causal ∧
     same-segment predicate fused inside every block — packed rows never
-    materialize a (b, s, s) mask).  On a TPU the kernel is what was asked
-    for: shapes that do not tile raise.  Off the TPU the kernel runs in
-    interpret mode and untileable shapes take the XLA reference.
+    materialize a (b, s, s) mask).  ``window`` (causal only) is a sliding
+    window: key ``j`` is seen by query ``t`` iff ``0 <= t - j < window``,
+    the query's own position counted; blocks wholly behind it are skipped
+    like those above the diagonal, and it composes with ``segment_ids``.
+    On a TPU the kernel is what was asked for: shapes that do not tile
+    raise.  Off the TPU the kernel runs in interpret mode and untileable
+    shapes take the XLA reference.
     """
+    check_window(window, causal)
     b, s_q, h, d = q.shape
     s_kv, h_kv = k.shape[1], k.shape[2]
     block_q = min(block_q, s_q)
@@ -700,11 +727,12 @@ def flash_attention_gqa(
                 f"on a TPU nothing falls back to a reference — give this "
                 f"caller a path of its own (attention_impl='dot')"
             )
-        return mha_reference(q, k, v, causal=causal, segment_ids=segment_ids)
+        return mha_reference(
+            q, k, v, causal=causal, segment_ids=segment_ids, window=window)
 
     def kernel(q_, k_, v_, seg_):
         return _flash_attention(
-            q_, k_, v_, seg_, causal, block_q, block_kv, interpret
+            q_, k_, v_, seg_, causal, block_q, block_kv, interpret, window
         )
 
     if interpret:  # plain HLO: GSPMD partitions it itself

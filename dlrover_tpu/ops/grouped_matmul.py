@@ -71,6 +71,29 @@ def _contraction_tile(size: int) -> int:
     return size if size <= 2048 else _tile(size, 1024)
 
 
+# What one grouped product may hold in the kernel's fast memory: the
+# compiler's scoped limit on a v5e is 16 MiB and it adds some 0.6 MiB of
+# its own to the blocks counted here ((512, 2048, 896) counts 14.5 MiB and
+# compiles; (512, 2048, 1024) counts 16.0 and is refused at 16.58).
+_VMEM_BUDGET = 15 * 2 ** 20
+
+
+def _fits(tm: int, tk: int, tn: int) -> bool:
+    """Both operands' bf16 blocks and the output's, double-buffered, and
+    the float32 accumulator."""
+    blocks = tm * tk + tk * tn + tm * tn
+    return 2 * blocks * 2 + tm * tn * 4 <= _VMEM_BUDGET
+
+
+def _output_tile(tm: int, tk: int, size: int) -> int:
+    """The widest output tile up to 1024 whose product fits the fast
+    memory beside a contraction tile of ``tk``."""
+    tn = _tile(size, 1024)
+    while tn > 128 and not _fits(tm, tk, tn):
+        tn = _tile(size, tn - 128)
+    return tn
+
+
 def tilings(m: int, k: int, n: int):
     """(tm, tk, tn) for the forward product, the rows' gradient and the
     weights' gradient of an (m, k) x (g, k, n) call.  ``tm`` tiles the
@@ -78,12 +101,20 @@ def tilings(m: int, k: int, n: int):
     of each product as the library names them.  Swept on a v5e at the
     benchmark's shapes (131072 rows, 2048 x 3584 and 1792 x 2048, a
     quarter of the rows in groups; PERF.md, PR 34): each is within 3% of
-    the best of 18 to 27 tilings tried for its product."""
+    the best of 18 to 27 tilings tried for its product.  Where the whole
+    contraction (2048) and an output tile of 1024 do not fit the kernel's
+    fast memory together (2048 x 2048 products: PR 36) the output tile
+    narrows and the contraction stays whole."""
     tm = _tile(m, 512)
+
+    def product(contraction, output):
+        tk = _contraction_tile(contraction)
+        return tm, tk, _output_tile(tm, tk, output)
+
     return (
-        (tm, _contraction_tile(k), _tile(n, 1024)),  # out = lhs . rhs
-        (tm, _contraction_tile(n), _tile(k, 1024)),  # dlhs = dout . rhs^T
-        (tm, _tile(k, 1024), _tile(n, 1024)),        # drhs = lhs^T . dout
+        product(k, n),  # out = lhs . rhs
+        product(n, k),  # dlhs = dout . rhs^T
+        (tm, _tile(k, 1024), _tile(n, 1024)),  # drhs = lhs^T . dout
     )
 
 

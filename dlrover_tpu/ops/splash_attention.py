@@ -69,20 +69,30 @@ def _build_kernel(
     causal: bool,
     max_segment_len: Optional[int] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ):
+    """One kernel a distinct static mask: causal, a causal band, or full.
+
+    Two arguments give a band and promise different things.  ``window``
+    IS the mask: key ``j`` is seen by query ``t`` iff ``0 <= t - j <
+    window``, whatever the segments.  ``max_segment_len`` is a pruning
+    hint: when no document spans more tokens, no in-document (q, k) pair
+    is further apart, so its band is a *superset* of the true packed mask
+    — ``SegmentIds`` supplies exactness, the band only prunes
+    far-below-diagonal blocks from the schedule (the static FLOP saving).
+    With both, the narrower band is built: the window masks, the hint
+    cannot widen it.
+    """
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
     )
 
-    if causal and max_segment_len is not None:
-        # Causal ∧ (q - k < max_segment_len) band: when no document spans
-        # more than max_segment_len tokens, no in-document (q, k) pair is
-        # further apart, so the band is a *superset* of the true packed
-        # mask — SegmentIds supplies exactness, the band prunes far-below-
-        # diagonal blocks from the schedule (the static FLOP saving).
+    band = min(
+        (n for n in (window, max_segment_len) if n is not None), default=None)
+    if causal and band is not None:
         head_mask = sm.LocalMask(
-            (s_q, s_kv), window_size=(max_segment_len - 1, 0), offset=0
+            (s_q, s_kv), window_size=(band - 1, 0), offset=0
         )
     elif causal:
         head_mask = sm.CausalMask((s_q, s_kv))
@@ -102,6 +112,28 @@ def _build_kernel(
         mask, block_sizes=block_sizes, head_shards=1, q_seq_shards=1,
         interpret=interpret,
     )
+
+
+# The kernel's blocks unless a caller says otherwise: what
+# ``splash_attention_gqa`` runs and what ``mask_plan`` describes.
+DEFAULT_BLOCK = 1024
+
+
+def mask_plan(seq: int, window: Optional[int] = None,
+              block_q: int = DEFAULT_BLOCK, block_kv: int = DEFAULT_BLOCK):
+    """What the kernel's schedule visits for a causal mask (``window``
+    None) or a sliding window over ``seq`` positions, for a model's
+    ``lower`` span: the blocks, and the share of (q block, kv block) pairs
+    the mask keeps: those with at least one seen (query, key) pair."""
+    from dlrover_tpu.ops.flash_attention import block_live
+
+    bq, bkv = min(block_q, seq), min(block_kv, seq)
+    n_q, n_kv = -(-seq // bq), -(-seq // bkv)
+    kept = sum(
+        block_live(iq, ik, bq, bkv, True, window)
+        for iq in range(n_q) for ik in range(n_kv))
+    return dict(block_q=bq, block_kv=bkv, block_pairs=n_q * n_kv,
+                kept=kept, kept_share=kept / (n_q * n_kv))
 
 
 def shapes_tileable(
@@ -136,18 +168,24 @@ def splash_attention_gqa(
     k,
     v,
     segment_ids=None,
-    block_q: int = 1024,
-    block_kv: int = 1024,
+    block_q: int = DEFAULT_BLOCK,
+    block_kv: int = DEFAULT_BLOCK,
     causal: bool = True,
     max_segment_len: Optional[int] = None,
     interpret: Optional[bool] = None,
     scale: Optional[float] = None,
+    window: Optional[int] = None,
 ):
     """Drop-in for :func:`flash_attention_gqa` backed by the library kernel.
 
     ``segment_ids`` (b, s) packed rows run the SAME fast kernel via its
     native ``SegmentIds`` argument; ``max_segment_len`` (packer row bound)
-    additionally prunes blocks past the document-length band.  On a TPU an
+    additionally prunes blocks past the document-length band: a hint that
+    masks nothing by itself.  ``window`` (causal only) is a mask: a sliding
+    window in which key ``j`` is seen by query ``t`` iff ``0 <= t - j <
+    window`` (the query's own position counted), built into the kernel's
+    static mask so that blocks behind it are never visited; it composes
+    with ``segment_ids``, and the off-TPU path honours it too.  On a TPU an
     untileable shape raises.  Off the TPU the call takes the in-tree
     Pallas/XLA path (counted, see the module docstring) — the swap never
     changes semantics, only the schedule.  Block defaults match
@@ -158,10 +196,12 @@ def splash_attention_gqa(
     ``1 / sqrt(head_dim)``.
     """
     from dlrover_tpu.ops.flash_attention import (
+        check_window,
         flash_attention_gqa,
         shard_kernel_over_mesh,
     )
 
+    check_window(window, causal)
     s_q, h, d = q.shape[1:]
     s_kv, h_kv = k.shape[1], k.shape[2]
     default_scale = 1.0 / math.sqrt(d)
@@ -178,7 +218,7 @@ def splash_attention_gqa(
         return flash_attention_gqa(
             q_, k, v, segment_ids=segment_ids,
             block_q=min(block_q, 512), block_kv=min(block_kv, 512),
-            causal=causal,
+            causal=causal, window=window,
         )
 
     if interpret is None:
@@ -197,7 +237,7 @@ def splash_attention_gqa(
     local = functools.partial(
         _splash_local, block_q=block_q, block_kv=block_kv, causal=causal,
         max_segment_len=max_segment_len, interpret=interpret,
-        scale=default_scale if scale is None else scale,
+        scale=default_scale if scale is None else scale, window=window,
     )
     if interpret:  # plain HLO: GSPMD partitions it itself
         return local(q, k, v, segment_ids)
@@ -205,7 +245,7 @@ def splash_attention_gqa(
 
 
 def _splash_local(q, k, v, segment_ids, *, block_q, block_kv, causal,
-                  max_segment_len, interpret, scale):
+                  max_segment_len, interpret, scale, window=None):
     """The library kernel on one device's (batch, heads) shard."""
     b, s_q, h, d = q.shape
     s_kv, h_kv = k.shape[1], k.shape[2]
@@ -214,7 +254,7 @@ def _splash_local(q, k, v, segment_ids, *, block_q, block_kv, causal,
         v = jnp.repeat(v, h // h_kv, axis=2)
     kernel = _build_kernel(
         s_q, s_kv, h, block_q, block_kv, causal,
-        max_segment_len=max_segment_len, interpret=interpret,
+        max_segment_len=max_segment_len, interpret=interpret, window=window,
     )
     q_t = (q * jnp.asarray(scale, q.dtype)).transpose(0, 2, 1, 3)
     k_t = k.transpose(0, 2, 1, 3)

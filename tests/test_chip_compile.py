@@ -140,6 +140,15 @@ KERNELS = {
         "flash", 2, 2048, 12, 12, 64, segmented=True,
         block_q=512, block_kv=512,
     ),
+    # trinitymini.steady's two masks at its heads (32 / 4 of 128, s8192):
+    # a window is part of the kernel's static mask, so each is a kernel.
+    "splash_window2048_32_4_s8192": _attention_case(
+        "splash", 1, 8192, 32, 4, 128, window=2048),
+    "splash_causal_32_4_s8192": _attention_case(
+        "splash", 1, 8192, 32, 4, 128),
+    "flash_window512_d128": _attention_case(
+        "flash", 2, 2048, 32, 4, 128, window=512, block_q=512,
+        block_kv=512),
     "grouped_matmul_gate_up": _grouped_matmul_case(131072, 2048, 3584, 8),
     "grouped_matmul_down": _grouped_matmul_case(131072, 1792, 2048, 8),
     "quantize_blockwise": _quant,
@@ -243,6 +252,64 @@ def _compile_step(topo, monkeypatch, chips, layers, seq=2048, **widths):
     step = make_train_step(model, mesh, rules, shardings)
     with nn_partitioning.axis_rules(list(rules)), use_mesh(mesh):
         return step.jitted.lower(state, batch).compile()
+
+
+def test_trinity_minis_cut_fits_one_v5e_chip(topo, monkeypatch):
+    """``trinitymini.steady``'s whole step as the benchmark's worker builds
+    it (the configuration's file, b2 x s8192, every layer recomputed):
+    705.5 M parameters x 12 B of arguments, two splash kernels (a windowed
+    and a causal mask) and the grouped products in one program, inside
+    15.75 GiB."""
+    import json
+    import os
+
+    import optax
+    from flax.linen import partitioning as nn_partitioning
+
+    from dlrover_tpu.models.hybrid import HybridConfig, HybridModel
+    from dlrover_tpu.parallel.mesh import MeshConfig, build_mesh, use_mesh
+    from dlrover_tpu.parallel.sharding import PRESET_RULES
+    from dlrover_tpu.telemetry.costmodel import abstract_sharded_state
+    from dlrover_tpu.trainer.step import data_sharding, make_train_step
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "trinity-mini.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = HybridModel(HybridConfig(
+        **{ours: cfg[theirs]
+           for ours, theirs in cfg["model"]["from_source"].items()},
+        **cfg["model"]["kwargs"]))
+    mesh = build_mesh(MeshConfig(**cfg["mesh"]), topo.devices[:1])
+    rules = PRESET_RULES[cfg["rules"]]
+    batch = {
+        k: jax.ShapeDtypeStruct(
+            (cfg["batch"], cfg["seq"]), jnp.int32,
+            sharding=data_sharding(mesh, rules))
+        for k in ("input_ids", "labels")
+    }
+    opt = cfg["optimizer"]
+    state, shardings = abstract_sharded_state(
+        model, optax.adamw(opt["learning_rate"], b2=opt["b2"]), mesh, rules,
+        batch)
+    n_params = sum(x.size for x in jax.tree.leaves(state.params))
+    assert n_params == 705_474_304
+    step = make_train_step(model, mesh, rules, shardings)
+    with nn_partitioning.axis_rules(list(rules)), use_mesh(mesh):
+        compiled = step.jitted.lower(state, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 12 * n_params  # + the batch
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"trinity-mini b{cfg['batch']} x s{cfg['seq']}: arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB")
+    assert total < 15.75 * 2**30, f"{total / 2**30:.2f} GiB"
+    # the fullest device holds over a quarter of the chip before a step
+    assert mem.argument_size_in_bytes > 0.25 * HBM_BYTES
 
 
 def test_train_step_fits_one_v5e_chip_with_its_snapshot(topo, monkeypatch):
