@@ -86,7 +86,7 @@ from dlrover_tpu.models.llama import (
     param_with_axes,
     remat_policy,
 )
-from dlrover_tpu.models.moe import RoutedExperts
+from dlrover_tpu.models.moe import ROUTED_OUT, RoutedExperts, small_buffer
 from dlrover_tpu.ops import grouped_matmul, ssd
 from dlrover_tpu.ops.splash_attention import mask_plan, splash_attention_gqa
 from dlrover_tpu.parallel.sharding import constrain
@@ -576,17 +576,24 @@ class HybridModel(nn.Module):
                     n_chunks=input_ids.shape[1] // cfg.ssm_chunk)
             if cfg.num_experts:
                 pairs = input_ids.size * cfg.num_experts_per_token
+                held = cfg.experts_held or cfg.num_experts
+                cap, small_rows = small_buffer(pairs, held, cfg.num_experts)
                 h, m = cfg.hidden_size, cfg.moe_intermediate_size
                 lowered.update(
-                    num_experts=cfg.num_experts,
-                    experts_held=cfg.experts_held or cfg.num_experts,
+                    num_experts=cfg.num_experts, experts_held=held,
                     top_k=cfg.num_experts_per_token, pairs_rows=pairs,
+                    pairs_cap=cap,
                     num_shared_experts=cfg.num_shared_experts,
-                                routed_layers=sum(
+                    routed_layers=sum(
                         cfg.routed(i) for i in range(len(cfg.layer_types))),
                     gmm_gate_up=grouped_matmul.plan(pairs, h, 2 * m),
                     gmm_down=grouped_matmul.plan(pairs, m, h),
                 )
+                if cap < pairs:  # the small buffer's products (models/moe.py)
+                    lowered.update(
+                        gmm_gate_up_at_cap=grouped_matmul.plan(
+                            small_rows, h, 2 * m),
+                        gmm_down_at_cap=grouped_matmul.plan(small_rows, m, h))
             embed = self.param(
                 "embed_tokens",
                 param_with_axes(
@@ -598,8 +605,16 @@ class HybridModel(nn.Module):
             x = constrain(x, ("batch", "seq", "act_embed"))
             block_cls = HybridBlock
             if cfg.remat_policy != "none":
+                # Whatever the policy recomputes, a routed layer's output is
+                # kept (one row a token): a gradient that reads it (a norm
+                # after the layer) would otherwise run the layer's passes a
+                # third time (models/moe.py::_experts_in_passes).
                 block_cls = nn.remat(
-                    HybridBlock, policy=remat_policy(cfg.remat_policy),
+                    HybridBlock,
+                    policy=jax.checkpoint_policies.save_from_both_policies(
+                        remat_policy(cfg.remat_policy),
+                        jax.checkpoint_policies.save_only_these_names(
+                            ROUTED_OUT)),
                     prevent_cse=True,
                 )
             for i, kind in enumerate(cfg.layer_types):

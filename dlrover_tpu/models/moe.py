@@ -29,26 +29,45 @@ SwiGLU_e(n)`` over the picks whose expert is **held here** (a contiguous block, 
 wide, number ``expert_block``; held = all is the uncut layer).  Picks whose
 expert lies elsewhere add nothing: on one chip the layer runs without its
 ``ep`` exchange, and the partial sum is what goes on.  No token is dropped
-whatever the imbalance: the (token, pick) pairs are sorted by expert into a
-buffer sized for the worst case (every pick here), and the grouped products
+whatever the imbalance: the (token, pick) pairs are sorted by expert, those
+of the held experts in front, and the grouped products
 (``ops/grouped_matmul.py``) do the work of the pairs that are there.
+**How the buffer of sorted rows is sized.**  The sorted rows the layer
+works on are not the worst case's (``top_k x tokens``, every pick here) but
+a buffer of ``cap`` slots, ``cap`` = twice the pairs expected here
+(:func:`small_buffer`), and one more row tile that no group reaches and
+that therefore reads zeros.  One pass over it takes ``cap`` of the pairs
+that landed here (``n_held``, the sum of the held experts' sizes), in
+sorted order; the layer is a loop of such passes, one for every ``cap``
+pairs or part of it, each over the next window of the sort
+(:func:`_window`), its length counted on the device: one pass wherever the
+load is within ``cap`` (:func:`_experts_in_passes`).  The pairs a pass's
+window lacks read a row of zeros there and their own row in the pass that
+has them: no pair is dropped, capped or re-weighted whatever the load, and
+there is no capacity factor: the layer is as dropless as it was, and no
+buffer of the worst case's size is left in it.  Where ``cap`` reaches the
+worst case (every expert held, a handful of tokens) there is one pass over
+all the slots and no loop: the program is the one it was.
 ``num_shared_experts`` > 0 adds a shared expert: one more SwiGLU
 (``models/llama.py::MLP``, ``num_shared_experts x intermediate_size`` wide)
 that every token passes, unweighted, whole on every chip that shares the
 layer; it joins the routed sum in float32, before the one cast to the
 compute dtype.  No auxiliary loss; the layer sows ``moe_load``, the pairs
-routed to each held expert and, last, elsewhere, and ``moe_picks``.
+routed to each held expert and, last, elsewhere, ``moe_compact``, whether
+this call's load fit one pass over the small buffer, and ``moe_picks``.
 
 The bias ``b`` is a leaf of zeros that no gradient reaches and the step has
 no rule for (an optimizer's weight decay is all that touches it).
 """
 
 import dataclasses
+import functools
 from typing import Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from dlrover_tpu.ops.grouped_matmul import grouped_matmul
 from dlrover_tpu.parallel.sharding import constrain
@@ -187,9 +206,11 @@ class MoEMLP(nn.Module):
 def _rows_of_pairs(tokens, order, position):
     """Row ``order[j] % t`` of ``tokens`` (t, h) for every sorted slot
     ``j``: the token of the pair sorted there (pair ``p * t + i`` is token
-    ``i``'s pick ``p``).  ``position`` is ``order``'s inverse.  The
-    gradient is a gather too (each pair's slot, summed over a token's
-    picks), where autodiff would scatter-add 2048-wide rows."""
+    ``i``'s pick ``p``).  ``position`` is each pair's slot: ``order``'s
+    inverse, or, where ``order`` is a window of the sort, the last slot
+    for a pair outside it, whose gradient is zeros.  The gradient is a
+    gather too (each pair's slot, summed over a token's picks), where
+    autodiff would scatter-add 2048-wide rows."""
     return tokens[order % tokens.shape[0]]
 
 
@@ -210,8 +231,9 @@ _rows_of_pairs.defvjp(_rows_of_pairs_fwd, _rows_of_pairs_bwd)
 
 @jax.custom_vjp
 def _unsort(rows, order, position):
-    """``rows[position]``: every pair reads the slot it was sorted to.
-    ``order`` is ``position``'s inverse, so the gradient is
+    """``rows[position]``: every pair reads the slot it was sorted to, and
+    a pair outside a window the last one, a row of zeros.  ``order`` is
+    ``position``'s inverse over the rows, so the gradient is
     ``grad[order]``."""
     return rows[position]
 
@@ -266,6 +288,125 @@ def sort_pairs(picks, first: int, held: int):
         key[:, None] == jnp.arange(held + 1, dtype=key.dtype), axis=0,
         dtype=jnp.int32)
     return order, position, sizes
+
+
+# The row tile ``ops/grouped_matmul.py::tilings`` gives a buffer of these
+# sizes; a product's rows are a multiple of it.
+_ROW_TILE = 512
+# The small buffer holds this many times the pairs expected on the held
+# experts: the loads on record reach 1.26 of the expectation by layer and
+# seed (PERF.md, section 7).  A load past it is never a wrong answer: it
+# takes one more pass over the buffer for every ``cap`` pairs or part of it.
+_CAP_FACTOR = 2
+
+
+def small_buffer(pairs: int, held: int, experts: int):
+    """(cap, rows) of the small buffer for ``pairs`` (token, pick) pairs:
+    ``cap``, the most pairs on the held experts that one pass over it
+    takes, is ``_CAP_FACTOR`` times the ``pairs * held / experts``
+    expected, rounded up to the row tile; its ``rows`` are one tile more,
+    which no group reaches.  (pairs, pairs) where that would not be smaller
+    than the worst case: no small buffer is built."""
+    cap = -(-_CAP_FACTOR * pairs * held // experts)
+    cap = -(-cap // _ROW_TILE) * _ROW_TILE
+    if cap + _ROW_TILE < pairs:
+        return cap, cap + _ROW_TILE
+    return pairs, pairs
+
+
+def _window(sort, start, cap: int, rows: int):
+    """The sort as one pass over the small buffer sees it: the sorted slots
+    ``start .. start + cap - 1`` in front of a buffer of ``rows`` slots,
+    each held expert's group cut to the part that lies there, and every
+    pair sorted elsewhere sent to the last slot, which lies in the tile no
+    group reaches and reads zeros."""
+    order, position, sizes = sort
+    held = sizes.shape[0] - 1
+    ends = jnp.cumsum(sizes[:held])
+    begins = ends - sizes[:held]
+    here = (jnp.clip(ends, start, start + cap)
+            - jnp.clip(begins, start, start + cap))
+    # Room for the last window, whatever lies in the padding: its slots
+    # are past every group.
+    windows = -(-order.shape[0] // cap)
+    padded = jnp.pad(order, (0, windows * cap + rows - cap - order.shape[0]))
+    inside = (position >= start) & (position < start + cap)
+    return (jax.lax.dynamic_slice(padded, (start,), (rows,)),
+            jnp.where(inside, position - start, rows - 1), here)
+
+
+def _experts_over(dtype, inputs, sort):
+    """The held experts' part of the layer for every token, (t, h) float32:
+    ``sum_picks w . SwiGLU_e(token)`` over ``inputs`` (the tokens, the three
+    stacked weights, the picks' weights) and the pairs ``sort`` names
+    (:func:`sort_pairs`, or a :func:`_window` of it)."""
+    tokens, w_gate, w_up, w_down, pick_weights = inputs
+    order, position, sizes = sort
+    held, _, m = w_gate.shape
+    with jax.named_scope("moe/sort"):
+        rows = _rows_of_pairs(tokens, order, position)
+    with jax.named_scope("moe/gate_up"):
+        gate_up = grouped_matmul(
+            rows,
+            jnp.concatenate([w_gate.astype(dtype), w_up.astype(dtype)], -1),
+            sizes[:held])
+        act = nn.silu(gate_up[:, :m]) * gate_up[:, m:]
+    with jax.named_scope("moe/down"):
+        out = grouped_matmul(act, w_down.astype(dtype), sizes[:held])
+    with jax.named_scope("moe/combine"):
+        # A pair sorted behind the held experts reads a zero row.
+        by_pair = _unsort(out, order, position).reshape(-1, *tokens.shape)
+        return jnp.sum(
+            by_pair.astype(jnp.float32) * pick_weights.T[..., None],
+            axis=0,
+        )
+
+
+def _pass_over(cap, rows, dtype, inputs, sort, i):
+    """:func:`_experts_over` the ``i``-th window of the sort."""
+    return _experts_over(dtype, inputs, _window(sort, i * cap, cap, rows))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _experts_in_passes(cap, rows, dtype, passes, inputs, sort):
+    """The held experts' part of the layer in ``passes`` passes over the
+    small buffer, a count on the device and 1 wherever the load is within
+    ``cap``: a loop over the windows of the sort, each adding its pairs'
+    part.  A loop of that length has no autodiff rule, so the layer brings
+    its own: a second loop that runs each window's forward again, then its
+    pullback, and adds up the gradients.  Under a policy that recomputes
+    the layer that takes the place of the recomputation, as long as no
+    gradient outside reads the layer's output: ``RoutedExperts`` names its
+    output ``ROUTED_OUT`` for the policy to keep."""
+    return jax.lax.fori_loop(
+        0, passes,
+        lambda i, out: out + _pass_over(cap, rows, dtype, inputs, sort, i),
+        jnp.zeros(inputs[0].shape, jnp.float32))
+
+
+def _experts_in_passes_fwd(cap, rows, dtype, passes, inputs, sort):
+    return (_experts_in_passes(cap, rows, dtype, passes, inputs, sort),
+            (passes, inputs, sort))
+
+
+def _experts_in_passes_bwd(cap, rows, dtype, residual, grad):
+    passes, inputs, sort = residual
+
+    def one_more(i, grads):
+        _, pullback = jax.vjp(
+            lambda inputs: _pass_over(cap, rows, dtype, inputs, sort, i),
+            inputs)
+        return jax.tree.map(jnp.add, grads, pullback(grad)[0])
+
+    return None, jax.lax.fori_loop(
+        0, passes, one_more, jax.tree.map(jnp.zeros_like, inputs)), None
+
+
+_experts_in_passes.defvjp(_experts_in_passes_fwd, _experts_in_passes_bwd)
+
+# What a recomputation policy keeps of a routed layer (models/hybrid.py):
+# its output, one row a token in the compute dtype.
+ROUTED_OUT = "routed_experts_out"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -332,7 +473,6 @@ class RoutedExperts(nn.Module):
             # For a comparison of routing (scripts/logits_check.py); the
             # step fetches the load only, so this costs a step nothing.
             self.sow("intermediates", "moe_picks", picks)
-            rows = _rows_of_pairs(tokens, order, position)
         lecun = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                              batch_axis=(0,))
         w_gate = weights("gate_proj", lecun, (held, h, m),
@@ -341,22 +481,27 @@ class RoutedExperts(nn.Module):
                        ("expert", "embed", "mlp"))
         w_down = weights("down_proj", lecun, (held, m, h),
                          ("expert", "mlp", "embed"))
-        with jax.named_scope("moe/gate_up"):
-            gate_up = grouped_matmul(
-                rows,
-                jnp.concatenate(
-                    [w_gate.astype(self.dtype), w_up.astype(self.dtype)], -1),
-                sizes[:held])
-            act = nn.silu(gate_up[:, :m]) * gate_up[:, m:]
-        with jax.named_scope("moe/down"):
-            out = grouped_matmul(act, w_down.astype(self.dtype), sizes[:held])
-        with jax.named_scope("moe/combine"):
-            # A pair sorted behind the held experts reads a zero row.
-            by_pair = _unsort(out, order, position).reshape(k, b * s, h)
-            out = jnp.sum(
-                by_pair.astype(jnp.float32) * pick_weights.T[..., None],
-                axis=0,
-            )
+
+        inputs = (tokens, w_gate, w_up, w_down, pick_weights)
+        sort = (order, position, sizes)
+        pairs = k * b * s
+        cap, rows = small_buffer(pairs, held, e)
+        if cap < pairs:
+            # Counted on the device: one pass over the small buffer takes
+            # ``cap`` of the pairs that landed here, in sorted order.
+            passes = -(-jnp.sum(sizes[:held]) // cap)
+            compact = passes <= 1
+            # The weights in the compute dtype from outside: the loop then
+            # adds up their gradients in that dtype, as the products yield
+            # them, and autodiff casts once.
+            inputs = (tokens, *(w.astype(self.dtype) for w in inputs[1:4]),
+                      pick_weights)
+            out = _experts_in_passes(
+                cap, rows, self.dtype, passes, inputs, sort)
+        else:
+            compact = jnp.bool_(False)
+            out = _experts_over(self.dtype, inputs, sort)
+        self.sow("intermediates", "moe_compact", compact.astype(jnp.int32))
         if self.num_shared_experts:
             from dlrover_tpu.models.llama import MLP
 
@@ -365,22 +510,27 @@ class RoutedExperts(nn.Module):
                     h, m * self.num_shared_experts, self.dtype,
                     self.param_dtype), name="shared")(x)
                 out = out + shared.reshape(b * s, h).astype(jnp.float32)
-        out = out.astype(self.dtype)
+        out = checkpoint_name(out.astype(self.dtype), ROUTED_OUT)
         return constrain(
             out.reshape(b, s, h), ("batch", "seq", "act_embed"))
 
 
-def collect_moe_load(intermediates):
-    """The sown ``moe_load`` leaves by layer, ``{layer name: (held + 1,)
-    int32}``; empty where the model has no dropless layer."""
-    loads = {}
+def collect_moe_metrics(intermediates) -> dict:
+    """What the dropless layers sowed, as step metrics: ``moe_load``, the
+    ``(held + 1,)`` int32 loads by layer name, and ``moe_compact``, the
+    routed layers of this step whose load fit one pass over the small
+    buffer (int32; 0 where none is built).  Empty where the model has no
+    dropless layer."""
     if not intermediates:
-        return loads
+        return {}
+    loads, compact = {}, jnp.int32(0)
     for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
         names = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
         if "moe_load" in names:
             loads["/".join(names[:names.index("moe_load")])] = leaf
-    return loads
+        elif "moe_compact" in names:
+            compact = compact + leaf
+    return {"moe_load": loads, "moe_compact": compact} if loads else {}
 
 
 def collect_moe_losses(intermediates) -> jnp.ndarray:

@@ -214,24 +214,26 @@ def make_train_step(
             else:
                 loss = loss_fn(logits, batch)
             # MoE load-balancing/z losses arrive sown in intermediates, as
-            # does the dropless layer's load (pairs routed to each held
-            # expert and to elsewhere, by layer): a step metric.
+            # do the dropless layer's step metrics (pairs routed to each
+            # held expert and to elsewhere, by layer; the layers whose load
+            # fit one pass over the small pairs buffer).
             from dlrover_tpu.models.moe import (
-                collect_moe_load,
                 collect_moe_losses,
+                collect_moe_metrics,
             )
 
             sown = aux_vars.get("intermediates", {})
             loss = loss + collect_moe_losses(sown)
             return loss, (
-                {k: aux_vars[k] for k in extra_keys}, collect_moe_load(sown))
+                {k: aux_vars[k] for k in extra_keys},
+                collect_moe_metrics(sown))
 
         if gradient_fn_factory is not None:
             (loss, ), grads = gradient_fn_factory(
                 lambda p: compute_loss(p)[0])(full_params)
-            new_vars, moe_load = {}, {}
+            new_vars, moe_metrics = {}, {}
         else:
-            (loss, (new_vars, moe_load)), grads = jax.value_and_grad(
+            (loss, (new_vars, moe_metrics)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(full_params)
         if wus_plan is not None:
             # The reduce-scatter point: grads leave their base layout for
@@ -250,9 +252,8 @@ def make_train_step(
             "loss": loss,
             "grad_norm": gnorm,
             "step": new_state.step,
+            **moe_metrics,
         }
-        if moe_load:
-            metrics["moe_load"] = moe_load
         return new_state, metrics
 
     jitted = jax.jit(

@@ -24,8 +24,9 @@ hidden states, and a swapped pick is a whole expert's output, so it is
 routing and not rounding; bounded and recorded), the logits over the tokens
 whose picks agree in every layer (``share_agreeing``; attention and the
 convolutions carry a flipped token's change to its neighbours, so this band
-is wide and tells no precision from another), and each layer's
-``moe_load`` (pairs on each held expert, then elsewhere).  Layer by layer,
+is wide and tells no precision from another), each layer's ``moe_load``
+(pairs on each held expert, then elsewhere) and ``moe_compact`` (the routed
+layers whose load fit one pass over the small pairs buffer).  Layer by layer,
 which is what separates the precisions: each routed layer of the program
 alone, fed the reference's own input to that layer rounded to bf16, against
 the reference's layer on the same input (``layer_rel_l2``, the largest
@@ -324,10 +325,11 @@ def main():
 
     def routing(sown, theirs):
         """Per token whether every routed layer's picks are the
-        reference's, the share of (token, layer) pairs that are not, and
-        each layer's load."""
+        reference's, the share of (token, layer) pairs that are not, each
+        layer's load, and the layers whose load fit one pass over the small
+        buffer."""
         names = sorted(n for n in sown if "experts" in sown[n])
-        agree, flipped, loads = True, [], {}
+        agree, flipped, loads, compact = True, [], {}, 0
         for name, mask in zip(names, theirs):
             picks = sown[name]["experts"]["moe_picks"][0]
             ours = jnp.zeros(mask.shape, bool).at[
@@ -337,7 +339,8 @@ def main():
             flipped.append(1.0 - float(same.mean()))
             loads[name] = [int(n) for n in
                            sown[name]["experts"]["moe_load"][0]]
-        return agree, sum(flipped) / len(flipped), loads
+            compact += int(sown[name]["experts"]["moe_compact"][0])
+        return agree, sum(flipped) / len(flipped), loads, compact
 
     def compare(run, want, ids, theirs):
         logits, sown = run
@@ -349,7 +352,8 @@ def main():
                                / jnp.linalg.norm(want)),
                "loss_rel": abs(loss(logits, ids) / loss(want, ids) - 1.0)}
         if routed:
-            agree, out["flipped"], out["moe_load"] = routing(sown, theirs)
+            agree, out["flipped"], out["moe_load"], out["moe_compact"] = (
+                routing(sown, theirs))
             out["share_agreeing"] = float(
                 jnp.where(agree, worst, 0.0).max()) / top
         return out

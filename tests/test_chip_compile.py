@@ -254,12 +254,20 @@ def _compile_step(topo, monkeypatch, chips, layers, seq=2048, **widths):
         return step.jitted.lower(state, batch).compile()
 
 
-def test_trinity_minis_cut_fits_one_v5e_chip(topo, monkeypatch):
-    """``trinitymini.steady``'s whole step as the benchmark's worker builds
-    it (the configuration's file, b2 x s8192, every layer recomputed):
-    705.5 M parameters x 12 B of arguments, two splash kernels (a windowed
-    and a causal mask) and the grouped products in one program, inside
-    15.75 GiB."""
+@pytest.mark.parametrize("config, params", [
+    ("trinity-mini", 705_474_304),
+    ("lfm2-8b-a1b", 507_820_288),
+])
+def test_a_routed_cells_step_fits_one_v5e_chip(
+        topo, monkeypatch, config, params):
+    """``trinitymini.steady``'s and ``lfm2moe.steady``'s whole steps as the
+    benchmark's worker builds them (the configuration's file, b2 / b4 x
+    s8192, every layer recomputed): 12 B a parameter of arguments, the
+    splash kernels (Trinity's two masks) and the grouped products in one
+    program, inside 15.75 GiB, with no conditional (a conditional's two
+    branches count double in the compiler's own estimate of the memory)
+    and nothing that the compiler computes a second time for want of
+    room."""
     import json
     import os
 
@@ -274,7 +282,7 @@ def test_trinity_minis_cut_fits_one_v5e_chip(topo, monkeypatch):
 
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks", "configs",
-        "trinity-mini.json")
+        f"{config}.json")
     with open(path) as f:
         cfg = json.load(f)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -295,16 +303,17 @@ def test_trinity_minis_cut_fits_one_v5e_chip(topo, monkeypatch):
         model, optax.adamw(opt["learning_rate"], b2=opt["b2"]), mesh, rules,
         batch)
     n_params = sum(x.size for x in jax.tree.leaves(state.params))
-    assert n_params == 705_474_304
+    assert n_params == params
     step = make_train_step(model, mesh, rules, shardings)
     with nn_partitioning.axis_rules(list(rules)), use_mesh(mesh):
         compiled = step.jitted.lower(state, batch).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert " conditional(" not in text and ".remat" not in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 12 * n_params  # + the batch
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    print(f"trinity-mini b{cfg['batch']} x s{cfg['seq']}: arguments "
+    print(f"{config} b{cfg['batch']} x s{cfg['seq']}: arguments "
           f"{mem.argument_size_in_bytes / 2**30:.3f} GiB, temporaries "
           f"{mem.temp_size_in_bytes / 2**30:.3f} GiB")
     assert total < 15.75 * 2**30, f"{total / 2**30:.2f} GiB"
