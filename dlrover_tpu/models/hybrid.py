@@ -88,7 +88,12 @@ from dlrover_tpu.models.llama import (
 )
 from dlrover_tpu.models.moe import ROUTED_OUT, RoutedExperts, small_buffer
 from dlrover_tpu.ops import grouped_matmul, ssd
-from dlrover_tpu.ops.splash_attention import mask_plan, splash_attention_gqa
+from dlrover_tpu.ops.splash_attention import (
+    KERNEL_RESULTS,
+    kept_bytes,
+    mask_plan,
+    splash_attention_gqa,
+)
 from dlrover_tpu.parallel.sharding import constrain
 
 ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
@@ -143,7 +148,13 @@ class HybridConfig:
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
     attention_impl: str = "dot"  # dot | splash
-    remat_policy: str = "none"  # models/llama.py::remat_policy
+    # models/llama.py::remat_policy.  Whatever that recomputes, a layer
+    # also keeps a routed FFN's output (one row a token) and, where its
+    # attention is the splash kernel, the kernel's output (a row a token
+    # at the heads' width) and log-sum-exp (a float a head and token):
+    # ``recompute_policy``.  So under "full" an attention layer holds two
+    # rows a token where the layer's input alone was one.
+    remat_policy: str = "none"
 
     def __post_init__(self):
         # A JSON list arrives through a configuration file; a flax module
@@ -482,6 +493,22 @@ class HybridAttention(nn.Module):
         return constrain(out, ("batch", "seq", "act_embed"))
 
 
+def recompute_policy(name: str):
+    """What a recomputed layer keeps from its forward pass: what
+    ``remat_policy(name)`` keeps, and two results of kernel-heavy pieces
+    whatever that recomputes.  A routed layer's output (one row a token):
+    a gradient that reads it (a norm after the layer) would otherwise run
+    the layer's passes a third time (models/moe.py::_experts_in_passes).
+    The splash kernel's output and log-sum-exp (one row a token and one
+    float a (head, token)): the backward kernel reads both, and the
+    recomputation would otherwise run the forward kernel a second time to
+    make them."""
+    return jax.checkpoint_policies.save_from_both_policies(
+        remat_policy(name),
+        jax.checkpoint_policies.save_only_these_names(
+            ROUTED_OUT, KERNEL_RESULTS))
+
+
 def routed_experts(cfg: HybridConfig, **kw) -> RoutedExperts:
     """The routed-expert FFN of a configuration (``scripts/logits_check.py``
     builds it alone to hold one layer to the reference)."""
@@ -559,6 +586,17 @@ class HybridModel(nn.Module):
                 attention_impl=cfg.attention_impl,
                 head_dim=cfg.resolved_head_dim,
             )
+            # The attention layers whose forward kernel the backward pass
+            # will not run again (``recompute_policy``), and what keeping
+            # the kernel's two results holds.
+            each = 0
+            if cfg.remat_policy != "none" and cfg.attention_impl == "splash":
+                each = kept_bytes(
+                    *input_ids.shape, cfg.num_heads, cfg.resolved_head_dim,
+                    cfg.dtype)
+            kept = sum(kinds[k] for k in ATTENTION_KINDS) if each else 0
+            lowered.update(
+                attention_kept=kept, attention_kept_bytes=kept * each)
             if kinds["sliding_attention"]:
                 seq = input_ids.shape[1]
                 lowered.update(
@@ -605,18 +643,9 @@ class HybridModel(nn.Module):
             x = constrain(x, ("batch", "seq", "act_embed"))
             block_cls = HybridBlock
             if cfg.remat_policy != "none":
-                # Whatever the policy recomputes, a routed layer's output is
-                # kept (one row a token): a gradient that reads it (a norm
-                # after the layer) would otherwise run the layer's passes a
-                # third time (models/moe.py::_experts_in_passes).
                 block_cls = nn.remat(
-                    HybridBlock,
-                    policy=jax.checkpoint_policies.save_from_both_policies(
-                        remat_policy(cfg.remat_policy),
-                        jax.checkpoint_policies.save_only_these_names(
-                            ROUTED_OUT)),
-                    prevent_cse=True,
-                )
+                    HybridBlock, policy=recompute_policy(cfg.remat_policy),
+                    prevent_cse=True)
             for i, kind in enumerate(cfg.layer_types):
                 x = block_cls(cfg, kind, cfg.routed(i), name=f"layers_{i}")(
                     x, positions, segment_ids)

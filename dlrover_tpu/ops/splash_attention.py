@@ -42,6 +42,13 @@ from dlrover_tpu.common.platform import pallas_interpret
 # Reasons already warned about (warn once per process, count every time).
 _warned_reasons = set()
 
+# The library's forward wraps its two results, ``out`` and ``logsumexp``, in
+# this name.  A recomputation policy that keeps it hands the backward kernel
+# the forward pass's own results, where it would otherwise run the forward
+# kernel a second time to make them (models/hybrid.py::recompute_policy).
+# Outside a ``jax.checkpoint`` the name is the identity.
+KERNEL_RESULTS = "splash_attention_results"
+
 
 def _record_fallback(reason: str):
     """One-time warning + always-on counter for splash-kernel fallbacks."""
@@ -110,7 +117,7 @@ def _build_kernel(
     )
     return sk.make_splash_mha(
         mask, block_sizes=block_sizes, head_shards=1, q_seq_shards=1,
-        interpret=interpret,
+        interpret=interpret, residual_checkpoint_name=KERNEL_RESULTS,
     )
 
 
@@ -134,6 +141,17 @@ def mask_plan(seq: int, window: Optional[int] = None,
         for iq in range(n_q) for ik in range(n_kv))
     return dict(block_q=bq, block_kv=bkv, block_pairs=n_q * n_kv,
                 kept=kept, kept_share=kept / (n_q * n_kv))
+
+
+def kept_bytes(batch: int, seq: int, heads: int, head_dim: int, dtype) -> int:
+    """What a policy that keeps ``KERNEL_RESULTS`` holds for one call as a
+    model makes it (``interpret=None``), for a model's ``lower`` span:
+    ``out`` in the call's dtype and ``logsumexp`` in float32, a row a
+    (head, token).  0 off the TPU, where such a call takes the in-tree
+    kernel, which carries no such name and is recomputed whole."""
+    if pallas_interpret():
+        return 0
+    return batch * heads * seq * (head_dim * jnp.dtype(dtype).itemsize + 4)
 
 
 def shapes_tileable(

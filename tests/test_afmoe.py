@@ -22,6 +22,7 @@ import numpy as np
 import optax
 import pytest
 
+from test_hybrid_model import _lower_span
 from test_lfm2_moe import (
     _bf16_accumulation,
     _bf16_router,
@@ -603,17 +604,9 @@ class TestModelContract:
 
     def test_each_lowering_leaves_a_span_in_the_telemetry_directory(
             self, tmp_path, monkeypatch):
-        from dlrover_tpu.telemetry import events
-
-        log = events.EventLog(directory=str(tmp_path))
-        monkeypatch.setattr(events, "emit", log.emit)
-        cfg = HybridConfig.tiny_afmoe(experts_held=4)
-        jax.eval_shape(HybridModel(cfg).init, jax.random.key(0),
-                       jnp.zeros((2, 32), jnp.int32))
-        ends = [e for e in events.read_dir(str(tmp_path))
-                if e["ev"] == "span_end" and e.get("name") == "lower"]
-        assert len(ends) == 1
-        end = ends[0]
+        end = _lower_span(
+            tmp_path, monkeypatch, HybridConfig.tiny_afmoe(experts_held=4),
+            (2, 32))
         assert end["layer_types"] == {
             "sliding_attention": 3, "full_attention": 1}
         assert (end["head_dim"], end["sliding_window"]) == (32, 8)
@@ -630,6 +623,24 @@ class TestModelContract:
         assert end["pairs_rows"] == 2 * 32 * 4 and end["routed_layers"] == 3
         assert end["gmm_gate_up"] == end["gmm_down"] == {
             "path": "ragged_dot", "tiling": None}
+
+    @pytest.mark.parametrize("backend, overrides, kept", [
+        ("tpu", dict(remat_policy="full", attention_impl="splash"), 4),
+        ("tpu", dict(remat_policy="none", attention_impl="splash"), 0),
+        ("tpu", dict(remat_policy="full", attention_impl="dot"), 0),
+        # off the TPU the call takes the in-tree kernel: recomputed whole
+        ("cpu", dict(remat_policy="full", attention_impl="splash"), 0),
+    ])
+    def test_the_span_counts_the_layers_whose_forward_kernel_runs_once(
+            self, tmp_path, monkeypatch, backend, overrides, kept):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        end = _lower_span(
+            tmp_path, monkeypatch,
+            HybridConfig.tiny_afmoe(experts_held=4, **overrides), (2, 128))
+        # out in bf16 and logsumexp in f32 over (batch 2, 4 heads, 128
+        # tokens) at head dim 32, for each layer
+        assert (end["attention_kept"], end["attention_kept_bytes"]) == (
+            kept, kept * 2 * 4 * 128 * (32 * 2 + 4))
 
 
 # Where each rule table puts a new parameter's dimensions, by logical axis.

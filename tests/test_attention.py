@@ -195,6 +195,44 @@ class TestSplashAttention:
         # GQA head-divisibility gate
         assert not shapes_tileable(1024, 1024, 12, 5, 512, 512)
 
+    @pytest.mark.parametrize("d", [64, 128])
+    @pytest.mark.parametrize("window", [None, 128])
+    def test_a_recomputed_layer_runs_the_forward_kernel_once(self, window, d):
+        """Under the model's own recomputation policy the gradient holds one
+        forward kernel and the fused backward one: the backward kernel reads
+        the forward pass's ``out`` and ``logsumexp``.  Under
+        ``nothing_saveable`` the forward kernel is there twice, and the two
+        gradients are the same bits (interpret mode: the kernel is
+        deterministic and nothing else differs)."""
+        from dlrover_tpu.models.hybrid import recompute_policy
+        from dlrover_tpu.ops.splash_attention import splash_attention_gqa
+
+        q, k, v = _rand_qkv(b=1, s=256, h=2, h_kv=1, d=d)
+
+        def layer(q, k, v):  # work on both sides of the kernel, as in a layer
+            out = splash_attention_gqa(
+                q * 1.5, k, v, window=window, interpret=True, block_q=128,
+                block_kv=128)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        grads, kernels = {}, {}
+        for name, policy in (
+                ("model", recompute_policy("full")),
+                ("nothing", jax.checkpoint_policies.nothing_saveable)):
+            grad = jax.grad(
+                jax.checkpoint(layer, policy=policy, prevent_cse=True),
+                argnums=(0, 1, 2))
+            text = str(jax.make_jaxpr(grad)(q, k, v))
+            kernels[name] = (
+                text.count("pallas_call["),
+                text.count("name=splash_mha_fwd_residuals"),
+                text.count("name=splash_mha_dkv_no_residuals"))
+            grads[name] = jax.jit(grad)(q, k, v)
+        assert kernels == {"model": (2, 1, 1), "nothing": (3, 2, 1)}
+        for kept, recomputed in zip(grads["model"], grads["nothing"]):
+            assert np.isfinite(kept).all() and np.abs(kept).max() > 0
+            np.testing.assert_array_equal(kept, recomputed)
+
     def test_model_with_splash_impl(self):
         cfg = LlamaConfig.tiny(attention_impl="splash")
         model = LlamaModel(cfg)

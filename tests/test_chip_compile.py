@@ -254,20 +254,34 @@ def _compile_step(topo, monkeypatch, chips, layers, seq=2048, **widths):
         return step.jitted.lower(state, batch).compile()
 
 
-@pytest.mark.parametrize("config, params", [
-    ("trinity-mini", 705_474_304),
-    ("lfm2-8b-a1b", 507_820_288),
+def _kernel_calls(text, name):
+    """The custom-call instructions of a compiled program's text that run
+    the Pallas kernel ``name`` (an instruction's line holds its target; the
+    lines of metadata after it name the kernel several times more)."""
+    return sum(
+        'custom_call_target="tpu_custom_call"' in line and name in line
+        for line in text.splitlines())
+
+
+@pytest.mark.parametrize("config, params, attention_layers", [
+    ("trinity-mini", 705_474_304, 5),
+    ("lfm2-8b-a1b", 507_820_288, 1),
+    ("granite-4.0-h-micro", 797_850_560, 1),
 ])
-def test_a_routed_cells_step_fits_one_v5e_chip(
-        topo, monkeypatch, config, params):
-    """``trinitymini.steady``'s and ``lfm2moe.steady``'s whole steps as the
-    benchmark's worker builds them (the configuration's file, b2 / b4 x
-    s8192, every layer recomputed): 12 B a parameter of arguments, the
-    splash kernels (Trinity's two masks) and the grouped products in one
+def test_a_hybrid_cells_step_fits_one_v5e_chip(
+        topo, monkeypatch, config, params, attention_layers):
+    """``trinitymini.steady``'s, ``lfm2moe.steady``'s and
+    ``granite4h.steady``'s whole steps as the benchmark's worker builds
+    them (the configuration's file, b2 / b4 / b1 x s8192, every layer
+    recomputed): 12 B a parameter of arguments, the splash kernels
+    (Trinity's two masks) and the routed cells' grouped products in one
     program, inside 15.75 GiB, with no conditional (a conditional's two
-    branches count double in the compiler's own estimate of the memory)
-    and nothing that the compiler computes a second time for want of
-    room."""
+    branches count double in the compiler's own estimate of the memory),
+    nothing that the compiler computes a second time for want of room,
+    and one forward and one backward attention kernel a layer: the
+    recomputation reads the forward pass's output and log-sum-exp
+    (models/hybrid.py::recompute_policy) where it once ran the forward
+    kernel again."""
     import json
     import os
 
@@ -310,6 +324,9 @@ def test_a_routed_cells_step_fits_one_v5e_chip(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert " conditional(" not in text and ".remat" not in text
+    assert (_kernel_calls(text, "splash_mha_fwd"),
+            _kernel_calls(text, "splash_mha_dkv")) == (
+        attention_layers, attention_layers)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 12 * n_params  # + the batch
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes

@@ -165,6 +165,21 @@ def _seeded(cfg, seed=0, b=2, s=32):
     return model, params, ids[:, :-1], ids[:, 1:]
 
 
+
+def _lower_span(tmp_path, monkeypatch, cfg, shape):
+    """The one ``lower`` span that tracing ``HybridModel(cfg)`` over ids of
+    ``shape`` leaves in a telemetry directory."""
+    from dlrover_tpu.telemetry import events
+
+    log = events.EventLog(directory=str(tmp_path))
+    monkeypatch.setattr(events, "emit", log.emit)
+    jax.eval_shape(HybridModel(cfg).init, jax.random.key(0),
+                   jnp.zeros(shape, jnp.int32))
+    end, = [e for e in events.read_dir(str(tmp_path))
+            if e["ev"] == "span_end" and e.get("name") == "lower"]
+    return end
+
+
 def _program_loss(model, params, ids, labels):
     return cross_entropy_loss(model.apply({"params": params}, ids), labels)
 
@@ -295,21 +310,29 @@ class TestModelContract:
 
     def test_each_lowering_leaves_a_span_in_the_telemetry_directory(
             self, tmp_path, monkeypatch):
-        from dlrover_tpu.telemetry import events
+        end = _lower_span(
+            tmp_path, monkeypatch, HybridConfig.tiny(dtype=jnp.float32),
+            (1, 32))
+        assert end["what"] == "hybrid"
+        assert end["layer_types"] == {"mamba": 2, "attention": 1}
+        assert (end["chunk"], end["n_chunks"]) == (8, 4)
+        assert (end["attention_impl"], end["head_dim"]) == ("dot", 16)
+        assert (end["attention_kept"], end["attention_kept_bytes"]) == (0, 0)
 
-        log = events.EventLog(directory=str(tmp_path))
-        monkeypatch.setattr(events, "emit", log.emit)
-        cfg = HybridConfig.tiny(dtype=jnp.float32)
-        model = HybridModel(cfg)
-        jax.eval_shape(model.init, jax.random.key(0),
-                       jnp.zeros((1, 32), jnp.int32))
-        ends = [e for e in events.read_dir(str(tmp_path))
-                if e["ev"] == "span_end" and e.get("name") == "lower"]
-        assert len(ends) == 1
-        assert ends[0]["what"] == "hybrid"
-        assert ends[0]["layer_types"] == {"mamba": 2, "attention": 1}
-        assert (ends[0]["chunk"], ends[0]["n_chunks"]) == (8, 4)
-        assert (ends[0]["attention_impl"], ends[0]["head_dim"]) == ("dot", 16)
+    @pytest.mark.parametrize("overrides, kept", [
+        (dict(remat_policy="full", attention_impl="splash"), 1),
+        (dict(remat_policy="none", attention_impl="splash"), 0),
+        (dict(remat_policy="full", attention_impl="dot"), 0),
+    ])
+    def test_the_span_counts_the_layers_whose_forward_kernel_runs_once(
+            self, tmp_path, monkeypatch, overrides, kept):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        end = _lower_span(
+            tmp_path, monkeypatch, HybridConfig.tiny(**overrides), (1, 128))
+        # one attention layer of three: out in bf16 and logsumexp in f32
+        # over (batch 1, 4 heads, 128 tokens) at head dim 16
+        assert (end["attention_kept"], end["attention_kept_bytes"]) == (
+            kept, kept * 4 * 128 * (16 * 2 + 4))
 
 
 # Where each rule table puts a state-space parameter's sharded dimension:
